@@ -23,7 +23,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ..model.tensors import ClusterTensors, is_leader_slot, replica_exists
+from ..model.tensors import (
+    ClusterTensors, flatten_slots, is_leader_slot, replica_exists, slot_coords,
+)
 from .derived import DerivedState
 
 KIND_MOVE = 0
@@ -194,7 +196,8 @@ def select_sources(state: ClusterTensors, source_score: jax.Array,
     seg = jnp.where(state.assignment >= 0, state.assignment, b)
     on_source = (jnp.concatenate([source_score, jnp.array([-1.0])])[seg] > 0.0) & exists
 
-    flat_weight = jnp.where(on_source, replica_weight, -jnp.inf).reshape(-1)
+    flat_weight = flatten_slots(
+        jnp.where(on_source, replica_weight, -jnp.inf))
     n_flat = flat_weight.shape[0]
     k_src = min(num_sources, n_flat)
 
@@ -209,7 +212,7 @@ def select_sources(state: ClusterTensors, source_score: jax.Array,
     # the best (and second-best) replica of each of the top source brokers.
     quarter = min(k_src // 4, b)
     half = k_src - 2 * quarter            # exact: half + 2*quarter == k_src
-    seg_flat = seg.reshape(-1)
+    seg_flat = flatten_slots(seg)
     idxs = jnp.arange(n_flat, dtype=jnp.int32)
 
     g_w, g_idx = jax.lax.top_k(flat_weight, half)
@@ -244,9 +247,8 @@ def select_sources(state: ClusterTensors, source_score: jax.Array,
     src_valid = jnp.concatenate([jnp.isfinite(g_w), broker_ok, ok_b2])[:k_src]
     src_valid &= top_idx < n_flat
     top_idx = jnp.minimum(top_idx, n_flat - 1)
-    cand_p = (top_idx // s_dim).astype(jnp.int32)
-    cand_s = (top_idx % s_dim).astype(jnp.int32)
-    return cand_p, cand_s, src_valid
+    cand_p, cand_s = slot_coords(top_idx, state.num_partitions, s_dim)
+    return cand_p.astype(jnp.int32), cand_s.astype(jnp.int32), src_valid
 
 
 def generate_candidates(state: ClusterTensors, derived: DerivedState,
@@ -318,10 +320,11 @@ def generate_candidates(state: ClusterTensors, derived: DerivedState,
         # leader, try every other slot.
         lead_mask = is_leader_slot(state)
         lead_weight = jnp.where(on_source & lead_mask, replica_weight, -jnp.inf)
-        flat_lw = lead_weight.reshape(-1)
+        flat_lw = flatten_slots(lead_weight)
         k_l = min(num_sources, flat_lw.shape[0])
         top_lw, top_lidx = jax.lax.top_k(flat_lw, k_l)
-        lp = (top_lidx // s_dim).astype(jnp.int32)
+        lp = slot_coords(top_lidx, state.num_partitions,
+                         s_dim)[0].astype(jnp.int32)
         l_valid = jnp.isfinite(top_lw)
         n = k_l * s_dim
         grid_p = jnp.repeat(lp, s_dim)

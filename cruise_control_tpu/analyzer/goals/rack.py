@@ -17,7 +17,9 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from ...model.tensors import replica_exists, replica_load_total
+from ...model.tensors import (
+    flatten_slots, replica_exists, replica_load_total,
+)
 from ..candidates import CandidateDeltas
 from .base import Goal
 
@@ -57,8 +59,9 @@ class RackAwareGoal(Goal):
         # GoalUtils excluded-topic filtering).
         dup = _duplicate_mask(state) & derived.movable_partition[:, None]
         b = state.num_brokers
-        seg = jnp.where(state.assignment >= 0, state.assignment, b).reshape(-1)
-        out = jax.ops.segment_sum(dup.astype(jnp.float32).reshape(-1), seg,
+        seg = flatten_slots(
+            jnp.where(state.assignment >= 0, state.assignment, b))
+        out = jax.ops.segment_sum(flatten_slots(dup.astype(jnp.float32)), seg,
                                   num_segments=b + 1)
         return out[:b]
 
@@ -161,7 +164,8 @@ class RackAwareDistributionGoal(RackAwareGoal):
         over = (rank_in_rack > limit[:, None]) & replica_exists(state) \
             & derived.movable_partition[:, None]
         b = state.num_brokers
-        seg = jnp.where(state.assignment >= 0, state.assignment, b).reshape(-1)
-        out = jax.ops.segment_sum(over.astype(jnp.float32).reshape(-1), seg,
+        seg = flatten_slots(
+            jnp.where(state.assignment >= 0, state.assignment, b))
+        out = jax.ops.segment_sum(flatten_slots(over.astype(jnp.float32)), seg,
                                   num_segments=b + 1)
         return out[:b]

@@ -31,7 +31,9 @@ import jax
 import jax.numpy as jnp
 
 from ..common.resources import Resource
-from ..model.tensors import ClusterTensors, offline_replicas
+from ..model.tensors import (
+    ClusterTensors, flatten_slots, offline_replicas, slot_coords,
+)
 from .agg import pot_lbi_deltas
 from .candidates import (
     KIND_MOVE, attach_cumulative, compute_deltas, generate_candidates,
@@ -304,9 +306,11 @@ def score_round_candidates(state: ClusterTensors, masks: ExclusionMasks,
     # (ClusterModel.selfHealingEligibleReplicas / _fixOfflineReplicasOnly).
     off = offline_replicas(state)  # [P, S]
     b = state.num_brokers
-    seg = jnp.where(state.assignment >= 0, state.assignment, b).reshape(-1)
+    seg = flatten_slots(
+        jnp.where(state.assignment >= 0, state.assignment, b))
     offline_per_broker = jax.ops.segment_sum(
-        off.astype(jnp.float32).reshape(-1), seg, num_segments=b + 1)[:b]
+        flatten_slots(off.astype(jnp.float32)), seg,
+        num_segments=b + 1)[:b]
     if psum is not None:
         offline_per_broker = psum(offline_per_broker)
     if not goal.leadership_only:
@@ -391,12 +395,14 @@ def _per_broker_top_replicas(state: ClusterTensors, weight: jax.Array,
                              brokers: jax.Array, j: int, largest: bool):
     """For each broker in ``brokers[K]``: the j best replicas it hosts by
     ``weight[P, S]`` (largest or smallest). Returns (flat_idx[K, j],
-    valid[K, j]) into the flattened [P*S] replica axis."""
+    valid[K, j]) into the ``flatten_slots`` replica axis (decode with
+    ``slot_coords``)."""
     from ..model.tensors import replica_exists
     exists = replica_exists(state)
     b = state.num_brokers
-    seg = jnp.where(state.assignment >= 0, state.assignment, b).reshape(-1)
-    flat_w = jnp.where(exists, weight, jnp.nan).reshape(-1)
+    seg = flatten_slots(
+        jnp.where(state.assignment >= 0, state.assignment, b))
+    flat_w = flatten_slots(jnp.where(exists, weight, jnp.nan))
 
     def one(broker):
         on_b = (seg == broker) & jnp.isfinite(flat_w)
@@ -445,8 +451,8 @@ def swap_grid(state: ClusterTensors, derived: DerivedState,
     dst_b = dst_brokers[di]
     a_flat = heavy_idx[si, ai]
     b_flat = light_idx[di, bi]
-    p1, s1 = a_flat // s_dim, a_flat % s_dim
-    p2, s2 = b_flat // s_dim, b_flat % s_dim
+    p1, s1 = slot_coords(a_flat, state.num_partitions, s_dim)
+    p2, s2 = slot_coords(b_flat, state.num_partitions, s_dim)
 
     base_valid = src_b_ok[si] & dst_b_ok[di] & heavy_ok[si, ai] \
         & light_ok[di, bi] & (src_b != dst_b) \

@@ -35,6 +35,15 @@ def load_properties(path: str) -> dict:
     return out
 
 
+def entry_point_optimizer(cfg: CruiseControlConfig):
+    """The serving process solves on every device it can see: the mesh is
+    chosen HERE, at the entry point (``mesh="auto"`` = all visible devices
+    when more than one), and handed to the facade through its
+    ``optimizer=`` seam. Library defaults stay single-device."""
+    from ..analyzer.optimizer import GoalOptimizer
+    return GoalOptimizer(cfg, mesh="auto")
+
+
 def build_demo_cruise_control(cfg: CruiseControlConfig) -> CruiseControl:
     from ..common.resources import Resource
     from ..executor.admin import InMemoryAdminBackend, PartitionState
@@ -52,7 +61,8 @@ def build_demo_cruise_control(cfg: CruiseControlConfig) -> CruiseControl:
                                        Resource.NW_IN: 1e6, Resource.NW_OUT: 1e6})
     monitor = LoadMonitor(cfg, backend, samplers=[SyntheticSampler()],
                           capacity_resolver=caps)
-    return CruiseControl(cfg, backend, load_monitor=monitor)
+    return CruiseControl(cfg, backend, load_monitor=monitor,
+                         optimizer=entry_point_optimizer(cfg))
 
 
 def _configured_sample_store(cfg: CruiseControlConfig, bootstrap: str):
@@ -117,7 +127,8 @@ def build_live_cruise_control(cfg: CruiseControlConfig) -> CruiseControl:
         cfg, admin, samplers=[sampler],
         sample_store=_configured_sample_store(cfg, bootstrap),
         capacity_resolver=_configured_capacity_resolver(cfg))
-    return CruiseControl(cfg, admin, load_monitor=monitor)
+    return CruiseControl(cfg, admin, load_monitor=monitor,
+                         optimizer=entry_point_optimizer(cfg))
 
 
 # Demo-mode tunables: a fresh operator should see a working rebalance in
@@ -129,6 +140,21 @@ _DEMO_DEFAULTS = {
     "broker.metrics.window.ms": 5_000,
     "min.valid.partition.ratio": 0.0,
 }
+
+
+def serve(cc: CruiseControl, host: str | None = None,
+          port: int | None = None, start_precompute: bool = True):
+    """Bring a wired facade up behind the REST server: compile cache
+    first (so even monitor-warmup jits land in it), then ``start_up``
+    (monitor, detectors, prewarm), then the HTTP thread. Returns
+    ``(server, api, thread)``; the caller shuts down ``server``, ``api``
+    and ``cc`` in that order. ``main`` and ``chip_smoke.py`` both come
+    through here."""
+    from ..warmstart import configure_compile_cache
+    configure_compile_cache(cc.config)
+    cc.start_up(block_on_load=False, start_precompute=start_precompute)
+    server, api = make_server(cc, host=host, port=port)
+    return server, api, serve_forever_in_thread(server)
 
 
 def main(argv: list[str] | None = None) -> int:
@@ -151,17 +177,7 @@ def main(argv: list[str] | None = None) -> int:
         demo_cfg = dict(_DEMO_DEFAULTS)
         demo_cfg.update(overrides)
         cc = build_demo_cruise_control(CruiseControlConfig(demo_cfg))
-    # start_up wires the persistent compile cache + the background shape
-    # prewarm from the solver.compile.cache.* / solver.prewarm.* config
-    # keys (round 18) — no wrapper-script env vars needed; configure the
-    # cache as early as possible anyway so even monitor-warmup jits land
-    # in it.
-    from cruise_control_tpu.warmstart import configure_compile_cache
-    configure_compile_cache(cc.config)
-    cc.start_up(block_on_load=False)
-
-    server, api = make_server(cc, host=args.host, port=args.port)
-    thread = serve_forever_in_thread(server)
+    server, api, thread = serve(cc, host=args.host, port=args.port)
     host, port = server.server_address[:2]
     LOG.info("cruise-control-tpu listening on http://%s:%s/kafkacruisecontrol/state",
              host, port)

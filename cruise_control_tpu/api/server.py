@@ -662,7 +662,12 @@ class CruiseControlApi:
         # coalescing key, and run admission — in that order, so a cache
         # hit or a coalesced join is never shed (neither consumes solver
         # capacity). Polls of existing tasks skip all three.
-        resume_id = headers.get(USER_TASK_HEADER)
+        # Header names are case-insensitive on the wire, and urllib (the
+        # repo's own client.Responder) sends this one as "User-task-id":
+        # an exact-case lookup turned every poll of a non-coalescible
+        # operation into a NEW task until admission shed them.
+        resume_id = next((v for k, v in headers.items()
+                          if k.lower() == USER_TASK_HEADER.lower()), None)
         store_key = coalesce_key = None
         if resume_id is None:
             with jny.seg("cache_lookup") as cache_seg:

@@ -154,13 +154,42 @@ def replica_load_column(state: ClusterTensors, r: int) -> jax.Array:
         * replica_exists(state)
 
 
+def slot_major_flat() -> bool:
+    """Whether the flat replica axis is laid out slot-major ([S, P] order)
+    rather than partition-major ([P, S] order). XLA:TPU takes tens of
+    seconds to COMPILE the merge of the huge partition axis with the
+    3-wide slot axis (26 s per reshape at P=100k and growing faster than
+    P, against under a second for [S, P] -> [S*P]; chip run, PR 21), and
+    every solver program flattens several [P, S] arrays. The CPU backend
+    keeps partition-major order: ties in the source selection break by
+    flat index, and the pinned CPU trajectories were recorded under it."""
+    return jax.default_backend() != "cpu"
+
+
+def flatten_slots(per_slot: jax.Array) -> jax.Array:
+    """[P, S, ...] -> [P*S, ...]: the flat replica axis. The element ORDER
+    is backend-dependent (``slot_major_flat``); callers either do not
+    care (segment reductions) or decode indices with ``slot_coords``."""
+    if slot_major_flat():
+        per_slot = jnp.moveaxis(per_slot, 1, 0)
+    return per_slot.reshape((-1,) + per_slot.shape[2:])
+
+
+def slot_coords(flat_idx: jax.Array, num_partitions: int,
+                num_slots: int) -> tuple[jax.Array, jax.Array]:
+    """(partition, slot) of indices into a ``flatten_slots`` axis."""
+    if slot_major_flat():
+        return flat_idx % num_partitions, flat_idx // num_partitions
+    return flat_idx // num_slots, flat_idx % num_slots
+
+
 def _scatter_to_brokers(state: ClusterTensors, per_slot: jax.Array) -> jax.Array:
     """Sum a [P, S] or [P, S, R] per-replica quantity into per-broker rows
     ([B] or [B, R]). Padded slots route to a dead bucket at index B."""
     b = state.num_brokers
-    seg = jnp.where(state.assignment >= 0, state.assignment, b).reshape(-1)
-    flat = per_slot.reshape((seg.shape[0],) + per_slot.shape[2:])
-    out = jax.ops.segment_sum(flat, seg, num_segments=b + 1)
+    seg = flatten_slots(jnp.where(state.assignment >= 0, state.assignment, b))
+    out = jax.ops.segment_sum(flatten_slots(per_slot), seg,
+                              num_segments=b + 1)
     return out[:b]
 
 
@@ -189,8 +218,9 @@ def _topic_broker_counts(state: ClusterTensors, num_topics: int,
     seg = jnp.where(per_slot, state.topic[:, None] * (b + 1)
                     + jnp.where(state.assignment >= 0, state.assignment, b),
                     num_topics * (b + 1))
-    flat = per_slot.astype(jnp.int32).reshape(-1)
-    out = jax.ops.segment_sum(flat, seg.reshape(-1), num_segments=num_topics * (b + 1) + 1)
+    flat = flatten_slots(per_slot.astype(jnp.int32))
+    out = jax.ops.segment_sum(flat, flatten_slots(seg),
+                              num_segments=num_topics * (b + 1) + 1)
     return out[:num_topics * (b + 1)].reshape(num_topics, b + 1)[:, :b]
 
 

@@ -241,11 +241,14 @@ class SyntheticSampler:
     self-consistent across intervals AND across processes (builtin
     ``hash()`` is PYTHONHASHSEED-randomized for the topic string — the
     same trap PR 4 fixed in the partition assignor; CCSA004 now polices
-    it)."""
+    it). ``skew`` > 1 raises the uniform draw to that power: most
+    partitions light, a few heavy (1.0 keeps the uniform spread)."""
 
-    def __init__(self, seed: int = 0, cpu_per_kb: float = 2e-4):
+    def __init__(self, seed: int = 0, cpu_per_kb: float = 2e-4,
+                 skew: float = 1.0):
         self._seed = seed
         self._cpu_per_kb = cpu_per_kb
+        self._skew = skew
 
     def get_samples(self, partitions, start_ms, end_ms) -> SamplerResult:
         from ...metricdef.kafka_metric_def import CommonMetric as CM
@@ -254,8 +257,8 @@ class SyntheticSampler:
         for (topic, part), st in partitions.items():
             if st.leader < 0:
                 continue
-            h = (zlib.crc32(f"{self._seed}:{topic}:{part}".encode())
-                 % 1000) / 1000.0
+            h = ((zlib.crc32(f"{self._seed}:{topic}:{part}".encode())
+                  % 1000) / 1000.0) ** self._skew
             bytes_in = 50.0 + 950.0 * h
             bytes_out = 2.0 * bytes_in
             psamples.append(PartitionMetricSample.make(topic, part, end_ms, {

@@ -30,6 +30,7 @@ from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..analyzer.candidates import (
@@ -56,8 +57,10 @@ from ..analyzer.search import (
     run_carry_loop,
 )
 from ..common.resources import Resource
-from ..model.tensors import ClusterTensors, offline_replicas
-from .mesh import PARTITION_AXIS, shard_map
+from ..model.tensors import (
+    ClusterTensors, flatten_slots, offline_replicas, slot_coords,
+)
+from .mesh import PARTITION_AXIS
 from .sharded import _mask_specs, _psum, _state_specs, mutable_state_specs
 
 
@@ -112,7 +115,7 @@ def _global_source_threshold(weight: jax.Array, src_score: jax.Array,
                  > 0.0) & exists
     w_eff = jnp.where(on_source, weight, -jnp.inf)
     k = min(k_src, w_eff.size)
-    local_top, _ = jax.lax.top_k(w_eff.reshape(-1), k)
+    local_top, _ = jax.lax.top_k(flatten_slots(w_eff), k)
     g_top = jax.lax.all_gather(local_top, PARTITION_AXIS).reshape(-1)
     theta = jax.lax.top_k(g_top, k)[0][-1]
     # -inf theta (fewer than k eligible replicas globally) keeps all.
@@ -122,8 +125,9 @@ def _global_source_threshold(weight: jax.Array, src_score: jax.Array,
 
 def _offline_per_broker(state: ClusterTensors, off: jax.Array) -> jax.Array:
     b = state.num_brokers
-    seg = jnp.where(state.assignment >= 0, state.assignment, b).reshape(-1)
-    local = jax.ops.segment_sum(off.astype(jnp.float32).reshape(-1), seg,
+    seg = flatten_slots(
+        jnp.where(state.assignment >= 0, state.assignment, b))
+    local = jax.ops.segment_sum(flatten_slots(off.astype(jnp.float32)), seg,
                                 num_segments=b + 1)[:b]
     return _psum(local)
 
@@ -382,8 +386,8 @@ def _chain_swap_local(state: ClusterTensors, agg, masks: ExclusionMasks,
     light_idx, light_ok = _per_broker_top_replicas(
         state, weight, dst_brokers, j, largest=False)
 
-    p1, s1 = heavy_idx // s_dim, heavy_idx % s_dim
-    p2, s2 = light_idx // s_dim, light_idx % s_dim
+    p1, s1 = slot_coords(heavy_idx, state.num_partitions, s_dim)
+    p2, s2 = slot_coords(light_idx, state.num_partitions, s_dim)
 
     def leg_masks(pp, ss, ok, counterparties):
         n = k * j * k

@@ -46,8 +46,7 @@ _POD_ENV_MARKERS = ("TPU_WORKER_HOSTNAMES", "TPU_WORKER_ID",
 
 def _backend_initialized() -> bool:
     from jax._src import xla_bridge
-    probe = getattr(xla_bridge, "backends_are_initialized", None)
-    return bool(probe()) if probe is not None else False
+    return bool(xla_bridge.backends_are_initialized())
 
 
 def initialize(coordinator_address: str | None = None,

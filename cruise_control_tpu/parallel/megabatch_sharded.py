@@ -48,11 +48,12 @@ from functools import lru_cache
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..analyzer.search import ExclusionMasks
 from ..model.tensors import ClusterTensors
-from .mesh import PARTITION_AXIS, shard_map
+from .mesh import PARTITION_AXIS
 
 # The fleet mesh is the solver mesh: one 1-D axis. For the megabatch
 # twins that axis carries CLUSTERS (each device holds whole clusters),

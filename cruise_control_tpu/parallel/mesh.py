@@ -16,21 +16,6 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pre-stabilization jax: experimental home + old kwarg
-    from functools import wraps
-
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    @wraps(_exp_shard_map)
-    def shard_map(*args, **kwargs):
-        # The stabilized API renamed check_rep -> check_vma; translate so
-        # call sites can use the current spelling everywhere.
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _exp_shard_map(*args, **kwargs)
-
 PARTITION_AXIS = "p"
 
 

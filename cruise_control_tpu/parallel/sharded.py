@@ -28,6 +28,7 @@ from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..analyzer.candidates import Candidates, CandidateDeltas, compute_deltas
@@ -38,8 +39,8 @@ from ..analyzer.search import (
     _conflict_free_top_m, _per_broker_top_replicas, apply_selected, goal_aux,
     reduce_per_source, run_rounds_loop, score_round_candidates,
 )
-from ..model.tensors import ClusterTensors
-from .mesh import PARTITION_AXIS, shard_map
+from ..model.tensors import ClusterTensors, slot_coords
+from .mesh import PARTITION_AXIS
 
 
 def _state_specs() -> ClusterTensors:
@@ -232,8 +233,8 @@ def _swap_round_local(state: ClusterTensors, masks: ExclusionMasks, *, goal,
     light_idx, light_ok = _per_broker_top_replicas(
         state, weight, dst_brokers, j, largest=False)
 
-    p1, s1 = heavy_idx // s_dim, heavy_idx % s_dim        # local ids [k, j]
-    p2, s2 = light_idx // s_dim, light_idx % s_dim
+    p1, s1 = slot_coords(heavy_idx, state.num_partitions, s_dim)  # local ids [k, j]
+    p2, s2 = slot_coords(light_idx, state.num_partitions, s_dim)
 
     def leg_masks(pp, ss, ok, counterparties):
         """[k, j, k] leg acceptance: replica (pp, ss) moved to each

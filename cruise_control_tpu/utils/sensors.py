@@ -179,6 +179,20 @@ class SensorRegistry:
             h = self._histograms.get(k)
             return h.quantile(q) if h is not None else None
 
+    def counter_total(self, name: str) -> float:
+        """Sum of a counter over every label set (introspection surface:
+        ``chip_smoke.py`` brackets requests with the compile counters)."""
+        with self._lock:
+            return sum(v for (n, _labels), v in self._counters.items()
+                       if n == name)
+
+    def histogram_sum(self, name: str) -> float:
+        """Sum of all observations of a histogram over every label set
+        (same surface: seconds spent in backend compiles)."""
+        with self._lock:
+            return sum(h.total for (n, _labels), h in self._histograms.items()
+                       if n == name)
+
     def histogram_snapshot(self, name: str, labels: dict | None = None,
                            ) -> dict | None:
         """{buckets, counts (non-cumulative, +Inf last), sum, count} of a

@@ -114,15 +114,9 @@ def install(enabled: bool = True) -> bool:
         _enabled = bool(enabled)
         if _installed or not _enabled:
             return _installed
-        try:
-            from jax import monitoring
-            monitoring.register_event_duration_secs_listener(
-                _on_event_duration)
-            monitoring.register_event_listener(_on_event)
-        except Exception:  # noqa: BLE001 — older/newer jax without the API
-            LOG.warning("jax.monitoring unavailable; xla telemetry off",
-                        exc_info=True)
-            return False
+        from jax import monitoring
+        monitoring.register_event_duration_secs_listener(_on_event_duration)
+        monitoring.register_event_listener(_on_event)
         _installed = True
         return True
 

@@ -24,8 +24,8 @@ sites:
   skipped byte-identically (``DispatchStats.goals_skipped``).
 
 - **AOT prewarm** — ``ShapeRegistry`` persists every solved padded
-  bucket-shape signature under the XLA persistent-cache partition dir
-  (one JSON file per host fingerprint), and ``PrewarmManager`` compiles
+  bucket-shape signature in ``solver_shapes.json`` inside the XLA
+  persistent-cache directory in force, and ``PrewarmManager`` compiles
   the whole per-shape kernel set in a background thread at ``start_up``
   (``GoalOptimizer.prewarm_shape`` executes the production kernels on an
   inert synthetic model: full compile, zero search work). Watched by the
@@ -58,12 +58,13 @@ LOG = logging.getLogger(__name__)
 # -- compile-cache config seam (satellite: solver.compile.cache.*) ---------
 
 def configure_compile_cache(config) -> str | None:
-    """Point XLA's persistent compilation cache at the configured
-    directory — the ``solver.compile.cache.{enabled,dir,min.compile.secs}``
-    seam replacing the env-var/hardcoded values every entry point used to
-    wire by hand. Called from facade ``start_up`` so SERVING processes
-    (not just bench/CLI wrappers) persist their solver compiles. Returns
-    the host-partitioned cache dir, or None when disabled."""
+    """Turn on XLA's persistent compilation cache from the
+    ``solver.compile.cache.{enabled,dir,min.compile.secs}`` keys. Called
+    from facade ``start_up`` so SERVING processes (not just bench/CLI
+    wrappers) persist their solver compiles. ``JAX_COMPILATION_CACHE_DIR``
+    outranks ``solver.compile.cache.dir`` (the one placement rule lives in
+    ``enable_persistent_compile_cache``). Returns the directory in force,
+    or None when disabled."""
     if not config.get_boolean("solver.compile.cache.enabled"):
         return None
     from . import enable_persistent_compile_cache
@@ -337,11 +338,10 @@ def synthetic_masks(entry: dict):
 
 
 class ShapeRegistry:
-    """The persisted set of solved shape signatures, one JSON file under
-    the XLA persistent-cache partition dir (host-fingerprint scoped, so
-    a machine never prewarms another machine's unloadable artifacts).
-    Atomic rewrite on every NEW shape; the set is tiny (one entry per
-    padded bucket shape x chain x mask layout)."""
+    """The persisted set of solved shape signatures, one JSON file kept
+    in the XLA persistent-cache directory (it follows wherever the cache
+    is placed). Atomic rewrite on every NEW shape; the set is tiny (one
+    entry per padded bucket shape x chain x mask layout)."""
 
     def __init__(self, path: str):
         self._path = path
@@ -509,7 +509,7 @@ def ensure_prewarm(optimizer, config, start: bool = True,
     """Create (once) and start (idempotently) the prewarm manager for
     ``optimizer`` per ``config``. Returns None when prewarm is disabled
     or the persistent compile cache is off — the shape registry lives in
-    the cache's host-partition dir, and prewarming without persistence
+    the cache directory, and prewarming without persistence
     would re-pay every compile on the next restart anyway."""
     if not config.get_boolean("solver.prewarm.enabled"):
         return None

@@ -191,6 +191,10 @@ def test_user_task_id_resume(api):
     _s2, _b2, headers2 = api.handle("POST", "/kafkacruisecontrol/rebalance",
                                     "dryrun=true", {"User-Task-ID": tid})
     assert headers2["User-Task-ID"] == tid
+    # As urllib (client.Responder) spells it on the wire.
+    _s3, _b3, headers3 = api.handle("POST", "/kafkacruisecontrol/rebalance",
+                                    "dryrun=true", {"User-task-id": tid})
+    assert headers3["User-Task-ID"] == tid
 
 
 def test_admin_self_healing_toggle(api, cc):

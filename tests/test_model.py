@@ -262,3 +262,65 @@ def test_host_aware_optimization_separates_cohosted_replicas():
         hosts = host[reps]
         assert len(set(hosts.tolist())) == len(reps), \
             f"partition {p} has co-hosted replicas: brokers {reps.tolist()}"
+
+
+def test_slot_major_flat_axis_matches_partition_major(monkeypatch):
+    """The accelerator layout of the flat replica axis (slot-major, see
+    tensors.slot_major_flat) forced on CPU: segment reductions agree with
+    the partition-major layout, the source selection picks the same
+    replicas, and a full chain solve still lands a valid result."""
+    import jax
+
+    from cruise_control_tpu.analyzer.candidates import select_sources
+    from cruise_control_tpu.analyzer.optimizer import GoalOptimizer
+    from cruise_control_tpu.model import tensors
+    from cruise_control_tpu.model.fixtures import random_cluster
+
+    state, meta = random_cluster(num_brokers=12, num_topics=4,
+                                 num_partitions=96, rf=3, num_racks=4,
+                                 seed=3, skew_to_first=2.0)
+    rng = np.random.default_rng(0)
+    # Distinct weights: no ties, so the selection cannot depend on order.
+    weight = jnp.asarray(rng.permutation(state.assignment.size).reshape(
+        state.assignment.shape).astype(np.float32))
+    score = jnp.asarray(rng.random(state.num_brokers).astype(np.float32))
+
+    def snapshot():
+        p, s, ok = select_sources(state, score, weight, 32)
+        picked = {(int(a), int(b)) for a, b, v in zip(p, s, ok) if v}
+        return (np.asarray(tensors.broker_load(state)),
+                np.asarray(tensors.topic_broker_replica_counts(
+                    state, meta.num_topics)), picked)
+
+    load_pm, counts_pm, picked_pm = snapshot()
+    monkeypatch.setattr(tensors, "slot_major_flat", lambda: True)
+    jax.clear_caches()      # jitted traces baked the other layout in
+    try:
+        idx = jnp.arange(state.assignment.size)
+        p, s = tensors.slot_coords(idx, state.num_partitions,
+                                   state.max_replication_factor)
+        np.testing.assert_array_equal(
+            np.asarray(tensors.flatten_slots(state.assignment)),
+            np.asarray(state.assignment)[np.asarray(p), np.asarray(s)])
+        load_sm, counts_sm, picked_sm = snapshot()
+        np.testing.assert_allclose(load_sm, load_pm, rtol=1e-5)
+        np.testing.assert_array_equal(counts_sm, counts_pm)
+        assert picked_sm == picked_pm and picked_sm
+        # A short chain keeps the compile small: a hard goal, a count
+        # goal, a swap-capable resource goal and a leadership goal cover
+        # every consumer of the flat axis (moves, swaps, leader grid).
+        from cruise_control_tpu.analyzer.goals import (
+            LeaderReplicaDistributionGoal,
+            NetworkOutboundUsageDistributionGoal, RackAwareGoal,
+            ReplicaDistributionGoal,
+        )
+        _final, result = GoalOptimizer().optimizations(state, meta, goals=[
+            RackAwareGoal(), ReplicaDistributionGoal(),
+            NetworkOutboundUsageDistributionGoal(),
+            LeaderReplicaDistributionGoal()])
+        assert "RackAwareGoal" not in result.violated_goals_after
+        assert result.balancedness_after >= result.balancedness_before
+        assert result.proposals
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
