@@ -67,10 +67,8 @@ class AggCarry:
     topic_counts: jax.Array     # [T, B] i32
 
 
-def compute_agg(state: ClusterTensors, num_topics: int,
-                psum=None) -> AggCarry:
-    """Full aggregate recompute (the segment-sum path). ``psum`` combines
-    the partition-local partials across a sharded mesh."""
+def _compute_agg(state: ClusterTensors, num_topics: int,
+                 psum=None) -> AggCarry:
     p = psum or (lambda x: x)
     return AggCarry(
         broker_load=p(broker_load(state)),
@@ -80,6 +78,15 @@ def compute_agg(state: ClusterTensors, num_topics: int,
         lbi=p(leader_bytes_in(state)),
         topic_counts=p(topic_broker_replica_counts(state, num_topics)),
     )
+
+
+@jax.named_scope("goal.agg")
+def compute_agg(state: ClusterTensors, num_topics: int,
+                psum=None) -> AggCarry:
+    """Full aggregate recompute (the segment-sum path), once a goal or a
+    dispatch. ``psum`` combines the partition-local partials across a
+    sharded mesh."""
+    return _compute_agg(state, num_topics, psum)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,6 +153,7 @@ def pot_lbi_deltas(state: ClusterTensors, sub) -> tuple[jax.Array, jax.Array]:
     return pot, lbi
 
 
+@jax.named_scope("round.agg_refresh")
 def maybe_refresh(agg: AggCarry, state: ClusterTensors, num_topics: int,
                   rounds_done: jax.Array, psum=None) -> AggCarry:
     """Fresh recompute every REFRESH_EVERY rounds (f32 drift bound); the
@@ -157,5 +165,5 @@ def maybe_refresh(agg: AggCarry, state: ClusterTensors, num_topics: int,
         return agg
     return jax.lax.cond(
         (rounds_done % REFRESH_EVERY) == (REFRESH_EVERY - 1),
-        lambda: compute_agg(state, num_topics),
+        lambda: _compute_agg(state, num_topics),
         lambda: agg)

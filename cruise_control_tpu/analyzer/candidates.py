@@ -103,6 +103,7 @@ class CandidateDeltas:
         return 0.0 if value is None else value[:, r]
 
 
+@jax.named_scope("round.deltas")
 def compute_deltas(state: ClusterTensors, derived: DerivedState,
                    cand: Candidates) -> CandidateDeltas:
     """Gather the (src, dst, Δload) tuple for every candidate; also folds the
@@ -180,6 +181,7 @@ def compute_deltas(state: ClusterTensors, derived: DerivedState,
     )
 
 
+@jax.named_scope("round.source_topk")
 def select_sources(state: ClusterTensors, source_score: jax.Array,
                    replica_weight: jax.Array, num_sources: int,
                    ) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -251,6 +253,7 @@ def select_sources(state: ClusterTensors, source_score: jax.Array,
     return cand_p.astype(jnp.int32), cand_s.astype(jnp.int32), src_valid
 
 
+@jax.named_scope("round.candidates")
 def generate_candidates(state: ClusterTensors, derived: DerivedState,
                         source_score: jax.Array, dest_score: jax.Array,
                         replica_weight: jax.Array, num_sources: int,

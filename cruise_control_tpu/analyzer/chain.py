@@ -50,6 +50,7 @@ from .search import (
     run_carry_loop, swap_grid,
 )
 from ..utils.flight_recorder import NO_FLIGHT, STAT_WIDTH as _FLIGHT_STATS
+from ..utils.tracing import TRACER
 
 
 def _gated_aux(needed: jax.Array, goal: Goal, state, derived, constraint,
@@ -212,34 +213,43 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
     (the duplicated ``reduce_per_source`` is structurally identical to
     the one inside ``cumulative_select``, so XLA CSE collapses the two),
     never a new selection input: the trajectory is byte-identical with
-    collection on or off (pinned in tests/test_flight_recorder.py)."""
+    collection on or off (pinned in tests/test_flight_recorder.py).
+
+    The phases carry stable ``jax.named_scope`` names (``round.score``,
+    ``round.source_topk``, ``round.candidates``, ``round.deltas``,
+    ``round.accept``, ``round.select``, ``round.apply``,
+    ``round.flight_stats``; ``round.agg_refresh`` in the drivers), some
+    here and some inside the helpers, so a device profile names them.
+    Metadata only: the lowered computation is the same."""
     lead_only_f, incl_lead_f, indep_f = _goal_flags(goals)
     is_lead_only = lead_only_f[active_idx]
     has_leadership = incl_lead_f[active_idx]
 
-    derived = compute_derived(state, masks.excluded_topics,
-                              masks.excluded_replica_move_brokers,
-                              masks.excluded_leadership_brokers, agg=agg)
-    is_active = jnp.arange(len(goals)) == active_idx
-    aux_list = [_gated_aux(prior_mask[i] | is_active[i], g, state, derived,
-                           constraint, num_topics, agg=agg)
-                for i, g in enumerate(goals)]
+    with jax.named_scope("round.score"):
+        derived = compute_derived(state, masks.excluded_topics,
+                                  masks.excluded_replica_move_brokers,
+                                  masks.excluded_leadership_brokers, agg=agg)
+        is_active = jnp.arange(len(goals)) == active_idx
+        aux_list = [_gated_aux(prior_mask[i] | is_active[i], g, state,
+                               derived, constraint, num_topics, agg=agg)
+                    for i, g in enumerate(goals)]
 
-    src_score, dst_score, weight = _switch_scores(
-        active_idx, goals, aux_list, state, derived, constraint)
+        src_score, dst_score, weight = _switch_scores(
+            active_idx, goals, aux_list, state, derived, constraint)
 
-    # Self-healing priority (see search.score_round_candidates): offline
-    # replicas are always sources with maximal weight for non-leadership
-    # goals.
-    off = offline_replicas(state)  # [P, S]
-    b = state.num_brokers
-    seg = flatten_slots(
-        jnp.where(state.assignment >= 0, state.assignment, b))
-    offline_per_broker = jax.ops.segment_sum(
-        flatten_slots(off.astype(jnp.float32)), seg,
-        num_segments=b + 1)[:b]
-    src_score = src_score + jnp.where(is_lead_only, 0.0, offline_per_broker)
-    weight = jnp.where(off & ~is_lead_only, 1e30, weight)
+        # Self-healing priority (see search.score_round_candidates):
+        # offline replicas are always sources with maximal weight for
+        # non-leadership goals.
+        off = offline_replicas(state)  # [P, S]
+        b = state.num_brokers
+        seg = flatten_slots(
+            jnp.where(state.assignment >= 0, state.assignment, b))
+        offline_per_broker = jax.ops.segment_sum(
+            flatten_slots(off.astype(jnp.float32)), seg,
+            num_segments=b + 1)[:b]
+        src_score = src_score + jnp.where(is_lead_only, 0.0,
+                                          offline_per_broker)
+        weight = jnp.where(off & ~is_lead_only, 1e30, weight)
 
     # UNIFORM grid layout: both the move and the leadership block always
     # exist (static shapes shared by every goal); the active goal's traced
@@ -274,14 +284,6 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
     cand = dataclasses.replace(cand, valid=cand.valid & block_ok)
     deltas = compute_deltas(state, derived, cand)
 
-    accept = deltas.valid
-    for i, g in enumerate(goals):
-        accept &= (~prior_mask[i]) | g.acceptance(state, derived, constraint,
-                                                  aux_list[i], deltas)
-
-    moving_offline = off[deltas.partition, deltas.src_slot] \
-        & (deltas.replica_delta > 0)
-
     def imp_branch(i):
         g = goals[i]
 
@@ -290,11 +292,20 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
                                  deltas).astype(jnp.float32)
         return fn
 
-    imp = jax.lax.switch(active_idx,
-                         [imp_branch(i) for i in range(len(goals))], 0)
-    imp = jnp.where(moving_offline & jnp.isfinite(imp) & deltas.valid,
-                    jnp.maximum(imp, 0.0) + _OFFLINE_BONUS, imp)
-    score = jnp.where(accept, imp, -jnp.inf)
+    with jax.named_scope("round.accept"):
+        accept = deltas.valid
+        for i, g in enumerate(goals):
+            accept &= (~prior_mask[i]) | g.acceptance(
+                state, derived, constraint, aux_list[i], deltas)
+
+        moving_offline = off[deltas.partition, deltas.src_slot] \
+            & (deltas.replica_delta > 0)
+
+        imp = jax.lax.switch(active_idx,
+                             [imp_branch(i) for i in range(len(goals))], 0)
+        imp = jnp.where(moving_offline & jnp.isfinite(imp) & deltas.valid,
+                        jnp.maximum(imp, 0.0) + _OFFLINE_BONUS, imp)
+        score = jnp.where(accept, imp, -jnp.inf)
 
     independent = indep_f[active_idx] & ~prior_mask.any()
     m = max(cfg.moves_per_round, cfg.num_sources)
@@ -315,29 +326,35 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
         state, deltas, score, layout, m, cfg.moves_per_round, independent,
         recheck, extra_last_col=targets_enabled(state.num_partitions))
     if agg is not None:
-        agg = apply_deltas_to_agg(agg, sub, sel, pot_d, lbi_d)
+        with jax.named_scope("round.apply"):
+            agg = apply_deltas_to_agg(agg, sub, sel, pot_d, lbi_d)
+    # apply_selected carries the round.apply scope itself (every driver
+    # calls it)
     new_state = apply_selected(
         state, sel, deltas.partition[top_idx], deltas.src_slot[top_idx],
-        deltas.dst_broker[top_idx], cand.kind[top_idx], cand.dst_slot[top_idx])
+        deltas.dst_broker[top_idx], cand.kind[top_idx],
+        cand.dst_slot[top_idx])
     applied = sel.sum()
     stat = None
     if collect:
-        red_idx = reduce_per_source(
-            score, layout, extra_last_col=targets_enabled(
-                state.num_partitions))
-        viol = _switch_goal_fn(
-            active_idx, goals,
-            lambda g, i: g.broker_violations(
-                state, derived, constraint, aux_list[i]).sum()
-            .astype(jnp.float32))
-        stat = jnp.stack([
-            applied.astype(jnp.float32),
-            deltas.valid.sum().astype(jnp.float32),
-            accept.sum().astype(jnp.float32),
-            (score > _EPS_IMPROVEMENT).sum().astype(jnp.float32),
-            (score[red_idx] > _EPS_IMPROVEMENT).sum().astype(jnp.float32),
-            viol,
-        ])
+        with jax.named_scope("round.flight_stats"):
+            red_idx = reduce_per_source(
+                score, layout, extra_last_col=targets_enabled(
+                    state.num_partitions))
+            viol = _switch_goal_fn(
+                active_idx, goals,
+                lambda g, i: g.broker_violations(
+                    state, derived, constraint, aux_list[i]).sum()
+                .astype(jnp.float32))
+            stat = jnp.stack([
+                applied.astype(jnp.float32),
+                deltas.valid.sum().astype(jnp.float32),
+                accept.sum().astype(jnp.float32),
+                (score > _EPS_IMPROVEMENT).sum().astype(jnp.float32),
+                (score[red_idx] > _EPS_IMPROVEMENT).sum()
+                .astype(jnp.float32),
+                viol,
+            ])
         assert stat.shape == (_FLIGHT_STATS,)
     return new_state, agg, applied, stat
 
@@ -466,6 +483,7 @@ def chain_optimize_rounds_donated(assignment: jax.Array,
     return final.assignment, final.leader_slot, total, rounds
 
 
+@jax.named_scope("swap.round")
 def _chain_swap_body(state: ClusterTensors, agg: "AggCarry | None",
                      active_idx: jax.Array,
                      prior_mask: jax.Array, goals: tuple[Goal, ...],
@@ -574,6 +592,7 @@ def chain_swap_rounds_donated(assignment: jax.Array, leader_slot: jax.Array,
     return final.assignment, final.leader_slot, total, rounds
 
 
+@jax.named_scope("goal.stats")
 def _chain_goal_stats_body(state: ClusterTensors, active_idx: jax.Array,
                            goals: tuple[Goal, ...],
                            constraint: BalancingConstraint, num_topics: int,
@@ -629,6 +648,7 @@ def chain_all_violations(state: ClusterTensors, goals: tuple[Goal, ...],
     return jnp.stack(totals)
 
 
+@jax.named_scope("goal.stats")
 def _chain_all_goal_stats_body(state: ClusterTensors,
                                goals: tuple[Goal, ...],
                                constraint: BalancingConstraint,
@@ -827,10 +847,22 @@ def optimize_chain(state: ClusterTensors, chain: Sequence[Goal],
     goals = tuple(chain)
     if not goals:
         return state, []
-    state, stats = chain_optimize_full(state, goals, constraint, cfg,
-                                       num_topics, masks)
-    stats = {k: jax.device_get(v) for k, v in stats.items()}
-    return state, _chain_infos_from_stats(goals, stats)
+    with TRACER.span("solver.dispatch", route="fused") as dispatch:
+        with TRACER.span("solver.enqueue"):
+            state, stats = chain_optimize_full(state, goals, constraint, cfg,
+                                               num_topics, masks)
+        with TRACER.span("solver.wait"):
+            stats = {k: jax.device_get(v) for k, v in stats.items()}
+        infos = _chain_infos_from_stats(goals, stats)
+        set_dispatch_rounds(dispatch, infos)
+    return state, infos
+
+
+def set_dispatch_rounds(dispatch, infos: list[dict]) -> None:
+    """Rounds of a whole-chain dispatch onto its ``solver.dispatch`` span:
+    the total, and each goal's count in chain order."""
+    dispatch.set(rounds=sum(i["rounds"] for i in infos),
+                 goal_rounds=",".join(str(i["rounds"]) for i in infos))
 
 
 def _chain_infos_from_stats(goals: tuple[Goal, ...], stats: dict,
@@ -1156,61 +1188,68 @@ def run_bounded_pass(enqueue: Callable, st, pass_cap: int,
     prev = None    # (applied, rounds, budget, t0, donated, ring) — unread
     last_read_t = None
     converged = False
-    while True:
-        cur = None
-        may_enqueue = prev is None or async_readback
-        if may_enqueue and not converged and est_rounds < pass_cap \
-                and not (out_of_time is not None and out_of_time()):
-            budget = controller.budget(pass_cap - est_rounds)
-            t0 = _time.monotonic()
-            st, applied, r, donated, ring = enqueue(st, budget)
-            cur = (applied, r, budget, t0, donated, ring)
-            est_rounds += budget
-        if prev is not None:
-            applied_p, r_p, budget_p, t0_p, donated_p, ring_p = prev
-            # ccsa: ok[CCSA001] THE pump readback: dispatch N's scalars are
-            # read here exactly one enqueue behind — N+1 is already in
-            # flight, so this block overlaps device compute by design
-            r_read = int(r_p)                       # blocks on dispatch N
-            now = _time.monotonic()
-            start = t0_p if last_read_t is None else max(t0_p, last_read_t)
-            # ccsa: ok[CCSA001] same readback point: N already synced via
-            # r_read, this transfer is paid, not a new stall
-            applied_total += int(applied_p)
-            controller.observe(r_read, budget_p, now - start)
-            last_read_t = now
-            if stats is not None:
-                stats.record(kind, r_read, donated=donated_p)
-            # ccsa: ok[CCSA001] same readback point, applied_p already read
-            flight.dispatch(kind, budget_p, r_read, int(applied_p),
-                            donated=donated_p, elapsed_s=now - start,
-                            controller_k=controller.k, ring=ring_p)
-            pass_rounds += r_read
-            est_rounds -= budget_p - r_read         # correct the estimate
-            if r_read < budget_p:
-                converged = True
-        if converged and cur is not None:
-            # Speculative post-convergence dispatch: one re-run of the
-            # terminal zero-apply round (state frozen on device, applies
-            # nothing). Its rounds are NOT added to pass_rounds — they
-            # make no search progress, and counting them would consume
-            # cfg.max_rounds budget the synchronous per-round path does
-            # not pay, diverging the paths at the round-cap boundary.
-            # Its ring rows repeat the terminal round — dropped for the
-            # same reason.
-            if stats is not None:
-                # ccsa: ok[CCSA001] post-convergence drain: nothing left to
-                # pipeline behind this readback — the pass is over
-                stats.record(kind, int(cur[1]), donated=cur[4],
-                             speculative=True)
-            # ccsa: ok[CCSA001] post-convergence drain, same as above
-            flight.dispatch(kind, cur[2], int(cur[1]), 0, donated=cur[4],
-                            speculative=True, controller_k=controller.k)
+    with TRACER.span("solver.dispatch", route="bounded",
+                     kind=kind) as dispatch:
+        while True:
             cur = None
-        prev = cur
-        if prev is None and (converged or est_rounds >= pass_cap
-                             or (out_of_time is not None and out_of_time())):
-            break
+            may_enqueue = prev is None or async_readback
+            if may_enqueue and not converged and est_rounds < pass_cap \
+                    and not (out_of_time is not None and out_of_time()):
+                budget = controller.budget(pass_cap - est_rounds)
+                t0 = _time.monotonic()
+                with TRACER.span("solver.enqueue"):
+                    st, applied, r, donated, ring = enqueue(st, budget)
+                cur = (applied, r, budget, t0, donated, ring)
+                est_rounds += budget
+            if prev is not None:
+                applied_p, r_p, budget_p, t0_p, donated_p, ring_p = prev
+                with TRACER.span("solver.wait"):
+                    # ccsa: ok[CCSA001] THE pump readback: dispatch N's
+                    # scalars are read here exactly one enqueue behind —
+                    # N+1 is already in flight, so this block overlaps
+                    # device compute by design (the span opens where the
+                    # pump already waits; it adds no sync)
+                    r_read = int(r_p)                   # blocks on dispatch N
+                now = _time.monotonic()
+                start = t0_p if last_read_t is None else max(t0_p, last_read_t)
+                # ccsa: ok[CCSA001] same readback point: N already synced via
+                # r_read, this transfer is paid, not a new stall
+                applied_total += int(applied_p)
+                controller.observe(r_read, budget_p, now - start)
+                last_read_t = now
+                if stats is not None:
+                    stats.record(kind, r_read, donated=donated_p)
+                # ccsa: ok[CCSA001] same readback point, applied_p already read
+                flight.dispatch(kind, budget_p, r_read, int(applied_p),
+                                donated=donated_p, elapsed_s=now - start,
+                                controller_k=controller.k, ring=ring_p)
+                pass_rounds += r_read
+                est_rounds -= budget_p - r_read         # correct the estimate
+                if r_read < budget_p:
+                    converged = True
+            if converged and cur is not None:
+                # Speculative post-convergence dispatch: one re-run of the
+                # terminal zero-apply round (state frozen on device, applies
+                # nothing). Its rounds are NOT added to pass_rounds — they
+                # make no search progress, and counting them would consume
+                # cfg.max_rounds budget the synchronous per-round path does
+                # not pay, diverging the paths at the round-cap boundary.
+                # Its ring rows repeat the terminal round — dropped for the
+                # same reason.
+                if stats is not None:
+                    # ccsa: ok[CCSA001] post-convergence drain: nothing left to
+                    # pipeline behind this readback — the pass is over
+                    stats.record(kind, int(cur[1]), donated=cur[4],
+                                 speculative=True)
+                # ccsa: ok[CCSA001] post-convergence drain, same as above
+                flight.dispatch(kind, cur[2], int(cur[1]), 0, donated=cur[4],
+                                speculative=True, controller_k=controller.k)
+                cur = None
+            prev = cur
+            if prev is None and (converged or est_rounds >= pass_cap
+                                 or (out_of_time is not None and out_of_time())):
+                break
+        dispatch.set(rounds=pass_rounds)
     return st, applied_total, pass_rounds
 
 
@@ -1556,79 +1595,86 @@ def run_megabatch_pass(enqueue: Callable, st, active0, pass_cap: int,
     prev = None   # (applied, rounds, active_out, budget, t0, donated, ring)
     last_read_t = None
     converged = False
-    while True:
-        cur = None
-        may_enqueue = prev is None or async_readback
-        if may_enqueue and not converged and est_rounds < pass_cap:
-            budget = controller.budget(pass_cap - est_rounds)
-            t0 = _time.monotonic()
-            st, active_dev, applied, r, donated, ring = enqueue(
-                st, active_dev, budget)
-            cur = (applied, r, active_dev, budget, t0, donated, ring)
-            est_rounds += budget
-        if prev is not None:
-            applied_p, r_p, act_p, budget_p, t0_p, donated_p, ring_p = prev
-            # ccsa: ok[CCSA001] THE megabatch pump readback: dispatch N's
-            # per-cluster arrays are read here exactly one enqueue behind
-            # — N+1 is already in flight chained on N's output state and
-            # early-exit mask, so this block overlaps device compute
-            rounds_np = np.asarray(r_p)             # blocks on dispatch N
-            now = _time.monotonic()
-            start = t0_p if last_read_t is None else max(t0_p, last_read_t)
-            # ccsa: ok[CCSA001] same readback point: N already synced via
-            # rounds_np, these transfers are paid, not new stalls
-            applied_np = np.asarray(applied_p)
-            # ccsa: ok[CCSA001] same readback point (the early-exit mask
-            # the NEXT enqueue already consumed on device)
-            active_host = np.asarray(act_p).astype(bool)
-            # ccsa: ok[CCSA001] decode of the already-fetched host array
-            global_rounds = int(rounds_np.max()) if c else 0
-            applied_total += applied_np
-            rounds_total += rounds_np
-            controller.observe(global_rounds, budget_p, now - start)
-            last_read_t = now
-            if physical_stats is not None:
-                physical_stats.record(kind, global_rounds, donated=donated_p)
-            for b in range(c):
-                if rounds_np[b] <= 0:
-                    continue
-                if stats is not None:
-                    # ccsa: ok[CCSA001] per-cluster split of the paid
-                    # readback: host numpy scalar decodes only
-                    stats[b].record(kind, int(rounds_np[b]),
-                                    donated=donated_p, telemetry=False)
-                if flights is not None:
-                    # ccsa: ok[CCSA001] same split, host numpy decodes
-                    r_b, a_b = int(rounds_np[b]), int(applied_np[b])
-                    flights[b].dispatch(
-                        kind, budget_p, r_b, a_b, donated=donated_p,
-                        elapsed_s=now - start, controller_k=controller.k,
-                        ring=None if ring_p is None else ring_p[b])
-            est_rounds -= budget_p - global_rounds
-            if not active_host.any():
-                converged = True
-        if converged and cur is not None:
-            # Speculative post-convergence dispatch: every cluster entered
-            # inactive, so the batched while_loop ran zero rounds and the
-            # state is untouched — recorded, never counted.
-            if physical_stats is not None:
-                physical_stats.record(kind, 0, donated=cur[5],
-                                      speculative=True)
-            if flights is not None:
-                # Only clusters that PARTICIPATED in this pass get the
-                # speculative record — a goal-satisfied (or pad-slot)
-                # cluster that never activated records no dispatch at
-                # all, exactly like its serial solve.
-                for b in range(c):
-                    if entry_active[b]:
-                        flights[b].dispatch(kind, cur[3], 0, 0,
-                                            donated=cur[5],
-                                            speculative=True,
-                                            controller_k=controller.k)
+    with TRACER.span("solver.dispatch", route="megabatch",
+                     kind=kind) as dispatch:
+        while True:
             cur = None
-        prev = cur
-        if prev is None and (converged or est_rounds >= pass_cap):
-            break
+            may_enqueue = prev is None or async_readback
+            if may_enqueue and not converged and est_rounds < pass_cap:
+                budget = controller.budget(pass_cap - est_rounds)
+                t0 = _time.monotonic()
+                with TRACER.span("solver.enqueue"):
+                    st, active_dev, applied, r, donated, ring = enqueue(
+                        st, active_dev, budget)
+                cur = (applied, r, active_dev, budget, t0, donated, ring)
+                est_rounds += budget
+            if prev is not None:
+                applied_p, r_p, act_p, budget_p, t0_p, donated_p, ring_p = prev
+                with TRACER.span("solver.wait"):
+                    # ccsa: ok[CCSA001] THE megabatch pump readback: dispatch
+                    # N's per-cluster arrays are read here exactly one
+                    # enqueue behind — N+1 is already in flight chained on
+                    # N's output state and early-exit mask, so this block
+                    # overlaps device compute
+                    rounds_np = np.asarray(r_p)         # blocks on dispatch N
+                now = _time.monotonic()
+                start = t0_p if last_read_t is None else max(t0_p, last_read_t)
+                # ccsa: ok[CCSA001] same readback point: N already synced via
+                # rounds_np, these transfers are paid, not new stalls
+                applied_np = np.asarray(applied_p)
+                # ccsa: ok[CCSA001] same readback point (the early-exit mask
+                # the NEXT enqueue already consumed on device)
+                active_host = np.asarray(act_p).astype(bool)
+                # ccsa: ok[CCSA001] decode of the already-fetched host array
+                global_rounds = int(rounds_np.max()) if c else 0
+                applied_total += applied_np
+                rounds_total += rounds_np
+                controller.observe(global_rounds, budget_p, now - start)
+                last_read_t = now
+                if physical_stats is not None:
+                    physical_stats.record(kind, global_rounds, donated=donated_p)
+                for b in range(c):
+                    if rounds_np[b] <= 0:
+                        continue
+                    if stats is not None:
+                        # ccsa: ok[CCSA001] per-cluster split of the paid
+                        # readback: host numpy scalar decodes only
+                        stats[b].record(kind, int(rounds_np[b]),
+                                        donated=donated_p, telemetry=False)
+                    if flights is not None:
+                        # ccsa: ok[CCSA001] same split, host numpy decodes
+                        r_b, a_b = int(rounds_np[b]), int(applied_np[b])
+                        flights[b].dispatch(
+                            kind, budget_p, r_b, a_b, donated=donated_p,
+                            elapsed_s=now - start, controller_k=controller.k,
+                            ring=None if ring_p is None else ring_p[b])
+                est_rounds -= budget_p - global_rounds
+                if not active_host.any():
+                    converged = True
+            if converged and cur is not None:
+                # Speculative post-convergence dispatch: every cluster entered
+                # inactive, so the batched while_loop ran zero rounds and the
+                # state is untouched — recorded, never counted.
+                if physical_stats is not None:
+                    physical_stats.record(kind, 0, donated=cur[5],
+                                          speculative=True)
+                if flights is not None:
+                    # Only clusters that PARTICIPATED in this pass get the
+                    # speculative record — a goal-satisfied (or pad-slot)
+                    # cluster that never activated records no dispatch at
+                    # all, exactly like its serial solve.
+                    for b in range(c):
+                        if entry_active[b]:
+                            flights[b].dispatch(kind, cur[3], 0, 0,
+                                                donated=cur[5],
+                                                speculative=True,
+                                                controller_k=controller.k)
+                cur = None
+            prev = cur
+            if prev is None and (converged or est_rounds >= pass_cap):
+                break
+        # ccsa: ok[CCSA001] host numpy totals of reads already paid
+        dispatch.set(rounds=int(rounds_total.max()) if c else 0)
     return st, active_host, applied_total, rounds_total
 
 

@@ -129,20 +129,6 @@ def _apportioned_goal_results(goal_chain: Sequence[Goal], infos: list[dict],
         for g, info in zip(goal_chain, infos)]
 
 
-def _record_goal_spans(tracer, goal_results: Sequence[GoalResult],
-                       search_cfg: SearchConfig) -> None:
-    """Per-goal spans for the single-dispatch paths: the whole chain runs
-    in one XLA execution, so the goals' spans cannot be opened live —
-    they are attached after the fact with the same apportioned durations
-    GoalResult carries (attributes mark them as such)."""
-    for r in goal_results:
-        tracer.record_span(
-            "goal.solve", r.duration_s, goal=r.name, rounds=r.rounds,
-            moves_applied=r.moves_applied, succeeded=r.succeeded,
-            candidates=search_cfg.num_sources * search_cfg.num_dests,
-            apportioned=True)
-
-
 # Goals whose direct-transport arm stays ahead of greedy even at sparse
 # geometry (bench --transport, ROADMAP 2d): TR's [T, B] cell plane keeps
 # enough surplus per cell for the fractional plan to pay for itself,
@@ -699,7 +685,6 @@ class GoalOptimizer:
                 flight_pass.record_goal_infos(infos)
             goal_results = _apportioned_goal_results(
                 goal_chain, infos, time.time() - t0)
-            _record_goal_spans(TRACER, goal_results, search_cfg)
         elif self._fused_chain and not fast and (
                 self._fused_max_brokers == 0
                 or state.num_brokers <= self._fused_max_brokers):
@@ -714,7 +699,6 @@ class GoalOptimizer:
             flight_pass.record_goal_infos(infos)
             goal_results = _apportioned_goal_results(
                 goal_chain, infos, time.time() - t0)
-            _record_goal_spans(TRACER, goal_results, search_cfg)
         else:
             # Per-goal bounded-dispatch path: same kernels and trajectory,
             # ≤ solver.dispatch.max.rounds search rounds per XLA execution
@@ -847,7 +831,8 @@ class GoalOptimizer:
                                if r.violated_before]
         violated_after = [r.name for r in goal_results if not r.succeeded]
         with TRACER.span("analyzer.proposal_diff") as dsp:
-            stats_after = cluster_stats(state)
+            with TRACER.span("diff.stats"):
+                stats_after = cluster_stats(state)
             proposals = diff_proposals(initial, state, meta)
             dsp.set(num_proposals=len(proposals))
         _opt_span.set(num_proposals=len(proposals),

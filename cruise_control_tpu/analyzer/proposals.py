@@ -84,24 +84,33 @@ def diff_proposals(initial: ClusterTensors, final: ClusterTensors,
     """Set of ExecutionProposals for partitions whose replica set, order, or
     leader changed (AnalyzerUtils.getDiff)."""
     from ..common.resources import Resource
+    from ..utils.tracing import TRACER
+    from ..utils.xla_telemetry import record_transfer
 
-    a0 = np.asarray(initial.assignment)
-    a1 = np.asarray(final.assignment)
-    l0 = np.asarray(initial.leader_slot)
-    l1 = np.asarray(final.leader_slot)
-    mask = np.asarray(initial.partition_mask)
-    disk_mb = np.asarray(initial.leader_load[:, int(Resource.DISK)])
+    with TRACER.span("diff.fetch"):
+        a0 = np.asarray(initial.assignment)
+        a1 = np.asarray(final.assignment)
+        l0 = np.asarray(initial.leader_slot)
+        l1 = np.asarray(final.leader_slot)
+        mask = np.asarray(initial.partition_mask)
+        disk_mb = np.asarray(initial.leader_load[:, int(Resource.DISK)])
+        record_transfer(sum(x.nbytes for x in (a0, a1, l0, l1, mask,
+                                               disk_mb)),
+                        direction="d2h", source="proposal_diff")
 
-    changed = ((a0 != a1).any(axis=1) | (l0 != l1)) & mask
-    proposals: list[ExecutionProposal] = []
-    for p in np.nonzero(changed)[0]:
-        old_reps, old_leader = _ordered_replicas(a0[p], int(l0[p]), meta.broker_ids)
-        new_reps, new_leader = _ordered_replicas(a1[p], int(l1[p]), meta.broker_ids)
-        if old_reps == new_reps and old_leader == new_leader:
-            continue
-        topic, pnum = meta.partition_index[p]
-        proposals.append(ExecutionProposal(
-            topic=topic, partition=pnum, old_leader=old_leader,
-            old_replicas=old_reps, new_replicas=new_reps,
-            new_leader=new_leader, data_to_move_mb=float(disk_mb[p])))
+    with TRACER.span("diff.compare"):
+        changed = ((a0 != a1).any(axis=1) | (l0 != l1)) & mask
+        proposals: list[ExecutionProposal] = []
+        for p in np.nonzero(changed)[0]:
+            old_reps, old_leader = _ordered_replicas(a0[p], int(l0[p]),
+                                                     meta.broker_ids)
+            new_reps, new_leader = _ordered_replicas(a1[p], int(l1[p]),
+                                                     meta.broker_ids)
+            if old_reps == new_reps and old_leader == new_leader:
+                continue
+            topic, pnum = meta.partition_index[p]
+            proposals.append(ExecutionProposal(
+                topic=topic, partition=pnum, old_leader=old_leader,
+                old_replicas=old_reps, new_replicas=new_reps,
+                new_leader=new_leader, data_to_move_mb=float(disk_mb[p])))
     return proposals

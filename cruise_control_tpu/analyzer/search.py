@@ -178,6 +178,7 @@ def _conflict_free_top_m(score: jax.Array, partition: jax.Array,
     return top_idx, accept
 
 
+@jax.named_scope("round.select")
 def cumulative_select(state: ClusterTensors, deltas, score: jax.Array,
                       layout, m: int, moves_cap: int,
                       independent: bool | jax.Array, recheck,
@@ -364,6 +365,7 @@ def score_round_candidates(state: ClusterTensors, masks: ExclusionMasks,
     return cand, deltas, score, layout, (derived, aux, aux_by_goal)
 
 
+@jax.named_scope("round.apply")
 def apply_selected(state: ClusterTensors, sel: jax.Array, sel_p: jax.Array,
                    sel_slot: jax.Array, sel_dst_b: jax.Array,
                    sel_kind: jax.Array, sel_dst_slot: jax.Array,

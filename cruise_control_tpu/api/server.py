@@ -411,6 +411,12 @@ class CruiseControlApi:
                     cluster_id = self._fleet.cluster_id_of(self._cc)
                 cc = self._route_cluster(endpoint, cluster_id)
                 from ..utils.sensors import cluster_label
+                if cluster_id is not None:
+                    # The request's root span closes on the handler
+                    # thread, after this label has been left: the trace
+                    # takes its cluster from the root's attribute.
+                    from ..utils.tracing import TRACER
+                    TRACER.annotate_root(cluster=cluster_id)
                 with cluster_label(cluster_id):
                     body = self._dispatch(endpoint, params, principal,
                                           query_string, headers, out_headers,
@@ -736,6 +742,20 @@ class CruiseControlApi:
                 with j.seg("cache_store"):
                     self._response_cache.put(key, body)
                 return body
+
+        from ..utils.tracing import TRACER
+        parent = TRACER.current_span()
+        if parent is not None:
+            # The trace context crosses the pool the way the cluster label
+            # and the journey do, by re-entry inside the work callable:
+            # the task's spans keep this request's trace id on whichever
+            # thread runs them, also after a 202 has closed the root (a
+            # poll is its own http.request with the same userTaskId).
+            traced_inner = work
+
+            def work(inner=traced_inner, parent=parent):
+                with TRACER.attach(parent):
+                    return inner()
 
         info = self._tasks.get_or_create_task(
             endpoint.name, query_string, work,
@@ -1619,6 +1639,22 @@ def _as_text(value: Any, indent: int = 0) -> str:
     return f"{pad}{value}"
 
 
+_SCRAPE_PATHS = {"/metrics": "metrics", URL_PREFIX + "/metrics": "metrics",
+                 "/openapi": "openapi", URL_PREFIX + "/openapi": "openapi"}
+
+def _endpoint_label(method: str, path: str) -> str:
+    """The request's endpoint for the http.* spans: the endpoint's name,
+    the scrape surface, or OTHER (UI assets, unknown paths)."""
+    kind = _SCRAPE_PATHS.get(path) if method == "GET" else None
+    if kind is not None:
+        return kind.upper()
+    if path.startswith(URL_PREFIX):
+        endpoint = endpoint_for_path(path[len(URL_PREFIX):])
+        if endpoint is not None:
+            return endpoint.name
+    return "OTHER"
+
+
 class _Handler(BaseHTTPRequestHandler):
     api: CruiseControlApi  # set by make_server
 
@@ -1632,7 +1668,15 @@ class _Handler(BaseHTTPRequestHandler):
               content_type: str, extra: dict[str, str] | None = None) -> None:
         """The single response writer: every surface (API, scrapes, UI,
         errors) goes through here so HSTS, CORS, and the access log apply
-        uniformly."""
+        uniformly. Span ``http.write``: headers and body onto the socket;
+        status and bytes go onto the request's root span."""
+        from ..utils.tracing import TRACER
+        TRACER.annotate(status=status, bytes=len(data))
+        with self._http_span("http.write"):
+            self._write(method, t0, status, data, content_type, extra)
+
+    def _write(self, method: str, t0: float, status: int, data: bytes,
+               content_type: str, extra: dict[str, str] | None) -> None:
         self.send_response(status)
         self.send_header("Content-Type", content_type)
         self.send_header("Content-Length", str(len(data)))
@@ -1691,7 +1735,31 @@ class _Handler(BaseHTTPRequestHandler):
             return f.read(), self._UI_TYPES.get(ext,
                                                 "application/octet-stream")
 
+    # The request's endpoint; a class default so that ``_send`` works
+    # before ``_serve`` has set it.
+    _endpoint_label = "OTHER"
+
+    def _http_span(self, name: str, **attributes):
+        """An ``http.*`` span: the only spans that label their histogram
+        series with the endpoint, so that one endpoint's requests can be
+        read alone, and ``transient``: a request under which the program
+        opened no other span (a scrape, a UI asset, a poll) leaves its
+        histogram samples and no trace."""
+        from ..utils.tracing import TRACER
+        return TRACER.span(name, label_keys=("endpoint",), transient=True,
+                           endpoint=self._endpoint_label, **attributes)
+
     def _serve(self, method: str) -> None:
+        """One request, one trace: root span ``http.request`` from the
+        parsed request line to the last byte written, with children
+        ``http.handle`` (the pipeline), ``http.serialize`` (the body's
+        JSON text) and ``http.write`` (``_send``)."""
+        parsed = urllib.parse.urlparse(self.path)
+        self._endpoint_label = _endpoint_label(method, parsed.path)
+        with self._http_span("http.request", method=method) as root:
+            self._serve_traced(method, parsed, root)
+
+    def _serve_traced(self, method: str, parsed, root) -> None:
         t0 = time.time()
         cfg = self.api._config
         header_bytes = sum(len(k) + len(v) for k, v in self.headers.items())
@@ -1700,10 +1768,7 @@ class _Handler(BaseHTTPRequestHandler):
                 {"errorMessage": "request headers too large"}).encode(),
                 "application/json")
             return
-        parsed = urllib.parse.urlparse(self.path)
-        scrape_paths = {"/metrics": "metrics", URL_PREFIX + "/metrics": "metrics",
-                        "/openapi": "openapi", URL_PREFIX + "/openapi": "openapi"}
-        kind = scrape_paths.get(parsed.path) if method == "GET" else None
+        kind = _SCRAPE_PATHS.get(parsed.path) if method == "GET" else None
         ui = None
         if method == "GET" and kind is None:
             ui = self._ui_lookup(parsed.path)
@@ -1730,16 +1795,20 @@ class _Handler(BaseHTTPRequestHandler):
                 self._send(method, t0, 200, openapi_yaml().encode(),
                            "application/yaml")
             return
-        status, body, extra = self.api.handle(
-            method, parsed.path, parsed.query, dict(self.headers),
-            self.client_address[0])
-        if isinstance(body, dict) and "__text__" in body:
-            data = (body["__text__"] + "\n").encode()
-            content_type = extra.pop("Content-Type",
-                                     "text/plain; charset=utf-8")
-        else:
-            data = json.dumps(body, indent=2).encode()
-            content_type = extra.pop("Content-Type", "application/json")
+        with self._http_span("http.handle"):
+            status, body, extra = self.api.handle(
+                method, parsed.path, parsed.query, dict(self.headers),
+                self.client_address[0])
+        if USER_TASK_HEADER in extra:
+            root.set(userTaskId=extra[USER_TASK_HEADER])
+        with self._http_span("http.serialize"):
+            if isinstance(body, dict) and "__text__" in body:
+                data = (body["__text__"] + "\n").encode()
+                content_type = extra.pop("Content-Type",
+                                         "text/plain; charset=utf-8")
+            else:
+                data = json.dumps(body, indent=2).encode()
+                content_type = extra.pop("Content-Type", "application/json")
         self._send(method, t0, status, data, content_type, extra)
 
     def do_GET(self) -> None:  # noqa: N802 (http.server API)

@@ -66,10 +66,12 @@ _EXECUTION_OVERRIDES: contextvars.ContextVar[tuple] = \
 
 
 def _traced_op(name: str):
-    """Root-span wrapper for the operation runnables: each facade
-    operation becomes one trace (operation attribute = runnable name;
-    cluster attribution comes from the ambient sensor label). Child
-    spans — aggregate, model assembly, per-goal solve, execution — open
+    """Span wrapper for the operation runnables (operation attribute =
+    runnable name; cluster attribution comes from the ambient sensor
+    label): a child of the served request's ``http.handle`` where the
+    api layer re-entered the request's span on this worker, the root of
+    its own trace otherwise (library callers, detectors). Child spans —
+    aggregate, model assembly, solver dispatch, execution — open
     contextvar-deep with no plumbing."""
     import functools
 

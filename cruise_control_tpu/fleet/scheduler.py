@@ -73,6 +73,9 @@ class SolverJob:
     # routed through the scheduler re-enters its heal scope on the
     # worker thread and attributes its queue wait to the chain.
     heal: Any = None
+    # The submitter's ambient trace span (None outside any): the worker
+    # re-enters it, so a served request's fleet.job stays in its trace.
+    parent_span: Any = None
 
 
 class FleetScheduler:
@@ -156,12 +159,14 @@ class FleetScheduler:
                fn: Callable[[], Any], batch_key: tuple | None = None,
                payload: Any = None) -> Future:
         from ..utils.heal_ledger import current_heal
+        from ..utils.tracing import TRACER
         heal = current_heal()
         job = SolverJob(kind=kind, cluster_id=cluster_id, fn=fn,
                         future=Future(), enqueued_at=self._clock(),
                         seq=self._next_seq(), batch_key=batch_key,
                         payload=payload,
-                        heal=heal if heal.recording else None)
+                        heal=heal if heal.recording else None,
+                        parent_span=TRACER.current_span())
         with self._cond:
             if self._shut:
                 # After shutdown nothing drains the queue; a queued job's
@@ -327,13 +332,15 @@ class FleetScheduler:
                            waitS=round(wait_s, 6))
         t0 = time.monotonic()
         try:
-            # The job's own operation trace (the facade op opens the root
-            # span) gets the queue wait attached via the wrapping span —
-            # worker threads have no ambient parent, so fleet.job IS the
-            # root and the op span nests under it. The heal scope is
-            # re-entered explicitly: ContextVars do not cross into the
-            # worker thread.
+            # The wrapping span carries the queue wait. A job submitted
+            # under a span (a served request's engine worker) stays in
+            # that trace: the submitter's span is re-entered here like the
+            # heal scope, because ContextVars do not cross into the worker
+            # thread. A job nobody's request caused (the pacer's
+            # precomputes, a heal) has no parent, so fleet.job IS the root
+            # and the op span nests under it.
             with cluster_label(job.cluster_id), \
+                    TRACER.attach(job.parent_span), \
                     TRACER.span("fleet.job", operation=f"fleet.{job.kind.name.lower()}",
                                 cluster=job.cluster_id, kind=job.kind.name,
                                 queue_wait_s=round(wait_s, 6)), \

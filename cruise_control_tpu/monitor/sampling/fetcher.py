@@ -92,23 +92,34 @@ class MetricFetcherManager:
                                         thread_name_prefix="metric-fetcher")
         self._lock = threading.Lock()
 
-    def fetch_metric_samples(self, partitions: Mapping[tuple[str, int], PartitionState],
-                             start_ms: int, end_ms: int,
-                             store: bool = True) -> SamplerResult:
+    def fetch_metric_samples(
+            self,
+            describe: Callable[[], Mapping[tuple[str, int], PartitionState]],
+            start_ms: int, end_ms: int,
+            store: bool = True) -> SamplerResult:
+        """One sampling round under span ``monitor.sample_fetch``, split
+        where the work happens: ``sampling.describe`` (``describe()``, the
+        metadata read that names the partitions, so the round's span
+        covers it), ``sampling.get_samples`` (the sampler plug-ins'
+        fan-out, until the last fetcher returns) and ``sampling.ingest``
+        (aggregators and sample store)."""
         from ...utils.tracing import TRACER
         with TRACER.span("monitor.sample_fetch", operation="sampling",
-                         num_partitions=len(partitions),
                          num_fetchers=len(self._samplers)) as sp:
-            buckets = self._assignor(partitions, len(self._samplers))
-            futures = [self._pool.submit(self._fetch_one, s, b,
-                                         start_ms, end_ms)
-                       for s, b in zip(self._samplers, buckets)]
-            merged = SamplerResult([], [], 0)
-            for f in futures:
-                r = f.result()
-                merged.partition_samples.extend(r.partition_samples)
-                merged.broker_samples.extend(r.broker_samples)
-                merged.skipped_partitions += r.skipped_partitions
+            with TRACER.span("sampling.describe"):
+                partitions = describe()
+            sp.set(num_partitions=len(partitions))
+            with TRACER.span("sampling.get_samples"):
+                buckets = self._assignor(partitions, len(self._samplers))
+                futures = [self._pool.submit(self._fetch_one, s, b,
+                                             start_ms, end_ms)
+                           for s, b in zip(self._samplers, buckets)]
+                merged = SamplerResult([], [], 0)
+                for f in futures:
+                    r = f.result()
+                    merged.partition_samples.extend(r.partition_samples)
+                    merged.broker_samples.extend(r.broker_samples)
+                    merged.skipped_partitions += r.skipped_partitions
             total = len(partitions)
             completeness = 1.0 if total == 0 \
                 else 1.0 - merged.skipped_partitions / total
@@ -127,7 +138,8 @@ class MetricFetcherManager:
                 from ...utils.sensors import SENSORS
                 SENSORS.count("monitor_partial_windows")
                 sp.set(partial=True)
-            self._ingest(merged, end_ms, store)
+            with TRACER.span("sampling.ingest"):
+                self._ingest(merged, end_ms, store)
             sp.set(partition_samples=len(merged.partition_samples),
                    broker_samples=len(merged.broker_samples),
                    skipped_partitions=merged.skipped_partitions,

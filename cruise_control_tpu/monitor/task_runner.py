@@ -128,8 +128,10 @@ class LoadMonitorTaskRunner:
                 self._state = RunnerState.SAMPLING
         from .sampling.fetcher import PartialWindowError
         try:
-            partitions = self._metadata.describe_partitions()
-            self._fetcher.fetch_metric_samples(partitions, start, end)
+            # The describe runs inside the fetch's span (as its first
+            # child), so one span times the whole sampling round.
+            self._fetcher.fetch_metric_samples(
+                self._metadata.describe_partitions, start, end)
             self._last_sample_ms = end
         except PartialWindowError as e:
             # The window is below the completeness floor and LOST either
@@ -156,13 +158,14 @@ class LoadMonitorTaskRunner:
         try:
             if clear_metrics:
                 self._fetcher.clear()
+            # one metadata read serves every window of the replay
             partitions = self._metadata.describe_partitions()
             t = start_ms
             while t < end_ms and not self._stop.is_set():
                 nxt = min(t + self._interval_ms, end_ms)
                 try:
-                    self._fetcher.fetch_metric_samples(partitions, t, nxt,
-                                                       store=False)
+                    self._fetcher.fetch_metric_samples(
+                        lambda: partitions, t, nxt, store=False)
                 except Exception:  # noqa: BLE001 — one bad window (e.g.
                     # below the partial-completeness floor, or a range
                     # predating available metrics) must not abort the

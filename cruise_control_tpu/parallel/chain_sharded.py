@@ -43,7 +43,7 @@ from ..analyzer.agg import (
 from ..analyzer.chain import (
     _chain_infos_from_stats, _gated_aux, _goal_flags, _switch_scores,
     _switch_swap_dest_score, _switch_target_dests,
-    excluded_hosting_replicas,
+    excluded_hosting_replicas, set_dispatch_rounds,
 )
 from ..analyzer.constraint import BalancingConstraint
 from ..analyzer.derived import compute_derived
@@ -330,8 +330,9 @@ def _chain_round_local(state: ClusterTensors, agg, masks: ExclusionMasks,
     # every device, so its sum is already the global count, and the
     # aggregate-carry update below stays replicated device-for-device.
     if agg is not None:
-        agg = apply_deltas_to_agg(agg, ranked, sel, g_pot[order],
-                                  g_lbi[order])
+        with jax.named_scope("round.apply"):
+            agg = apply_deltas_to_agg(agg, ranked, sel, g_pot[order],
+                                      g_lbi[order])
     new_state = apply_selected(state, sel, ranked.partition,
                                ranked.src_slot, ranked.dst_broker,
                                g_kind[order], g_dslot[order],
@@ -339,6 +340,7 @@ def _chain_round_local(state: ClusterTensors, agg, masks: ExclusionMasks,
     return new_state, agg, sel.sum()
 
 
+@jax.named_scope("swap.round")
 def _chain_swap_local(state: ClusterTensors, agg, masks: ExclusionMasks,
                       active_idx: jax.Array, prior_mask: jax.Array, *,
                       goals, constraint: BalancingConstraint, num_topics: int,
@@ -554,6 +556,7 @@ def _chain_swap_local(state: ClusterTensors, agg, masks: ExclusionMasks,
     return dataclasses.replace(state, assignment=new_assignment), agg, sel.sum()
 
 
+@jax.named_scope("goal.stats")
 def _chain_stats_local(state: ClusterTensors, masks: ExclusionMasks,
                        active_idx: jax.Array, *, goals,
                        constraint: BalancingConstraint, num_topics: int):
@@ -754,9 +757,15 @@ def optimize_chain_sharded(state: ClusterTensors, chain,
             flight=flight)
     fn = _make_chain_full(mesh, goals, constraint, cfg, num_topics, presence,
                           swap_moves, swap_max_rounds)
-    state, stats_dev = fn(state, masks)
-    stats_dev = {k: jax.device_get(v) for k, v in stats_dev.items()}
-    return state, _chain_infos_from_stats(goals, stats_dev)
+    from ..utils.tracing import TRACER
+    with TRACER.span("solver.dispatch", route="mesh") as dispatch_span:
+        with TRACER.span("solver.enqueue"):
+            state, stats_dev = fn(state, masks)
+        with TRACER.span("solver.wait"):
+            stats_dev = {k: jax.device_get(v) for k, v in stats_dev.items()}
+        infos = _chain_infos_from_stats(goals, stats_dev)
+        set_dispatch_rounds(dispatch_span, infos)
+    return state, infos
 
 
 @lru_cache(maxsize=64)

@@ -43,6 +43,7 @@ import time
 from typing import Callable
 
 from ..utils.sensors import SENSORS
+from ..utils.tracing import annotation
 
 _AMBIENT: contextvars.ContextVar["Journey | None"] = \
     contextvars.ContextVar("journey_current", default=None)
@@ -92,9 +93,12 @@ NO_JOURNEY = _NullJourney()
 
 class _SegmentScope:
     """Times a ``with`` block into one journey segment. ``set()``
-    attaches attrs before close (cache hit, verdict, pass ids)."""
+    attaches attrs before close (cache hit, verdict, pass ids). The
+    block is also a ``cc.<segment>`` event of a running profiler capture
+    (the spans' helper), so the segment IS the measurement of its
+    boundary on the device trace's clock too."""
 
-    __slots__ = ("_journey", "_name", "_attrs", "_t0")
+    __slots__ = ("_journey", "_name", "_attrs", "_t0", "_annotation")
 
     def __init__(self, journey: "Journey", name: str, attrs: dict):
         self._journey = journey
@@ -103,9 +107,12 @@ class _SegmentScope:
 
     def __enter__(self) -> "_SegmentScope":
         self._t0 = self._journey.now()
+        self._annotation = annotation(self._name)
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        self._annotation.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self._attrs.setdefault("error", exc_type.__name__)
         self._journey.add(self._name,

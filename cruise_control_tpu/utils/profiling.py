@@ -7,6 +7,12 @@ precomputes — into a Perfetto/TensorBoard trace directory the operator
 pulls off the host (or CI uploads as an artifact). This is the live
 sibling of the offline marginal tools: span tracing (utils.tracing) says
 WHICH stage was slow, the profiler says which op inside the XLA program.
+The capture holds both on one clock (every live span is a ``cc.<name>``
+host event), and the response carries their reduction under ``summary``
+(utils.profile_summary): device busy / idle, device seconds by XLA
+program and by named scope of the round body, idle seconds by the
+program span that covers each gap — so the operator reads the answer
+and need not pull the trace off the host.
 
 Single-flight discipline: ``jax.profiler`` is process-global state — two
 overlapping ``start_trace`` calls corrupt each other — so capture runs
@@ -66,7 +72,9 @@ class DeviceProfiler:
                 max_duration_s: float = 60.0) -> dict:
         """Record ``duration_s`` of live device activity into a
         timestamped subdirectory of ``trace_dir``. Returns the trace
-        location + captured file listing."""
+        location + captured file listing + ``summary`` (None where no
+        operation ran on a device, as on the CPU backend) + ``summaryS``,
+        the seconds the reduction took."""
         duration = min(max(float(duration_s), 0.05), max_duration_s)
         self._acquire(duration)
         try:
@@ -79,8 +87,14 @@ class DeviceProfiler:
                 trace_dir, time.strftime("trace_%Y%m%d_%H%M%S")
                 + f"_{self._dir_seq:03d}")
             os.makedirs(out_dir, exist_ok=True)
+            # The interpreter's frames stay out of the capture: hooking
+            # every Python call slows the host work whose share of the
+            # idle time the summary reports, and the program's own spans
+            # are what it is read by.
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
             t0 = time.monotonic()
-            jax.profiler.start_trace(out_dir)
+            jax.profiler.start_trace(out_dir, profiler_options=options)
             try:
                 time.sleep(duration)
             finally:
@@ -98,10 +112,22 @@ class DeviceProfiler:
             from .sensors import SENSORS
             SENSORS.count("profiling_captures")
             SENSORS.record_timer("profiling_capture", elapsed)
+            # The reduction is pure Python over the whole capture, in
+            # this process and under the single-flight gate: its seconds
+            # are the operator's to see.
+            from .profile_summary import summarize
+            t1 = time.monotonic()
+            try:
+                summary = summarize(out_dir)
+            except Exception:  # noqa: BLE001 — the capture itself stands
+                LOG.exception("profile summary of %s failed", out_dir)
+                summary = None
+            summary_s = time.monotonic() - t1
             return {"traceDir": out_dir, "durationS": round(duration, 3),
                     "elapsedS": round(elapsed, 3),
                     "numFiles": len(files), "totalBytes": total,
-                    "files": sorted(files, key=lambda f: f["path"])}
+                    "files": sorted(files, key=lambda f: f["path"]),
+                    "summary": summary, "summaryS": round(summary_s, 3)}
         finally:
             self._lock.release()
 

@@ -12,12 +12,25 @@ Design points:
 - **Contextvar propagation** (the same pattern as ``sensors.cluster_label``
   and ``progress.OperationProgress``): deep layers open child spans with
   no plumbing; a span opened on a worker thread with no ambient parent
-  becomes its own trace root (the fleet scheduler's jobs, the executor's
-  run thread, the background sampling loop).
+  becomes its own trace root (the executor's run thread, the background
+  sampling loop). A request keeps ONE trace across thread pools:
+  ``attach(parent)`` re-establishes the ambient span inside the
+  task-engine worker and the fleet worker, at the sites that re-enter
+  the cluster label, the journey and the heal scope.
+- **One clock with the device trace**: a live span also enters a
+  ``jax.profiler.TraceAnnotation`` named ``cc.<span name>`` (and so does
+  a journey segment, through ``annotation()``), so a profiler capture
+  holds the program's spans beside the device's ops. Durations come from
+  ``time.monotonic_ns()``; the exported ``startTimeUnixNano`` adds one
+  wall-clock anchor read at import.
 - **Bounded ring** of recent traces, served by ``GET
   /kafkacruisecontrol/trace`` as OTLP-compatible JSON span trees
   (traceId/spanId/parentSpanId/startTimeUnixNano/attributes key-value
-  shape), filterable by cluster and operation.
+  shape), filterable by cluster and operation. A trace enters it when
+  it is whole: its root has closed and the work attached to it on other
+  threads has ended. A tree of ``transient`` spans alone (the ``http.*``
+  spans of a scrape, a UI asset or a poll) never enters it, so a scraper
+  does not turn the ring over.
 - **Automatic histograms**: every span close records into the
   ``trace_span_seconds`` histogram (one series per span name, ambient
   cluster label applies) so ``/metrics`` grows a ``_bucket`` latency
@@ -45,6 +58,25 @@ import contextvars
 _CURRENT: contextvars.ContextVar["Span | None"] = \
     contextvars.ContextVar("trace_current_span", default=None)
 
+# Unix nanoseconds at monotonic zero: spans are timed on the monotonic
+# clock (a stepped wall clock cannot shorten or lengthen them) and placed
+# on the wall clock only for export.
+_WALL_ANCHOR_NS = time.time_ns() - time.monotonic_ns()
+
+ANNOTATION_PREFIX = "cc."
+_TRACE_ANNOTATION = None
+
+
+def annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named ``cc.<name>``: the one way
+    the program's spans and journey segments enter a profiler capture.
+    With no profiler session entering it is a branch on an atomic."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION(ANNOTATION_PREFIX + name)
+
 # Monotone span-id source; thread-safe in CPython (single bytecode next()).
 _IDS = itertools.count(1)
 
@@ -54,19 +86,30 @@ SPAN_HISTOGRAM = "trace_span_seconds"
 class Span:
     """One timed, attributed node of a trace tree."""
 
-    __slots__ = ("name", "span_id", "parent", "trace_id", "start_ns",
-                 "end_ns", "attributes", "children")
+    __slots__ = ("name", "span_id", "parent", "root", "trace_id",
+                 "start_ns", "end_ns", "attributes", "children",
+                 "label_keys", "transient", "pending", "published")
 
     def __init__(self, name: str, parent: "Span | None"):
         self.name = name
         self.parent = parent
+        self.root = parent.root if parent is not None else self
         self.span_id = f"{next(_IDS):016x}"
         self.trace_id = parent.trace_id if parent is not None \
             else f"{next(_IDS):032x}"
-        self.start_ns = time.time_ns()
+        self.start_ns = time.monotonic_ns()
         self.end_ns = 0
         self.attributes: dict = {}
         self.children: list[Span] = []
+        # Attributes that also label the span's histogram series (the
+        # http.* spans' endpoint; empty for every other span).
+        self.label_keys: tuple[str, ...] = ()
+        # transient: this span alone does not make a trace worth keeping.
+        # On a root only: pending, the work attached on other threads
+        # that has not ended; published, the trace is in the ring.
+        self.transient = False
+        self.pending = 0
+        self.published = False
 
     def set(self, **attributes) -> None:
         """Attach attributes (goal name, candidate count, transfer bytes…)."""
@@ -84,8 +127,8 @@ class Span:
             "spanId": self.span_id,
             "parentSpanId": self.parent.span_id if self.parent else "",
             "name": self.name,
-            "startTimeUnixNano": str(self.start_ns),
-            "endTimeUnixNano": str(self.end_ns),
+            "startTimeUnixNano": str(self.start_ns + _WALL_ANCHOR_NS),
+            "endTimeUnixNano": str(self.end_ns + _WALL_ANCHOR_NS),
             "durationMs": round((self.end_ns - self.start_ns) / 1e6, 3),
             "attributes": [{"key": k, "value": _otlp_value(v)}
                            for k, v in self.attributes.items()],
@@ -125,21 +168,28 @@ _NULL = _NullSpan()
 
 class _SpanScope:
     """Live span context manager: opens on enter, closes (histogram +
-    trace completion) on exit. Exceptions mark the span and propagate."""
+    trace completion) on exit. Exceptions mark the span and propagate.
+    The span is also a ``cc.<name>`` event of a running profiler capture."""
 
-    __slots__ = ("_tracer", "_span", "_token")
+    __slots__ = ("_tracer", "_span", "_token", "_annotation")
 
-    def __init__(self, tracer: "Tracer", name: str, attributes: dict):
+    def __init__(self, tracer: "Tracer", name: str, attributes: dict,
+                 label_keys: tuple[str, ...] = (), transient: bool = False):
         self._tracer = tracer
         self._span = Span(name, _CURRENT.get())
+        self._span.label_keys = label_keys
+        self._span.transient = transient
         if attributes:
             self._span.attributes.update(attributes)
 
     def __enter__(self) -> Span:
         self._token = _CURRENT.set(self._span)
+        self._annotation = annotation(self._span.name)
+        self._annotation.__enter__()
         return self._span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        self._annotation.__exit__(exc_type, exc, tb)
         _CURRENT.reset(self._token)
         if exc_type is not None:
             self._span.attributes.setdefault("error", exc_type.__name__)
@@ -147,29 +197,65 @@ class _SpanScope:
         return False
 
 
+class _Attach:
+    """Re-establishes ``parent`` as the ambient span on another thread:
+    ContextVars do not cross a thread pool, so the work callable carries
+    the span that caused it and re-enters it where it runs. While it is
+    entered the trace is not complete, whether or not its root has
+    closed (the task of a request answered 202)."""
+
+    __slots__ = ("_tracer", "_parent", "_token")
+
+    def __init__(self, tracer: "Tracer", parent: Span):
+        self._tracer = tracer
+        self._parent = parent
+
+    def __enter__(self) -> Span:
+        self._tracer._pending(self._parent.root, 1)
+        self._token = _CURRENT.set(self._parent)
+        return self._parent
+
+    def __exit__(self, *exc) -> bool:
+        _CURRENT.reset(self._token)
+        self._tracer._pending(self._parent.root, -1)
+        return False
+
+
 class Trace:
     """A completed span tree plus its routing metadata."""
 
-    __slots__ = ("root", "operation", "operations", "cluster", "span_count")
+    __slots__ = ("root", "operation", "cluster")
 
-    def __init__(self, root: Span, cluster: str | None, span_count: int):
+    def __init__(self, root: Span):
         self.root = root
         self.operation = str(root.attributes.get("operation", root.name))
-        # EVERY operation attribute in the tree, for filtering: a
-        # fleet-routed request's root is the scheduler's "fleet.on_demand"
-        # wrapper span with the actual runnable ("rebalance") nested one
-        # level down — ?operation=rebalance must still find it.
+        # The root's own attribute: a served request's root closes on the
+        # handler thread, outside the label its work ran under, so the
+        # front door writes the cluster it routed to (annotate_root);
+        # every other root takes the ambient label when it closes.
+        self.cluster = root.attributes.get("cluster")
+
+    @property
+    def operations(self) -> frozenset:
+        """EVERY operation attribute in the tree, for filtering: a served
+        request's root is ``http.request`` (a precompute's, the
+        scheduler's ``fleet.job``) with the actual runnable ("rebalance")
+        nested below — ?operation=rebalance must still find it. Read when
+        asked: the task of a request answered 202 closes its spans into
+        the tree after the root has closed."""
         ops = {self.operation}
-        stack = [root]
+        stack = [self.root]
         while stack:
             s = stack.pop()
             op = s.attributes.get("operation")
             if op is not None:
                 ops.add(str(op))
             stack.extend(s.children)
-        self.operations = frozenset(ops)
-        self.cluster = cluster
-        self.span_count = span_count
+        return frozenset(ops)
+
+    @property
+    def span_count(self) -> int:
+        return _count_spans(self.root)
 
     def to_dict(self) -> dict:
         return {
@@ -177,7 +263,7 @@ class Trace:
             "operation": self.operation,
             "operations": sorted(self.operations),
             "cluster": self.cluster,
-            "startTimeUnixNano": str(self.root.start_ns),
+            "startTimeUnixNano": str(self.root.start_ns + _WALL_ANCHOR_NS),
             "durationMs": round(
                 (self.root.end_ns - self.root.start_ns) / 1e6, 3),
             "spanCount": self.span_count,
@@ -238,25 +324,25 @@ class Tracer:
                 self._jsonl_max_files = max(1, int(jsonl_max_files))
 
     # -- recording ---------------------------------------------------------
-    def span(self, name: str, **attributes):
+    def span(self, name: str, label_keys: tuple[str, ...] = (),
+             transient: bool = False, **attributes):
         """Open a child span of the ambient span (or a new trace root).
-        Returns a context manager yielding the Span (``.set(**attrs)``)."""
+        Returns a context manager yielding the Span (``.set(**attrs)``).
+        ``label_keys`` names attributes that also label the span's
+        ``trace_span_seconds`` series. A trace whose spans are all
+        ``transient`` (the ``http.*`` spans of a scrape, a UI asset or a
+        poll, under which the program did nothing it times) feeds the
+        histogram and stays out of the ring and the dump."""
         if not self._enabled:
             return _NULL
-        return _SpanScope(self, name, attributes)
+        return _SpanScope(self, name, attributes, label_keys, transient)
 
-    def record_span(self, name: str, duration_s: float, **attributes) -> None:
-        """Attach an ALREADY-TIMED child span to the ambient span (the
-        fused-chain path: per-goal wall-clock is apportioned after one
-        device dispatch, so the goals' spans cannot be opened live)."""
-        if not self._enabled:
-            return
-        parent = _CURRENT.get()
-        span = Span(name, parent)
-        span.end_ns = time.time_ns()
-        span.start_ns = span.end_ns - int(duration_s * 1e9)
-        span.attributes.update(attributes)
-        self._close(span)
+    def attach(self, parent: "Span | None"):
+        """Context manager that makes ``parent`` (a span captured on the
+        thread that caused the work) the ambient span of this thread."""
+        if parent is None or not self._enabled:
+            return _NULL
+        return _Attach(self, parent)
 
     def annotate(self, **attributes) -> None:
         """Attach attributes to the ambient span; no-op outside one (deep
@@ -267,24 +353,51 @@ class Tracer:
         if span is not None:
             span.attributes.update(attributes)
 
+    def annotate_root(self, **attributes) -> None:
+        """Attach attributes to the root of the ambient span's trace."""
+        if not self._enabled:
+            return
+        span = _CURRENT.get()
+        if span is not None:
+            span.root.attributes.update(attributes)
+
     def current_span(self) -> Span | None:
         return _CURRENT.get()
 
     def _close(self, span: Span) -> None:
-        if not span.end_ns:
-            span.end_ns = time.time_ns()
-        SENSORS.observe(SPAN_HISTOGRAM, span.duration_s,
-                        labels={"span": span.name})
+        span.end_ns = time.monotonic_ns()
+        labels = {"span": span.name}
+        for key in span.label_keys:
+            labels[key] = str(span.attributes.get(key, ""))
+        SENSORS.observe(SPAN_HISTOGRAM, span.duration_s, labels=labels)
+        with self._lock:
+            self.spans_closed += 1
         parent = span.parent
         if parent is not None:
             parent.children.append(span)
-            with self._lock:
-                self.spans_closed += 1
             return
-        trace = Trace(span, current_cluster_label(),
-                      span_count=_count_spans(span))
+        cluster = current_cluster_label()
+        if cluster is not None:
+            span.attributes.setdefault("cluster", cluster)
+        self._publish(span)
+
+    def _pending(self, root: Span, step: int) -> None:
         with self._lock:
-            self.spans_closed += 1
+            root.pending += step
+        if step < 0:
+            self._publish(root)
+
+    def _publish(self, root: Span) -> None:
+        """Ring and dump a trace, once, when it is whole: its root has
+        closed and no attached work is still running (so the dump's line
+        of a request answered 202 holds its task's spans too)."""
+        with self._lock:
+            if root.published or root.pending or not root.end_ns:
+                return
+            if root.transient and _all_transient(root):
+                return
+            root.published = True
+            trace = Trace(root)
             self.traces_completed += 1
             self._ring.append(trace)
             path = self._jsonl_path
@@ -352,6 +465,16 @@ class Tracer:
     def clear(self) -> None:
         with self._lock:
             self._ring.clear()
+
+
+def _all_transient(root: Span) -> bool:
+    stack = [root]
+    while stack:
+        s = stack.pop()
+        if not s.transient:
+            return False
+        stack.extend(s.children)
+    return True
 
 
 def _count_spans(span: Span) -> int:

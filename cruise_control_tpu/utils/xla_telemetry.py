@@ -9,7 +9,15 @@ same ``/metrics`` scrape (ambient per-cluster labels apply):
 
 - **Compilation**: ``jax.monitoring`` event listeners record every XLA
   backend compile (count + seconds, histogram ``xla_compile_seconds``)
-  and persistent-cache hits/misses. Compiles are labeled with the padded
+  and persistent-cache hits/misses, and the seconds jax spends BEFORE
+  the backend on every first call of a shape: tracing the Python function
+  to a jaxpr (``xla_trace_seconds``) and lowering the jaxpr to an MLIR
+  module (``xla_lower_seconds``) — what a persistent-cache hit does not
+  skip. jax reports a nested event inside its caller's duration too (a
+  jit called by a traced function, the compile of a constant computed
+  while tracing), so these two histograms hold each event's OWN seconds,
+  its nested events taken out: trace + lower + compile add up to the
+  seconds jax spent, each counted once. All are labeled with the padded
   bucket shape ambient at dispatch time (``shape_scope``), so a
   shape-flap recompile storm shows up as new ``shape=`` series — proving
   or disproving the bucket-hysteresis fix.
@@ -52,14 +60,23 @@ _SHAPE: contextvars.ContextVar[str | None] = \
     contextvars.ContextVar("xla_shape_label", default=None)
 
 _BACKEND_COMPILE_SUFFIX = "backend_compile_duration"
+_JAXPR_TRACE_SUFFIX = "jaxpr_trace_duration"
+_JAXPR_LOWER_SUFFIX = "jaxpr_to_mlir_module_duration"
 _EVENT_COUNTERS = {
     "/jax/compilation_cache/cache_hits": "xla_compile_cache_hits",
     "/jax/compilation_cache/cache_misses": "xla_compile_cache_misses",
 }
 
+_NESTING_SUFFIXES = (_BACKEND_COMPILE_SUFFIX, _JAXPR_TRACE_SUFFIX,
+                     _JAXPR_LOWER_SUFFIX)
+
 _install_lock = threading.Lock()
 _installed = False
 _enabled = True
+
+# Per thread, the trace / lower / compile events jax has begun and not
+# ended, innermost last: [event, seconds of the events nested in it].
+_OPEN = threading.local()
 
 
 @contextmanager
@@ -73,17 +90,54 @@ def shape_scope(num_partitions: int, num_brokers: int):
         _SHAPE.reset(token)
 
 
+def _on_scalar(event: str, value: float, **kwargs) -> None:
+    """jax announces the start of a timed event with a scalar of the same
+    name: that opens a level of nesting on this thread."""
+    if _enabled and event.endswith(_NESTING_SUFFIXES):
+        stack = getattr(_OPEN, "stack", None)
+        if stack is None:
+            stack = _OPEN.stack = []
+        stack.append([event, 0.0])
+
+
+def _own_seconds(event: str, duration_secs: float) -> float:
+    """Close the innermost open ``event`` of this thread: its duration
+    less what ran nested in it, and the whole of it charged to the event
+    that encloses it. An event whose start was not seen keeps all."""
+    stack = getattr(_OPEN, "stack", None) or []
+    nested = 0.0
+    for i in range(len(stack) - 1, -1, -1):
+        if stack[i][0] == event:
+            nested = stack[i][1]
+            del stack[i:]
+            break
+    if stack:
+        stack[-1][1] += duration_secs
+    return max(0.0, duration_secs - nested)
+
+
 def _on_event_duration(event: str, duration_secs: float, **kwargs) -> None:
     if not _enabled:
         return
     try:
+        labels = {"shape": _SHAPE.get() or "unscoped"}
+        if event.endswith(_NESTING_SUFFIXES):
+            own_secs = _own_seconds(event, duration_secs)
         if event.endswith(_BACKEND_COMPILE_SUFFIX):
-            labels = {"shape": _SHAPE.get() or "unscoped"}
             SENSORS.count("xla_compile_events", labels=labels)
             # Histogram ONLY — a timer named xla_compile would render the
             # same xla_compile_seconds_sum/_count family twice and
             # Prometheus rejects duplicate-sample scrapes outright.
             SENSORS.observe("xla_compile_seconds", duration_secs,
+                            labels=labels, buckets=COMPILE_BUCKETS)
+        elif event.endswith(_JAXPR_TRACE_SUFFIX):
+            # Python traced to a jaxpr: paid on every first call of a
+            # shape, persistent-cache hit or not.
+            SENSORS.observe("xla_trace_seconds", own_secs,
+                            labels=labels, buckets=COMPILE_BUCKETS)
+        elif event.endswith(_JAXPR_LOWER_SUFFIX):
+            # ... and the jaxpr lowered to an MLIR module, likewise.
+            SENSORS.observe("xla_lower_seconds", own_secs,
                             labels=labels, buckets=COMPILE_BUCKETS)
         elif event.endswith("cache_retrieval_time_sec"):
             # Persistent-cache hit: the retrieval that REPLACED a compile.
@@ -116,6 +170,7 @@ def install(enabled: bool = True) -> bool:
             return _installed
         from jax import monitoring
         monitoring.register_event_duration_secs_listener(_on_event_duration)
+        monitoring.register_scalar_listener(_on_scalar)
         monitoring.register_event_listener(_on_event)
         _installed = True
         return True

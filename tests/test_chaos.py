@@ -437,7 +437,7 @@ def test_fetcher_accepts_partial_window_above_floor():
     mgr = MetricFetcherManager(
         [SyntheticSampler(), _FailingSampler()], pagg, bagg, _NullStore(),
         assignor=_split_assignor, min_completeness=0.25)
-    merged = mgr.fetch_metric_samples(_fetch_partitions(), 0, 1000)
+    merged = mgr.fetch_metric_samples(_fetch_partitions, 0, 1000)
     assert merged.skipped_partitions == 4, "the failed fetcher's bucket"
     assert len(merged.partition_samples) == 4, "the healthy bucket landed"
     assert pagg.batches, "partial window must still be ingested"
@@ -453,7 +453,7 @@ def test_fetcher_rejects_window_below_completeness_floor():
         [SyntheticSampler(), _FailingSampler()], pagg, bagg, _NullStore(),
         assignor=_split_assignor, min_completeness=0.75)
     with pytest.raises(PartialWindowError):
-        mgr.fetch_metric_samples(_fetch_partitions(), 0, 1000)
+        mgr.fetch_metric_samples(_fetch_partitions, 0, 1000)
     assert not pagg.batches, "a rejected window must not be ingested"
     mgr.shutdown()
 
@@ -483,7 +483,7 @@ def test_fetcher_retries_flaky_sampler_to_success():
         [flaky], pagg, bagg, _NullStore(),
         retry_policy=RetryPolicy(max_attempts=3, base_backoff_s=0.0,
                                  jitter_ratio=0.0))
-    merged = mgr.fetch_metric_samples(_fetch_partitions(), 0, 1000)
+    merged = mgr.fetch_metric_samples(_fetch_partitions, 0, 1000)
     assert flaky.calls == 2
     assert merged.skipped_partitions == 0
     assert len(merged.partition_samples) == 8

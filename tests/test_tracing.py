@@ -74,24 +74,10 @@ def test_disabled_records_nothing_and_is_reentrant():
         s.set(x=1)  # the null span accepts set()
         with tracer.span("b"):
             tracer.annotate(y=2)
-        tracer.record_span("c", 0.1)
     assert tracer.traces() == []
     assert tracer.spans_closed == 0
     # the disabled path hands back one shared object — no per-call alloc
     assert tracer.span("a") is tracer.span("b")
-
-
-def test_record_span_attaches_pre_timed_child():
-    tracer = Tracer()
-    with tracer.span("root"):
-        tracer.record_span("goal.solve", 0.25, goal="RackAwareGoal",
-                           apportioned=True)
-    t = tracer.traces()[0]
-    goal = t["root"]["children"][0]
-    assert goal["name"] == "goal.solve"
-    assert 200 <= goal["durationMs"] <= 300
-    assert {"key": "goal", "value": {"stringValue": "RackAwareGoal"}} \
-        in goal["attributes"]
 
 
 def test_exception_marks_span_and_propagates():
@@ -239,10 +225,15 @@ def test_rebalance_dryrun_yields_full_trace_tree(traced_api):
     names = span_names(trace)
     assert names[0] == "rebalance"
     for expected in ("monitor.cluster_model", "monitor.aggregate",
-                     "model.assemble", "analyzer.optimize", "goal.solve",
-                     "analyzer.proposal_diff"):
+                     "model.assemble", "analyzer.optimize", "solver.dispatch",
+                     "solver.enqueue", "solver.wait",
+                     "analyzer.proposal_diff", "diff.stats", "diff.fetch",
+                     "diff.compare"):
         assert expected in names, f"missing {expected} in {names}"
-    assert names.count("goal.solve") >= 2, "per-goal spans expected"
+    # The fused route is ONE dispatch: a span is a measured interval, so
+    # no per-goal span is made up from the round counts (the bounded
+    # route's live goal.solve spans: test_bounded_route_goal_spans_are_live).
+    assert "goal.solve" not in names
 
     def find(node, name):
         if node["name"] == name:
@@ -258,21 +249,33 @@ def test_rebalance_dryrun_yields_full_trace_tree(traced_api):
     assert "topology_hit" in attrs, "cache hit/miss must be attributed"
     assert "transfer_bytes" in attrs
     assert int(attrs["transfer_bytes"]["intValue"]) > 0
-    goal = find(trace["root"], "goal.solve")
-    gattrs = {a["key"]: a["value"] for a in goal["attributes"]}
-    assert "goal" in gattrs and "candidates" in gattrs
+    dispatch = find(trace["root"], "solver.dispatch")
+    dattrs = {a["key"]: a["value"] for a in dispatch["attributes"]}
+    assert dattrs["route"] == {"stringValue": "fused"}
+    per_goal = [int(r) for r in
+                dattrs["goal_rounds"]["stringValue"].split(",")]
+    assert sum(per_goal) == int(dattrs["rounds"]["intValue"])
+    assert [c["name"] for c in dispatch["children"]] == \
+        ["solver.enqueue", "solver.wait"]
+    fetch = find(trace["root"], "diff.fetch")
+    fattrs = {a["key"]: a["value"] for a in fetch["attributes"]}
+    assert int(fattrs["transfer_bytes"]["intValue"]) > 0
 
 
 def test_sampling_fetch_traces_recorded(traced_api):
-    assert TRACER.traces(operation="sampling"), \
-        "each sampling cycle should record its own fetch trace"
+    traces = TRACER.traces(operation="sampling")
+    assert traces, "each sampling cycle should record its own fetch trace"
+    # the round is split where the work happens
+    assert span_names(traces[0]) == [
+        "monitor.sample_fetch", "sampling.describe", "sampling.get_samples",
+        "sampling.ingest"]
 
 
 def test_metrics_expose_histograms_and_device_telemetry(traced_api):
     # Run at least one traced operation first (module fixture already did).
     text = traced_api.metrics_text()
     # per-stage span histograms, well-formed
-    for stage in ("monitor.aggregate", "model.assemble", "goal.solve",
+    for stage in ("monitor.aggregate", "model.assemble", "solver.dispatch",
                   "analyzer.optimize"):
         assert (f'kafka_cruisecontrol_trace_span_seconds_bucket'
                 f'{{span="{stage}",le="+Inf"}}') in text, stage
@@ -463,3 +466,480 @@ def test_jsonl_rotation_cascade_keeps_max_files_generations(tmp_path):
         lines = f.read_text().splitlines()
         assert len(lines) == 1
         json.loads(lines[0])
+
+
+# ---- one trace a request, on the profiler's clock (ISSUE 25) -------------
+
+def _walk(node):
+    yield node
+    for c in node["children"]:
+        yield from _walk(c)
+
+
+def _find(node, name):
+    return next((n for n in _walk(node) if n["name"] == name), None)
+
+
+def _attrs(node):
+    return {a["key"]: next(iter(a["value"].values()))
+            for a in node["attributes"]}
+
+
+def _http_get(port, path, headers=None):
+    import urllib.request
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}/kafkacruisecontrol/{path}",
+        headers=headers or {})
+    with urllib.request.urlopen(req, timeout=300) as resp:
+        return resp.status, dict(resp.headers), json.loads(resp.read())
+
+
+def _request_traces(endpoint="PROPOSALS"):
+    return [t for t in TRACER.traces()
+            if t["root"]["name"] == "http.request"
+            and _attrs(t["root"]).get("endpoint") == endpoint]
+
+
+_SERVED_TREE = (
+    "http.handle", "http.serialize", "http.write", "monitor.cluster_model",
+    "analyzer.optimize", "solver.dispatch", "solver.enqueue", "solver.wait",
+    "analyzer.proposal_diff", "diff.stats", "diff.fetch", "diff.compare")
+
+
+def _served(fleet: bool):
+    """A deployment behind the real HTTP server on loopback, alone or as
+    one cluster of a fleet whose scheduler's worker drains the solves."""
+    from cruise_control_tpu.api.server import (
+        make_server, serve_forever_in_thread,
+    )
+    cfg = CruiseControlConfig({
+        "partition.metrics.window.ms": 1000,
+        "num.partition.metrics.windows": 3,
+        "min.valid.partition.ratio": 0.0,
+        "max.solver.rounds": 30,
+        "failed.brokers.file.path": "",
+        # the fleet's solves take the serial route too, so that both
+        # parameters of the fixture serve the same tree (the megabatch
+        # route at occupancy 1 keeps the trace id as well, under
+        # analyzer.megabatch)
+        "fleet.megabatch.enabled": False})
+    caps = StaticCapacityResolver({}, {Resource.CPU: 100.0, Resource.DISK: 1e7,
+                                       Resource.NW_IN: 1e6,
+                                       Resource.NW_OUT: 1e6})
+    backend = InMemoryAdminBackend(_partitions().values())
+    monitor = LoadMonitor(cfg, backend, samplers=[SyntheticSampler()],
+                          capacity_resolver=caps)
+    registry = scheduler = None
+    if fleet:
+        from cruise_control_tpu.fleet import FleetRegistry, FleetScheduler
+        scheduler = FleetScheduler()
+        registry = FleetRegistry(base_config=cfg, scheduler=scheduler)
+    cc = CruiseControl(cfg, backend, load_monitor=monitor,
+                       executor=Executor(backend, synchronous=True),
+                       optimizer=registry.optimizer if fleet else None)
+    for k in range(1, 4):
+        monitor.task_runner.run_sampling_once(end_ms=k * 1000)
+    if fleet:
+        registry.register("alpha", cc=cc)
+        scheduler.start(pacer=False)
+    server, api = make_server(cc, host="127.0.0.1", port=0, fleet=registry)
+    serve_forever_in_thread(server)
+    return server, api, scheduler
+
+
+@pytest.fixture(scope="module", params=["engine", "fleet"])
+def served(request):
+    server, api, scheduler = _served(fleet=request.param == "fleet")
+    api._async_wait_s = 180
+    yield server.server_address[1], api, request.param
+    server.shutdown()
+    server.server_close()
+    api.shutdown()
+    if scheduler is not None:
+        scheduler.shutdown()
+    TRACER.configure(enabled=True, jsonl_path=None)
+
+
+def test_served_proposals_is_one_trace_rooted_at_the_request(served):
+    """Through the task engine, and through the fleet worker: the spans
+    of one request share the root's trace id."""
+    port, _api, route = served
+    TRACER.clear()
+    status, headers, body = _http_get(
+        port, "proposals?verbose=true&ignore_proposal_cache=true")
+    assert status == 200 and "summary" in body
+    deadline = time.monotonic() + 5      # the root closes after the write
+    while not _request_traces() and time.monotonic() < deadline:
+        time.sleep(0.01)
+    traces = _request_traces()
+    assert len(traces) == 1
+    root = traces[0]["root"]
+    names = [n["name"] for n in _walk(root)]
+    for expected in _SERVED_TREE:
+        assert expected in names, f"missing {expected} in {names}"
+    assert ("fleet.job" in names) == (route == "fleet")
+    assert {n["traceId"] for n in _walk(root)} == {traces[0]["traceId"]}
+    # nothing of the request is left as a trace of its own
+    others = [t["root"]["name"] for t in TRACER.traces()
+              if t["traceId"] != traces[0]["traceId"]]
+    assert not {"proposals", "fleet.job", "analyzer.optimize"} & set(others)
+    dispatch = _find(root, "solver.dispatch")
+    assert [c["name"] for c in dispatch["children"]] == \
+        ["solver.enqueue", "solver.wait"]
+    diff = _find(root, "analyzer.proposal_diff")
+    assert [c["name"] for c in diff["children"]] == \
+        ["diff.stats", "diff.fetch", "diff.compare"]
+    attrs = _attrs(root)
+    assert attrs["method"] == "GET" and attrs["endpoint"] == "PROPOSALS"
+    assert attrs["status"] == "200" and int(attrs["bytes"]) > 0
+    assert attrs["userTaskId"] == headers["User-Task-ID"]
+    assert traces[0]["operations"] == ["http.request", "proposals"] \
+        or "proposals" in traces[0]["operations"]
+
+
+def test_children_of_the_request_cover_it(served):
+    port, _api, _route = served
+    TRACER.clear()
+    _http_get(port, "proposals?verbose=true&ignore_proposal_cache=true")
+    deadline = time.monotonic() + 5
+    while not _request_traces() and time.monotonic() < deadline:
+        time.sleep(0.01)
+    root = _request_traces()[0]["root"]
+    direct = [c for c in root["children"]
+              if c["name"] in ("http.handle", "http.serialize", "http.write")]
+    assert [c["name"] for c in direct] == \
+        ["http.handle", "http.serialize", "http.write"]
+    covered = sum(c["durationMs"] for c in direct)
+    assert covered >= 0.95 * root["durationMs"]
+    assert covered <= root["durationMs"] + 1e-6
+
+
+def test_202_and_its_poll_share_the_task_and_the_first_trace(
+        served, monkeypatch, tmp_path):
+    """A task that outlives its first response keeps the trace id of the
+    request that created it; each poll is its own http.request with the
+    same userTaskId, and leaves no trace of its own. The trace is rung
+    and dumped when the task has ended, so the dump's line holds the
+    task's spans."""
+    port, api, _route = served
+    TRACER.clear()
+    dump = tmp_path / "traces.jsonl"
+    TRACER.configure(jsonl_path=str(dump))
+    roots = []
+    real = TRACER._close
+    monkeypatch.setattr(
+        TRACER, "_close",
+        lambda span: (roots.append(span) if span.parent is None else None,
+                      real(span))[1])
+    api._async_wait_s = 0.0          # answer "in progress" at once
+    try:
+        status, headers, body = _http_get(
+            port, "proposals?verbose=true&ignore_proposal_cache=true")
+        assert status == 200 and "progress" in body
+        task_id = headers["User-Task-ID"]
+    finally:
+        api._async_wait_s = 180
+    status, _h, body = _http_get(port, "proposals?verbose=true"
+                                 "&ignore_proposal_cache=true",
+                                 headers={"User-Task-ID": task_id})
+    assert status == 200 and "summary" in body
+    deadline = time.monotonic() + 5
+    while (not _request_traces() or len(roots) < 2) \
+            and time.monotonic() < deadline:
+        time.sleep(0.01)
+    TRACER.configure(jsonl_path=None)
+    requests = [r for r in roots if r.name == "http.request"]
+    assert [r.attributes["userTaskId"] for r in requests] == \
+        [task_id, task_id]
+    assert requests[0].trace_id != requests[1].trace_id
+    # the poll ran no operation: its histogram sample stands, no trace
+    traces = _request_traces()
+    assert len(traces) == 1
+    first = traces[0]
+    assert first["traceId"] == requests[0].trace_id
+    # the task's spans closed into the FIRST request's tree
+    task = _find(first["root"], "analyzer.optimize")
+    assert task is not None and task["traceId"] == first["traceId"]
+    assert "proposals" in first["operations"]
+    assert first["spanCount"] == len(list(_walk(first["root"])))
+    lines = [json.loads(ln) for ln in dump.read_text().splitlines()]
+    assert [ln["traceId"] for ln in lines] == [first["traceId"]]
+    assert _find(lines[0]["root"], "analyzer.optimize") is not None
+
+
+def test_a_served_request_keeps_its_cluster(served):
+    """The root closes on the handler thread, outside the cluster label:
+    the trace still belongs to the cluster the front door routed to."""
+    port, _api, route = served
+    TRACER.clear()
+    _http_get(port, "proposals?verbose=true&ignore_proposal_cache=true")
+    deadline = time.monotonic() + 5
+    while not _request_traces() and time.monotonic() < deadline:
+        time.sleep(0.01)
+    trace = _request_traces()[0]
+    if route == "fleet":
+        assert trace["cluster"] == "alpha"
+        assert [t["traceId"] for t in TRACER.traces(cluster="alpha")] == \
+            [trace["traceId"]]
+        assert TRACER.traces(cluster="beta") == []
+        _s, _h, body = _http_get(port, "trace?cluster=alpha")
+        assert [t["traceId"] for t in body["traces"]] == [trace["traceId"]]
+    else:
+        assert trace["cluster"] is None
+        assert TRACER.traces(cluster="alpha") == []
+
+
+def test_requests_that_ran_nothing_leave_no_trace(served):
+    """Scrapes, reads of the trace ring and unknown paths feed the
+    histogram and stay out of the ring."""
+    import urllib.request
+    from cruise_control_tpu.utils.sensors import SENSORS
+    port, _api, _route = served
+
+    def requests_seen():
+        return sum(float(ln.rsplit(" ", 1)[1])
+                   for ln in SENSORS.render().splitlines()
+                   if "trace_span_seconds_count{" in ln
+                   and 'span="http.request"' in ln)
+
+    TRACER.clear()
+    before, completed = requests_seen(), TRACER.traces_completed
+    for _ in range(3):
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{port}/metrics", timeout=60) as resp:
+            resp.read()
+    _http_get(port, "trace")
+    deadline = time.monotonic() + 5
+    while requests_seen() < before + 4 and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert requests_seen() == before + 4
+    assert TRACER.traces() == [] and TRACER.traces_completed == completed
+
+
+def test_http_spans_alone_label_their_histogram_with_the_endpoint(served):
+    from cruise_control_tpu.utils.sensors import SENSORS
+    port, _api, _route = served
+    _http_get(port, "state?substates=monitor")
+    text = SENSORS.render()
+    for span in ("http.request", "http.handle", "http.serialize",
+                 "http.write"):
+        assert (f'trace_span_seconds_count{{endpoint="STATE",'
+                f'span="{span}"}}') in text, span
+    labelled = [ln for ln in text.splitlines()
+                if "trace_span_seconds_count{" in ln and "endpoint=" in ln]
+    assert labelled and all('span="http.' in ln for ln in labelled)
+
+
+def test_profiler_capture_holds_the_request_on_the_host_plane(
+        served, tmp_path):
+    """A live span is a ``cc.<name>`` event of a running profiler session
+    (and so is a journey segment): program and device share one clock."""
+    import jax
+    from cruise_control_tpu.utils.profile_summary import load_events
+    port, _api, _route = served
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        _http_get(port, "proposals?verbose=true&ignore_proposal_cache=true")
+        time.sleep(0.2)      # the root closes after the client has the body
+    finally:
+        jax.profiler.stop_trace()
+    host = {name for name, _s, _d in load_events(str(tmp_path))["host"]}
+    for name in ("cc.http.request", "cc.http.handle", "cc.analyzer.optimize",
+                 "cc.solver.wait", "cc.diff.compare", "cc.render"):
+        assert name in host, f"{name} not in {sorted(host)}"
+
+
+def test_tracing_disabled_no_span_no_annotation_no_histogram(
+        served, monkeypatch):
+    from cruise_control_tpu.utils import tracing
+    from cruise_control_tpu.utils.sensors import SENSORS
+    port, _api, _route = served
+
+    def samples():
+        return sum(float(ln.rsplit(" ", 1)[1])
+                   for ln in SENSORS.render().splitlines()
+                   if "trace_span_seconds_count" in ln)
+
+    entered = []
+    real = tracing.annotation
+    monkeypatch.setattr(tracing, "annotation",
+                        lambda name: entered.append(name) or real(name))
+    TRACER.configure(enabled=False)
+    try:
+        closed, before = TRACER.spans_closed, samples()
+        TRACER.clear()
+        status, _h, _b = _http_get(
+            port, "proposals?verbose=true&ignore_proposal_cache=true")
+        assert status == 200
+        assert TRACER.spans_closed == closed and samples() == before
+        assert TRACER.traces() == [] and entered == []
+    finally:
+        TRACER.configure(enabled=True)
+
+
+def test_attach_carries_the_parent_across_a_thread():
+    tracer = Tracer()
+    with tracer.span("request") as parent:
+        def work():
+            with tracer.attach(parent):
+                with tracer.span("task"):
+                    pass
+            assert tracer.current_span() is None
+        t = threading.Thread(target=work)
+        t.start()
+        t.join(5)
+        assert not t.is_alive()
+    (trace,) = tracer.traces()
+    assert span_names(trace) == ["request", "task"]
+    assert trace["root"]["children"][0]["traceId"] == trace["traceId"]
+    # nothing to attach: the block runs as it would have
+    with tracer.attach(None):
+        assert tracer.current_span() is None
+
+
+def test_no_negative_duration_when_the_wall_clock_steps_back(monkeypatch):
+    """Durations come from the monotonic clock; the wall clock only
+    places the export, through one anchor read at import."""
+    wall = iter(range(10**9, 0, -10**6))
+    monkeypatch.setattr(time, "time", lambda: next(wall))
+    monkeypatch.setattr(time, "time_ns", lambda: next(wall) * 10**9)
+    tracer = Tracer()
+    with tracer.span("outer"):
+        for _ in range(3):
+            with tracer.span("inner"):
+                time.sleep(0.001)
+    (trace,) = tracer.traces()
+    nodes = list(_walk(trace["root"]))
+    assert len(nodes) == 4
+    for n in nodes:
+        assert n["durationMs"] > 0
+        assert int(n["endTimeUnixNano"]) > int(n["startTimeUnixNano"])
+    starts = [int(n["startTimeUnixNano"]) for n in nodes]
+    assert starts == sorted(starts)
+
+
+def test_bounded_route_goal_spans_are_live():
+    """Above solver.fused.chain.max.brokers every goal is a live
+    ``goal.solve`` span over the pump's passes; none is apportioned."""
+    from cruise_control_tpu.analyzer.optimizer import GoalOptimizer
+    from cruise_control_tpu.model.fixtures import random_cluster
+    state, meta = random_cluster(8, 2, 96, seed=5, skew_to_first=0.6)
+    opt = GoalOptimizer(CruiseControlConfig(
+        {"solver.fused.chain.max.brokers": 4, "max.solver.rounds": 30}))
+    TRACER.clear()
+    opt.optimizations(state, meta)
+    (trace,) = [t for t in TRACER.traces()
+                if t["root"]["name"] == "analyzer.optimize"]
+    goals = [n for n in _walk(trace["root"]) if n["name"] == "goal.solve"]
+    assert len(goals) == 15          # the default chain, goal by goal
+    assert all("apportioned" not in _attrs(g) for g in goals)
+    dispatches = [n for g in goals for n in _walk(g)
+                  if n["name"] == "solver.dispatch"]
+    assert dispatches, "a goal with work runs passes of the pump"
+    for d in dispatches:
+        assert _attrs(d)["route"] == "bounded"
+        kinds = {c["name"] for c in d["children"]}
+        assert kinds <= {"solver.enqueue", "solver.wait"} and kinds
+
+
+def test_trace_and_lower_seconds_move_on_a_first_jit_call_only():
+    import jax
+    import jax.numpy as jnp
+    from cruise_control_tpu.utils import xla_telemetry
+    from cruise_control_tpu.utils.sensors import SENSORS
+    xla_telemetry.install(enabled=True)
+
+    def counts():
+        text = SENSORS.render()
+        return [sum(float(ln.rsplit(" ", 1)[1]) for ln in text.splitlines()
+                    if ln.startswith(f"kafka_cruisecontrol_{name}_count"))
+                for name in ("xla_trace_seconds", "xla_lower_seconds")]
+
+    @jax.jit
+    def fresh(x):
+        return (x * 3 + 1).sum()
+
+    before = counts()
+    with xla_telemetry.shape_scope(48, 6):
+        fresh(jnp.arange(7.0)).block_until_ready()
+    first = counts()
+    assert first[0] > before[0] and first[1] > before[1]
+    assert 'xla_trace_seconds_count{shape="p48_b6"}' in SENSORS.render()
+    fresh(jnp.arange(7.0)).block_until_ready()
+    assert counts() == first
+
+
+def test_a_nested_trace_is_counted_once():
+    """jax reports a nested jit's trace inside its caller's duration too:
+    the histograms hold each event's own seconds, so trace + lower +
+    compile of a first call do not pass the seconds the call took."""
+    import jax
+    import jax.numpy as jnp
+    from cruise_control_tpu.utils import xla_telemetry
+    from cruise_control_tpu.utils.sensors import SENSORS
+    xla_telemetry.install(enabled=True)
+
+    def seconds():
+        return sum(float(ln.rsplit(" ", 1)[1])
+                   for ln in SENSORS.render().splitlines()
+                   if ln.startswith(("kafka_cruisecontrol_xla_trace_seconds_sum",
+                                     "kafka_cruisecontrol_xla_lower_seconds_sum",
+                                     "kafka_cruisecontrol_xla_compile_seconds_sum")))
+
+    @jax.jit
+    def inner(x):
+        time.sleep(0.05)             # the inner trace takes 50 ms
+        return x * 2
+
+    @jax.jit
+    def middle(x):
+        return inner(x) + inner(x + 1)
+
+    @jax.jit
+    def outer(x):
+        return middle(x).sum() + jnp.arange(3.0).sum()
+
+    before = seconds()
+    t0 = time.monotonic()
+    outer(jnp.arange(5.0)).block_until_ready()
+    wall = time.monotonic() - t0
+    spent = seconds() - before
+    # inclusive durations would give over 3 x 50 ms more than the wall
+    assert 0.05 <= spent <= wall
+    assert not getattr(xla_telemetry._OPEN, "stack", None)
+
+
+_ROUND_SCOPES = ("round.agg_refresh", "round.score", "round.source_topk",
+                 "round.candidates", "round.deltas", "round.accept",
+                 "round.select", "round.apply", "swap.round", "goal.stats",
+                 "goal.agg")
+
+
+def test_lowered_chain_names_every_scope_and_solves_as_the_parent_did():
+    """The scopes are metadata: the lowered fused chain names each, and
+    rounds, moves and balancedness are bit for bit what the commit before
+    them gave on CPU (pinned from a run of that commit)."""
+    import re
+
+    from cruise_control_tpu.analyzer import optimizer as opt_mod
+    from cruise_control_tpu.analyzer.chain import chain_optimize_full
+    from cruise_control_tpu.analyzer.search import ExclusionMasks
+    from cruise_control_tpu.model.fixtures import random_cluster
+    state, meta = random_cluster(16, 4, 512, seed=3, skew_to_first=0.6)
+    cfg = CruiseControlConfig({})
+    opt = opt_mod.GoalOptimizer(cfg)
+    lowered = chain_optimize_full.lower(
+        state, tuple(opt_mod.goals_by_priority(cfg)), opt._constraint,
+        opt.search_config(state), meta.num_topics, ExclusionMasks())
+    named = set(re.findall(r"(?:round|swap|goal)\.[a-z_]+",
+                           lowered.as_text(debug_info=True)))
+    assert named == set(_ROUND_SCOPES)
+    _final, result = opt.optimizations(state, meta)
+    assert [g.rounds for g in result.goal_results] == \
+        [4, 0, 0, 0, 0, 2, 3, 1, 15, 0, 4, 0, 9, 2, 0]
+    assert [g.moves_applied for g in result.goal_results] == \
+        [289, 0, 0, 0, 0, 64, 91, 0, 24, 0, 37, 0, 70, 2, 0]
+    assert len(result.proposals) == 373
+    assert result.balancedness_after == 89.57958658572481
