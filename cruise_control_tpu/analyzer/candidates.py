@@ -49,6 +49,87 @@ class Candidates:
         return self.kind.shape[0]
 
 
+def _span(x: jax.Array, start: int, stop: int, stride: int = 1) -> jax.Array:
+    """``x[start:stop:stride]`` along axis 0 as a static ``lax.slice``
+    (jnp indexing lowers a strided slice, and a slice beside ``None``, to
+    a gather)."""
+    return jax.lax.slice_in_dim(x, start, stop, stride, axis=0)
+
+
+@partial(jax.tree_util.register_dataclass,
+         data_fields=["row_src", "dst_margin", "row_partition", "row_topic",
+                      "row_src_slot"],
+         meta_fields=["layout"])
+@dataclasses.dataclass(frozen=True)
+class CandidateGrid:
+    """The margins of ``generate_candidates``' two-block grid, so that what
+    is constant along a row or a column is looked up there and broadcast,
+    not gathered once per candidate (docs/DESIGN.md "Grid lookups").
+
+    ``layout`` is ``((k_src, k_cols), (k_l, S))``: the move block, one row
+    per source replica and one column per destination, then the leadership
+    block, one row per leader and one column per slot. A ROW fixes the
+    partition, its topic, the moving slot and the source broker in both
+    blocks. A COLUMN of the move block fixes the destination broker, all
+    but the last one (the targeted column is per row; a grid without one
+    spends k_src lookups on a shared column, same values); a leadership
+    destination is per candidate. The arrays index VALID candidates'
+    values; an invalid candidate reads its row's and column's brokers
+    where the flat fields read broker 0, and ``valid`` masks both."""
+
+    layout: tuple[tuple[int, int], tuple[int, int]]
+    row_src: jax.Array        # [k_src + k_l] source broker of each row
+    dst_margin: jax.Array     # [k_cols - 1 | k_src | k_l * S] dest brokers
+    row_partition: jax.Array  # [k_src + k_l]
+    row_topic: jax.Array      # [k_src + k_l]
+    row_src_slot: jax.Array   # [k_src + k_l]
+
+    def from_rows(self, rows: jax.Array) -> jax.Array:
+        """[k_src + k_l, ...] row values -> [N, ...] candidates."""
+        (k_src, k_cols), (k_l, s) = self.layout
+        tail = rows.shape[1:]
+        move = jnp.broadcast_to(jnp.expand_dims(_span(rows, 0, k_src), 1),
+                                (k_src, k_cols) + tail)
+        lead = jnp.broadcast_to(
+            jnp.expand_dims(_span(rows, k_src, k_src + k_l), 1),
+            (k_l, s) + tail)
+        return jnp.concatenate([move.reshape((k_src * k_cols,) + tail),
+                                lead.reshape((k_l * s,) + tail)])
+
+    def from_dst(self, vals: jax.Array) -> jax.Array:
+        """[len(dst_margin), ...] values read at ``dst_margin`` -> [N, ...]
+        candidates."""
+        (k_src, k_cols), _ = self.layout
+        k_shared = k_cols - 1
+        tail = vals.shape[1:]
+        move = jnp.concatenate(
+            [jnp.broadcast_to(_span(vals, 0, k_shared)[None],
+                              (k_src, k_shared) + tail),
+             jnp.expand_dims(_span(vals, k_shared, k_shared + k_src), 1)],
+            axis=1)
+        return jnp.concatenate([move.reshape((k_src * k_cols,) + tail),
+                                _span(vals, k_shared + k_src, len(vals))])
+
+    def at_dst_topic(self, x: jax.Array) -> jax.Array:
+        """``x[topic, dst_broker]`` of a [T, B] table per candidate: the
+        shared columns as k_shared columns of ``x``, then one row of them
+        per source; the targeted column and the leadership slots, where
+        topic and broker both vary, one element each."""
+        (k_src, k_cols), (_k_l, s) = self.layout
+        k_shared = k_cols - 1
+        n_rows, n_dst = len(self.row_topic), len(self.dst_margin)
+        t_move = _span(self.row_topic, 0, k_src)
+        t_lead = _span(self.row_topic, k_src, n_rows)
+        shared = jnp.take(x, _span(self.dst_margin, 0, k_shared),
+                          axis=1)[t_move]
+        rest = x[jnp.concatenate([t_move, jnp.repeat(t_lead, s)]),
+                 _span(self.dst_margin, k_shared, n_dst)]
+        move = jnp.concatenate(
+            [shared, jnp.expand_dims(_span(rest, 0, k_src), 1)], axis=1)
+        return jnp.concatenate([move.reshape(-1),
+                                _span(rest, k_src, len(rest))])
+
+
 @partial(jax.tree_util.register_dataclass,
          data_fields=["src_broker", "dst_broker", "load_delta", "replica_delta",
                       "leader_delta", "partition", "topic", "src_slot",
@@ -56,7 +137,7 @@ class Candidates:
                       "pre_src_count", "pre_dst_count", "pre_src_leaders",
                       "pre_dst_leaders", "pre_src_topic_count",
                       "pre_dst_topic_count", "pre_src_topic_leaders",
-                      "pre_dst_pot", "pre_dst_lbi"],
+                      "pre_dst_pot", "pre_dst_lbi", "grid"],
          meta_fields=[])
 @dataclasses.dataclass(frozen=True)
 class CandidateDeltas:
@@ -69,7 +150,13 @@ class CandidateDeltas:
     jointly — the sound relaxation of one-move-per-broker-per-round.
     Directionally conservative: dst pre terms count only inflows, src pre
     terms only outflows, so a rejected earlier candidate can only make the
-    check stricter, never looser. ``None`` = single-candidate semantics."""
+    check stricter, never looser. ``None`` = single-candidate semantics.
+
+    Goals read per-broker, per-topic and per-partition TABLES through the
+    ``at_*`` methods below, never by indexing with the [N] fields: with a
+    ``grid`` attached (compute_deltas given the layout) the lookup runs on
+    the grid's margins and is broadcast; with none it is the plain
+    per-candidate gather. Same values on every valid candidate."""
 
     src_broker: jax.Array    # [N] int32
     dst_broker: jax.Array    # [N] int32
@@ -92,6 +179,70 @@ class CandidateDeltas:
     pre_src_topic_leaders: jax.Array | None = None  # [N] f32
     pre_dst_pot: jax.Array | None = None         # [N] f32 potential NW-out
     pre_dst_lbi: jax.Array | None = None         # [N] f32 leader bytes-in
+    grid: CandidateGrid | None = None
+
+    def without_grid(self) -> "CandidateDeltas":
+        """The same candidates as a plain batch. Anything that re-indexes
+        the [N] fields (a selected sub-batch) must drop the grid first:
+        its margins describe the WHOLE grid."""
+        return dataclasses.replace(self, grid=None)
+
+    # -- row view: one entry per grid row (per candidate without a grid) --
+    @property
+    def row_src(self) -> jax.Array:
+        return self.src_broker if self.grid is None else self.grid.row_src
+
+    @property
+    def row_partition(self) -> jax.Array:
+        return self.partition if self.grid is None \
+            else self.grid.row_partition
+
+    @property
+    def row_topic(self) -> jax.Array:
+        return self.topic if self.grid is None else self.grid.row_topic
+
+    @property
+    def row_src_slot(self) -> jax.Array:
+        return self.src_slot if self.grid is None \
+            else self.grid.row_src_slot
+
+    def from_rows(self, rows: jax.Array) -> jax.Array:
+        """Row-view values -> [N, ...] candidates."""
+        return rows if self.grid is None else self.grid.from_rows(rows)
+
+    # -- table lookups ---------------------------------------------------
+    def at_src(self, x: jax.Array) -> jax.Array:
+        """``x[src_broker]`` of a [B] or [B, k] table."""
+        return self.from_rows(x[self.row_src])
+
+    def at_dst(self, x: jax.Array) -> jax.Array:
+        """``x[dst_broker]`` of a [B] or [B, k] table."""
+        if self.grid is None:
+            return x[self.dst_broker]
+        return self.grid.from_dst(x[self.grid.dst_margin])
+
+    def at_src_topic(self, x: jax.Array) -> jax.Array:
+        """``x[topic, src_broker]`` of a [T, B] table."""
+        return self.from_rows(x[self.row_topic, self.row_src])
+
+    def at_dst_topic(self, x: jax.Array) -> jax.Array:
+        """``x[topic, dst_broker]`` of a [T, B] table."""
+        if self.grid is None:
+            return x[self.topic, self.dst_broker]
+        return self.grid.at_dst_topic(x)
+
+    def at_topic(self, x: jax.Array) -> jax.Array:
+        """``x[topic]`` of a [T] table."""
+        return self.from_rows(x[self.row_topic])
+
+    def at_partition(self, x: jax.Array) -> jax.Array:
+        """``x[partition]`` of a [P] or [P, k] table."""
+        return self.from_rows(x[self.row_partition])
+
+    def at_src_slot(self, x: jax.Array) -> jax.Array:
+        """``x[partition, src_slot]`` of a [P, S] table: the moving
+        replica's entry."""
+        return self.from_rows(x[self.row_partition, self.row_src_slot])
 
     def pre0(self, name: str):
         """Pre-term or 0.0 (single-candidate semantics when absent)."""
@@ -105,11 +256,17 @@ class CandidateDeltas:
 
 @jax.named_scope("round.deltas")
 def compute_deltas(state: ClusterTensors, derived: DerivedState,
-                   cand: Candidates) -> CandidateDeltas:
+                   cand: Candidates,
+                   layout: "tuple[tuple[int, int], ...] | None" = None,
+                   ) -> CandidateDeltas:
     """Gather the (src, dst, Δload) tuple for every candidate; also folds the
     structural legitimacy checks (GoalUtils.legitMove: destination must not
     already host the partition, source must exist, destination must be an
-    alive allowed broker, leadership destination must be a live replica)."""
+    alive allowed broker, leadership destination must be a live replica).
+
+    ``layout``: ``generate_candidates``' layout for ``cand`` when it made
+    both blocks (moves, then leadership). The deltas then carry the grid's
+    margins (CandidateGrid) and the goals' table lookups run there."""
     p = cand.partition
     b = state.num_brokers
     assign_p = state.assignment[p]              # [N, S]
@@ -167,6 +324,30 @@ def compute_deltas(state: ClusterTensors, derived: DerivedState,
     valid = cand.valid & derived.movable_partition[p] & src_exists & dst_alive \
         & jnp.where(is_move, move_ok, lead_ok)
 
+    grid = None
+    if layout is not None:
+        if len(layout) != 2:
+            raise ValueError(
+                "grid lookups need the move + leadership layout, got "
+                f"{layout!r}")
+        (k_src, k_cols), (_k_l, s_dim) = layout
+        n_move, n = k_src * k_cols, cand.n
+
+        def rows(flat):
+            # constant along a row: each row's first candidate
+            return jnp.concatenate([_span(flat, 0, n_move, k_cols),
+                                    _span(flat, n_move, n, s_dim)])
+
+        row_p = rows(p)
+        grid = CandidateGrid(
+            layout=tuple(layout), row_src=rows(src_safe),
+            dst_margin=jnp.concatenate(
+                [_span(dst_safe, 0, k_cols - 1),
+                 _span(dst_safe, k_cols - 1, n_move, k_cols),
+                 _span(dst_safe, n_move, n)]),
+            row_partition=row_p, row_topic=state.topic[row_p],
+            row_src_slot=rows(jnp.maximum(src_slot, 0)))
+
     return CandidateDeltas(
         src_broker=jnp.where(valid, src_broker, 0),
         dst_broker=jnp.where(valid, dst_safe, 0),
@@ -178,6 +359,7 @@ def compute_deltas(state: ClusterTensors, derived: DerivedState,
         src_slot=jnp.where(valid, src_slot, 0),
         dst_slot=jnp.where(valid & ~is_move, cand.dst_slot, 0),
         valid=valid,
+        grid=grid,
     )
 
 

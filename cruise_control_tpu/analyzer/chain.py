@@ -192,6 +192,25 @@ def _switch_target_dests(active_idx, goals, aux_list, state, derived,
     return jax.lax.switch(active_idx, [branch(i) for i in range(len(goals))], 0)
 
 
+# How the move-round body last traced in this process looks tables up for
+# its candidates: "grid" (on the candidate grid's margins, CandidateGrid)
+# or "flat" (one gather a candidate). The form is fixed when a program is
+# traced, so it is written then, and the dispatch spans report it.
+_accept_lookup_traced: str | None = None
+
+
+def accept_lookup() -> str | None:
+    """The table-lookup form of the last traced move-round body (None
+    before any trace): the ``accept_lookup`` attribute of the
+    ``solver.dispatch`` spans."""
+    return _accept_lookup_traced
+
+
+def _set_accept_lookup(dispatch, kind: str = "move") -> None:
+    if kind == "move" and _accept_lookup_traced is not None:
+        dispatch.set(accept_lookup=_accept_lookup_traced)
+
+
 def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
                       active_idx: jax.Array,
                       prior_mask: jax.Array, goals: tuple[Goal, ...],
@@ -282,7 +301,9 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
         jnp.broadcast_to(has_leadership, (r1 * c1,)),
     ])
     cand = dataclasses.replace(cand, valid=cand.valid & block_ok)
-    deltas = compute_deltas(state, derived, cand)
+    deltas = compute_deltas(state, derived, cand, layout)
+    global _accept_lookup_traced
+    _accept_lookup_traced = "flat" if deltas.grid is None else "grid"
 
     def imp_branch(i):
         g = goals[i]
@@ -298,7 +319,7 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
             accept &= (~prior_mask[i]) | g.acceptance(
                 state, derived, constraint, aux_list[i], deltas)
 
-        moving_offline = off[deltas.partition, deltas.src_slot] \
+        moving_offline = deltas.at_src_slot(off) \
             & (deltas.replica_delta > 0)
 
         imp = jax.lax.switch(active_idx,
@@ -855,6 +876,7 @@ def optimize_chain(state: ClusterTensors, chain: Sequence[Goal],
             stats = {k: jax.device_get(v) for k, v in stats.items()}
         infos = _chain_infos_from_stats(goals, stats)
         set_dispatch_rounds(dispatch, infos)
+        _set_accept_lookup(dispatch)
     return state, infos
 
 
@@ -1250,6 +1272,7 @@ def run_bounded_pass(enqueue: Callable, st, pass_cap: int,
                                  or (out_of_time is not None and out_of_time())):
                 break
         dispatch.set(rounds=pass_rounds)
+        _set_accept_lookup(dispatch, kind)
     return st, applied_total, pass_rounds
 
 
@@ -1675,6 +1698,7 @@ def run_megabatch_pass(enqueue: Callable, st, active0, pass_cap: int,
                 break
         # ccsa: ok[CCSA001] host numpy totals of reads already paid
         dispatch.set(rounds=int(rounds_total.max()) if c else 0)
+        _set_accept_lookup(dispatch, kind)
     return st, active_host, applied_total, rounds_total
 
 

@@ -252,20 +252,14 @@ def pair_improvement(values: jax.Array, deltas: CandidateDeltas,
                      delta: jax.Array, viol_fn) -> jax.Array:
     """Improvement of Σ viol(broker) restricted to the touched (src, dst)
     pair. ``values[B]`` is the per-broker quantity, ``delta[N]`` how much
-    each candidate transfers, ``viol_fn(value, broker_idx)`` the violation
-    magnitude (broker_idx lets per-broker limits be gathered)."""
-    src, dst = deltas.src_broker, deltas.dst_broker
-    before = viol_fn(values[src], src) + viol_fn(values[dst], dst)
-    after = viol_fn(values[src] - delta, src) + viol_fn(values[dst] + delta, dst)
+    each candidate transfers, ``viol_fn(value, at)`` the violation
+    magnitude, where ``at`` is ``deltas.at_src`` or ``deltas.at_dst`` so
+    per-broker limits are looked up at the same end."""
+    v_src, v_dst = deltas.at_src(values), deltas.at_dst(values)
+    before = viol_fn(v_src, deltas.at_src) + viol_fn(v_dst, deltas.at_dst)
+    after = viol_fn(v_src - delta, deltas.at_src) \
+        + viol_fn(v_dst + delta, deltas.at_dst)
     return jnp.where(deltas.valid, before - after, -jnp.inf)
-
-
-def gather_pair(arr: jax.Array, deltas: CandidateDeltas,
-                column: int | None = None) -> tuple[jax.Array, jax.Array]:
-    """(src_value, dst_value) per candidate from a [B] or [B, R] array."""
-    if column is None:
-        return arr[deltas.src_broker], arr[deltas.dst_broker]
-    return arr[deltas.src_broker, column], arr[deltas.dst_broker, column]
 
 
 def donor_widened_shed(values: jax.Array, lower, upper,
@@ -287,6 +281,6 @@ def new_broker_gate(derived: DerivedState, deltas: CandidateDeltas) -> jax.Array
     """When NEW brokers exist, only they may receive replicas
     (ResourceDistributionGoal.rebalanceByMovingLoadIn:444-447)."""
     has_new = derived.new_brokers.any()
-    dst_is_new = derived.new_brokers[deltas.dst_broker]
+    dst_is_new = deltas.at_dst(derived.new_brokers)
     is_move = deltas.replica_delta > 0
     return jnp.where(has_new & is_move, dst_is_new, True)

@@ -104,15 +104,16 @@ class BrokerSetAwareGoal(Goal):
     def acceptance(self, state, derived, constraint, aux, deltas: CandidateDeltas):
         home, _ = aux
         sets = self._set_array(state)
-        dst_ok = sets[deltas.dst_broker] == home[deltas.topic]
+        dst_ok = deltas.at_dst(sets) == deltas.at_topic(home)
         is_move = deltas.replica_delta > 0
         return jnp.where(is_move, dst_ok, True)
 
     def improvement(self, state, derived, constraint, aux, deltas):
         home, _ = aux
         sets = self._set_array(state)
-        src_bad = (sets[deltas.src_broker] != home[deltas.topic]).astype(jnp.float32)
-        dst_bad = (sets[deltas.dst_broker] != home[deltas.topic]).astype(jnp.float32)
+        home_t = deltas.at_topic(home)
+        src_bad = (deltas.at_src(sets) != home_t).astype(jnp.float32)
+        dst_bad = (deltas.at_dst(sets) != home_t).astype(jnp.float32)
         is_move = deltas.replica_delta > 0
         imp = jnp.where(is_move, src_bad - dst_bad, 0.0)
         return jnp.where(deltas.valid, imp, -jnp.inf)

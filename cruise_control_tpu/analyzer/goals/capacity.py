@@ -39,16 +39,16 @@ class ResourceCapacityGoal(Goal):
         # higher-ranked candidates accepted this round).
         r = int(self.resource)
         limit = self._limit(state, constraint)
-        dst_after = derived.broker_load[deltas.dst_broker, r] \
+        dst_after = deltas.at_dst(derived.broker_load[:, r]) \
             + deltas.pre_load("pre_dst_load", r) + deltas.load_delta[:, r]
-        return dst_after <= limit[deltas.dst_broker] + 1e-6
+        return dst_after <= deltas.at_dst(limit) + 1e-6
 
     def improvement(self, state, derived, constraint, aux, deltas):
         r = int(self.resource)
         limit = self._limit(state, constraint)
 
-        def viol(value, idx):
-            return jnp.maximum(value - limit[idx], 0.0)
+        def viol(value, at):
+            return jnp.maximum(value - at(limit), 0.0)
 
         return pair_improvement(derived.broker_load[:, r], deltas,
                                 deltas.load_delta[:, r], viol)
@@ -73,8 +73,8 @@ class ResourceCapacityGoal(Goal):
         limit = self._limit(state, constraint)
         d = net.load_delta[:, r]
         load = derived.broker_load[:, r]
-        dst_ok = load[net.dst_broker] + d <= limit[net.dst_broker] + 1e-6
-        src_ok = load[net.src_broker] - d <= limit[net.src_broker] + 1e-6
+        dst_ok = net.at_dst(load) + d <= net.at_dst(limit) + 1e-6
+        src_ok = net.at_src(load) - d <= net.at_src(limit) + 1e-6
         return dst_ok & src_ok
 
 
@@ -87,14 +87,14 @@ class ReplicaCapacityGoal(Goal):
         return jnp.where(derived.alive, jnp.maximum(over, 0).astype(jnp.float32), 0.0)
 
     def acceptance(self, state, derived, constraint, aux, deltas: CandidateDeltas):
-        dst_after = derived.broker_replicas[deltas.dst_broker] \
+        dst_after = deltas.at_dst(derived.broker_replicas) \
             + deltas.pre0("pre_dst_count") + deltas.replica_delta
         return dst_after <= constraint.max_replicas_per_broker
 
     def improvement(self, state, derived, constraint, aux, deltas):
         cap = float(constraint.max_replicas_per_broker)
 
-        def viol(value, idx):
+        def viol(value, _at):
             return jnp.maximum(value - cap, 0.0)
 
         return pair_improvement(derived.broker_replicas.astype(jnp.float32), deltas,

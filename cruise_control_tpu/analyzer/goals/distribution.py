@@ -88,11 +88,12 @@ class ResourceDistributionGoal(Goal):
         lower, upper, _cap = self._limits(state, derived, constraint)
         load = derived.broker_load[:, r]
         d = deltas.load_delta[:, r]
-        src, dst = deltas.src_broker, deltas.dst_broker
         eps = 1e-6
+        load_src, load_dst = deltas.at_src(load), deltas.at_dst(load)
+        lower_src, upper_dst = deltas.at_src(lower), deltas.at_dst(upper)
         # Round-start loads shifted by same-round higher-ranked candidates.
-        ls = load[src] - deltas.pre_load("pre_src_load", r)
-        ld = load[dst] + deltas.pre_load("pre_dst_load", r)
+        ls = load_src - deltas.pre_load("pre_src_load", r)
+        ld = load_dst + deltas.pre_load("pre_dst_load", r)
 
         # BRANCH CHOICE uses the UNSHIFTED loads: the pre terms may
         # overcount (rejected earlier candidates are included), and a
@@ -101,27 +102,27 @@ class ResourceDistributionGoal(Goal):
         # breaking the conservative-relaxation contract. The band/util
         # CHECKS inside each branch use the shifted loads, where overcount
         # is strictly stricter.
-        src_above_lower = load[src] >= lower[src] - eps
-        dst_under_upper = load[dst] <= upper[dst] + eps
-        stays_in_band = (ld + d <= upper[dst] + eps) \
-            & (ls - d >= lower[src] - eps)
+        src_above_lower = load_src >= lower_src - eps
+        dst_under_upper = load_dst <= upper_dst + eps
+        stays_in_band = (ld + d <= upper_dst + eps) \
+            & (ls - d >= lower_src - eps)
 
-        cap_src = jnp.maximum(state.capacity[src, r], 1e-9)
-        cap_dst = jnp.maximum(state.capacity[dst, r], 1e-9)
+        cap_src = jnp.maximum(deltas.at_src(state.capacity[:, r]), 1e-9)
+        cap_dst = jnp.maximum(deltas.at_dst(state.capacity[:, r]), 1e-9)
         util_src_before = ls / cap_src
         util_dst_after = (ld + d) / cap_dst
         no_worse = util_dst_after <= util_src_before + eps
 
         accept = jnp.where(src_above_lower & dst_under_upper, stays_in_band, no_worse)
         return accept | (d <= eps) | self._low_util(derived, constraint) \
-            | (~derived.alive[src])
+            | (~deltas.at_src(derived.alive))
 
     def improvement(self, state, derived, constraint, aux, deltas):
         r = int(self.resource)
         lower, upper, _cap = self._limits(state, derived, constraint)
 
-        def viol(value, idx):
-            return _band_viol(value, lower[idx], upper[idx])
+        def viol(value, at):
+            return _band_viol(value, at(lower), at(upper))
 
         imp = pair_improvement(derived.broker_load[:, r], deltas,
                                deltas.load_delta[:, r], viol)
@@ -133,8 +134,7 @@ class ResourceDistributionGoal(Goal):
         # .java:380-435).
         load = derived.broker_load[:, r]
         d = deltas.load_delta[:, r]
-        src, dst = deltas.src_broker, deltas.dst_broker
-        gap_before = load[src] - load[dst]
+        gap_before = deltas.at_src(load) - deltas.at_dst(load)
         gap_after = gap_before - 2 * d
         var_gain = (gap_before ** 2 - gap_after ** 2) * 1e-6
         return jnp.where(deltas.valid,
@@ -224,13 +224,13 @@ class ResourceDistributionGoal(Goal):
         lower, upper, _cap = self._limits(state, derived, constraint)
         load = derived.broker_load[:, r]
         d = net.load_delta[:, r]
-        src, dst = net.src_broker, net.dst_broker
 
-        def viol(value, idx):
-            return _band_viol(value, lower[idx], upper[idx])
+        def viol(value, at):
+            return _band_viol(value, at(lower), at(upper))
 
-        before = viol(load[src], src) + viol(load[dst], dst)
-        after = viol(load[src] - d, src) + viol(load[dst] + d, dst)
+        l_src, l_dst = net.at_src(load), net.at_dst(load)
+        before = viol(l_src, net.at_src) + viol(l_dst, net.at_dst)
+        after = viol(l_src - d, net.at_src) + viol(l_dst + d, net.at_dst)
         return (after <= before + 1e-6) \
             | self._low_util(derived, constraint)
 
@@ -274,20 +274,21 @@ class CountDistributionGoal(Goal):
                               else "pre_dst_count")
         pre_src = deltas.pre0("pre_src_leaders" if self.leaders
                               else "pre_src_count")
-        dst_ok = counts[deltas.dst_broker] + pre_dst + d <= upper + 1e-6
-        src_ok = counts[deltas.src_broker] - pre_src - d >= lower - 1e-6
-        return (d == 0) | (dst_ok & src_ok) | (~derived.alive[deltas.src_broker])
+        dst_ok = deltas.at_dst(counts) + pre_dst + d <= upper + 1e-6
+        src_ok = deltas.at_src(counts) - pre_src - d >= lower - 1e-6
+        return (d == 0) | (dst_ok & src_ok) \
+            | (~deltas.at_src(derived.alive))
 
     def improvement(self, state, derived, constraint, aux, deltas):
         lower, upper = self._limits(derived, constraint)
 
-        def viol(value, idx):
+        def viol(value, _at):
             return _band_viol(value, lower, upper)
 
         imp = pair_improvement(self._counts(derived), deltas, self._delta(deltas), viol)
         counts = self._counts(derived)
         d = self._delta(deltas)
-        gap_before = counts[deltas.src_broker] - counts[deltas.dst_broker]
+        gap_before = deltas.at_src(counts) - deltas.at_dst(counts)
         # Band-fixing tiebreak only (see ResourceDistributionGoal): an
         # unconditional variance term would accept O(P) in-band churn.
         var_gain = (gap_before ** 2 - (gap_before - 2 * d) ** 2) * 1e-6
@@ -363,9 +364,9 @@ class CountDistributionGoal(Goal):
         def viol(value):
             return _band_viol(value, lower, upper)
 
-        src, dst = net.src_broker, net.dst_broker
-        before = viol(counts[src]) + viol(counts[dst])
-        after = viol(counts[src] - d) + viol(counts[dst] + d)
+        c_src, c_dst = net.at_src(counts), net.at_dst(counts)
+        before = viol(c_src) + viol(c_dst)
+        after = viol(c_src - d) + viol(c_dst + d)
         return after <= before + 1e-6
 
 
@@ -399,22 +400,21 @@ class TopicReplicaDistributionGoal(Goal):
         return jnp.where(derived.alive, viol.sum(axis=0), 0.0)
 
     def acceptance(self, state, derived, constraint, aux, deltas: CandidateDeltas):
-        t = deltas.topic
         d = deltas.replica_delta.astype(jnp.float32)
-        dst_cnt = aux["counts"][t, deltas.dst_broker] \
+        dst_cnt = deltas.at_dst_topic(aux["counts"]) \
             + deltas.pre0("pre_dst_topic_count")
-        src_cnt = aux["counts"][t, deltas.src_broker] \
+        src_cnt = deltas.at_src_topic(aux["counts"]) \
             - deltas.pre0("pre_src_topic_count")
-        dst_ok = dst_cnt + d <= aux["upper"][t] + 1e-6
-        src_ok = src_cnt - d >= aux["lower"][t] - 1e-6
-        return (d == 0) | (dst_ok & src_ok) | (~derived.alive[deltas.src_broker])
+        dst_ok = dst_cnt + d <= deltas.at_topic(aux["upper"]) + 1e-6
+        src_ok = src_cnt - d >= deltas.at_topic(aux["lower"]) - 1e-6
+        return (d == 0) | (dst_ok & src_ok) \
+            | (~deltas.at_src(derived.alive))
 
     def improvement(self, state, derived, constraint, aux, deltas):
-        t = deltas.topic
         d = deltas.replica_delta.astype(jnp.float32)
-        up, lo = aux["upper"][t], aux["lower"][t]
-        src_cnt = aux["counts"][t, deltas.src_broker]
-        dst_cnt = aux["counts"][t, deltas.dst_broker]
+        up, lo = deltas.at_topic(aux["upper"]), deltas.at_topic(aux["lower"])
+        src_cnt = deltas.at_src_topic(aux["counts"])
+        dst_cnt = deltas.at_dst_topic(aux["counts"])
         before = _band_viol(src_cnt, lo, up) + _band_viol(dst_cnt, lo, up)
         after = _band_viol(src_cnt - d, lo, up) + _band_viol(dst_cnt + d, lo, up)
         imp = before - after
@@ -500,24 +500,24 @@ class PotentialNwOutGoal(Goal):
     def _pot_delta(self, state, deltas):
         # Moves shift the partition's full leader NW_OUT potential; pure
         # leadership moves don't change which brokers host replicas.
-        nw = state.leader_load[deltas.partition, int(Resource.NW_OUT)]
+        nw = deltas.at_partition(state.leader_load[:, int(Resource.NW_OUT)])
         return jnp.where(deltas.replica_delta > 0, nw, 0.0)
 
     def acceptance(self, state, derived, constraint, aux, deltas: CandidateDeltas):
         limit = self._limit(state, constraint)
         d = self._pot_delta(state, deltas)
-        dst_after = derived.pot_nw_out[deltas.dst_broker] \
+        dst_after = deltas.at_dst(derived.pot_nw_out) \
             + deltas.pre0("pre_dst_pot") + d
         # Accept if destination stays within limit, or the source was
         # already violating (net improvement allowed).
-        src_viol = derived.pot_nw_out[deltas.src_broker] > limit[deltas.src_broker]
-        return (dst_after <= limit[deltas.dst_broker] + 1e-6) | (d <= 0) | src_viol
+        src_viol = deltas.at_src(derived.pot_nw_out) > deltas.at_src(limit)
+        return (dst_after <= deltas.at_dst(limit) + 1e-6) | (d <= 0) | src_viol
 
     def improvement(self, state, derived, constraint, aux, deltas):
         limit = self._limit(state, constraint)
 
-        def viol(value, idx):
-            return jnp.maximum(value - limit[idx], 0.0)
+        def viol(value, at):
+            return jnp.maximum(value - at(limit), 0.0)
 
         return pair_improvement(derived.pot_nw_out, deltas,
                                 self._pot_delta(state, deltas), viol)
@@ -559,27 +559,27 @@ class LeaderBytesInDistributionGoal(Goal):
         return jnp.where(derived.alive, jnp.maximum(aux["lbi"] - upper, 0.0), 0.0)
 
     def _lbi_delta(self, state, deltas):
-        nw_in = state.leader_load[deltas.partition, int(Resource.NW_IN)]
+        nw_in = deltas.at_partition(state.leader_load[:, int(Resource.NW_IN)])
         return jnp.where(deltas.leader_delta > 0, nw_in, 0.0)
 
     def acceptance(self, state, derived, constraint, aux, deltas: CandidateDeltas):
         upper = self._upper(aux, constraint)
         d = self._lbi_delta(state, deltas)
-        dst_after = aux["lbi"][deltas.dst_broker] \
+        dst_after = deltas.at_dst(aux["lbi"]) \
             + deltas.pre0("pre_dst_lbi") + d
-        src_over = aux["lbi"][deltas.src_broker] > upper
+        src_over = deltas.at_src(aux["lbi"]) > upper
         return (dst_after <= upper + 1e-6) | (d <= 0) | src_over
 
     def improvement(self, state, derived, constraint, aux, deltas):
         upper = self._upper(aux, constraint)
 
-        def viol(value, idx):
+        def viol(value, _at):
             return jnp.maximum(value - upper, 0.0)
 
         imp = pair_improvement(aux["lbi"], deltas, self._lbi_delta(state, deltas), viol)
         lbi = aux["lbi"]
         d = self._lbi_delta(state, deltas)
-        gap = lbi[deltas.src_broker] - lbi[deltas.dst_broker]
+        gap = deltas.at_src(lbi) - deltas.at_dst(lbi)
         var_gain = (gap ** 2 - (gap - 2 * d) ** 2) * 1e-6
         return jnp.where(deltas.valid, imp + var_gain, -jnp.inf)
 
@@ -628,7 +628,7 @@ class PreferredLeaderElectionGoal(Goal):
                                    num_segments=b + 1)[:b]
 
     def improvement(self, state, derived, constraint, aux, deltas):
-        pref = self._preferred_slot(state, derived)[deltas.partition]
+        pref = deltas.at_partition(self._preferred_slot(state, derived))
         is_lead = deltas.replica_delta == 0
         fixes = (deltas.src_slot != pref) & (deltas.dst_slot == pref)
         imp = jnp.where(is_lead & fixes, 1.0, 0.0)
@@ -667,7 +667,7 @@ class MinTopicLeadersPerBrokerGoal(Goal):
     def acceptance(self, state, derived, constraint, aux, deltas: CandidateDeltas):
         if aux is None:
             return jnp.ones(deltas.valid.shape[0], dtype=bool)
-        cnt = aux["leader_counts"][deltas.topic, deltas.src_broker] \
+        cnt = deltas.at_src_topic(aux["leader_counts"]) \
             - deltas.pre0("pre_src_topic_leaders")
         d = deltas.leader_delta
         return (d == 0) | (cnt - d >= self.min_leaders)

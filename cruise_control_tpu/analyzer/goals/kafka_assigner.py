@@ -64,7 +64,7 @@ class KafkaAssignerEvenRackAwareGoal(RackAwareGoal):
     def acceptance(self, state, derived, constraint, aux, deltas: CandidateDeltas):
         rack_ok = super().acceptance(state, derived, constraint, aux, deltas)
         cap = self._ceiling(derived)
-        dst_after = derived.broker_replicas[deltas.dst_broker] \
+        dst_after = deltas.at_dst(derived.broker_replicas) \
             + deltas.pre0("pre_dst_count") + 1
         under_cap = dst_after <= cap
         # Deadlock breaker: a RACK-duplicate-fixing move may overshoot the
@@ -84,7 +84,7 @@ class KafkaAssignerEvenRackAwareGoal(RackAwareGoal):
         # reference never hits this because its swap exchanges the two
         # replicas atomically; here the overshoot leg is only admitted
         # where the shed leg exists, so the two-step path stays live.
-        fixes_dup = _duplicate_mask(state)[deltas.partition, deltas.src_slot]
+        fixes_dup = deltas.at_src_slot(_duplicate_mask(state))
         shed_count = self._shed_count_per_broker(state, derived)
         # COUNT-matched, not boolean: each same-round overshoot onto a
         # broker must claim a DISTINCT shed channel (pre_dst_count is the
@@ -94,7 +94,7 @@ class KafkaAssignerEvenRackAwareGoal(RackAwareGoal):
         tolerant = fixes_dup & (dst_after <= cap + 1) \
             & (under_cap
                | (deltas.pre0("pre_dst_count")
-                  < shed_count[deltas.dst_broker]))
+                  < deltas.at_dst(shed_count)))
         is_move = deltas.replica_delta > 0
         return rack_ok & jnp.where(is_move, under_cap | tolerant, True)
 
@@ -104,7 +104,7 @@ class KafkaAssignerEvenRackAwareGoal(RackAwareGoal):
         counts = derived.broker_replicas.astype(jnp.float32)
         count_imp = pair_improvement(
             counts, deltas, deltas.replica_delta.astype(jnp.float32),
-            lambda v, _b: jnp.maximum(v - cap, 0.0))
+            lambda v, _at: jnp.maximum(v - cap, 0.0))
         # Rack fixes outweigh the count violation they may create (the
         # two-step deadlock-breaking path above must score positive at
         # both steps; terminates because 2*rack + count strictly falls).
@@ -331,9 +331,9 @@ class KafkaAssignerDiskUsageDistributionGoal(Goal):
     def acceptance(self, state, derived, constraint, aux, deltas: CandidateDeltas):
         # Destination must stay inside the upper band after the move.
         _lower, upper = self._band(derived, constraint)
-        dst_cap = jnp.maximum(state.capacity[deltas.dst_broker, Resource.DISK],
-                              1e-9)
-        dst_util_after = (derived.broker_load[deltas.dst_broker, Resource.DISK]
+        dst_cap = jnp.maximum(
+            deltas.at_dst(state.capacity[:, Resource.DISK]), 1e-9)
+        dst_util_after = (deltas.at_dst(derived.broker_load[:, Resource.DISK])
                           + deltas.pre_load("pre_dst_load", int(Resource.DISK))
                           + deltas.load_delta[:, Resource.DISK]) / dst_cap
         is_move = deltas.replica_delta > 0
@@ -344,8 +344,8 @@ class KafkaAssignerDiskUsageDistributionGoal(Goal):
         load = derived.broker_load[:, Resource.DISK]
         cap = jnp.maximum(state.capacity[:, Resource.DISK], 1e-9)
 
-        def viol(value, broker):
-            util = value / cap[broker]
+        def viol(value, at):
+            util = value / at(cap)
             return jnp.maximum(util - upper, 0.0) + jnp.maximum(lower - util, 0.0)
 
         return pair_improvement(load, deltas,

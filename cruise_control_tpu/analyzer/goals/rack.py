@@ -45,6 +45,23 @@ def _duplicate_mask(state):
     return (same & earlier).any(axis=2) & exists
 
 
+def _other_replica_racks(state, deltas: CandidateDeltas):
+    """[N, S] — rack of every OTHER live replica of the candidate's
+    partition (the moving slot and empty slots read a rack no broker
+    has). Built once per grid row, where partition and moving slot are
+    fixed, then broadcast."""
+    b = state.num_brokers
+    assign_p = state.assignment[deltas.row_partition]  # [rows, S]
+    rack_pad = jnp.concatenate([state.rack, state.rack[:1]])
+    slot_racks = rack_pad[jnp.clip(assign_p, 0, b - 1)]
+    s = state.max_replication_factor
+    not_moving = jnp.arange(s, dtype=jnp.int32)[None, :] \
+        != deltas.row_src_slot[:, None]
+    no_rack = jnp.iinfo(slot_racks.dtype).min
+    return deltas.from_rows(
+        jnp.where(not_moving & (assign_p >= 0), slot_racks, no_rack))
+
+
 @dataclasses.dataclass(frozen=True)
 class RackAwareGoal(Goal):
     """Strict rack-awareness (RackAwareGoal.java): every replica of a
@@ -68,15 +85,9 @@ class RackAwareGoal(Goal):
     def _dst_rack_conflict(self, state, deltas: CandidateDeltas):
         """[N] — destination rack already hosts another replica of the
         partition (excluding the moving slot itself)."""
-        b = state.num_brokers
-        p = deltas.partition
-        assign_p = state.assignment[p]  # [N, S]
-        rack_pad = jnp.concatenate([state.rack, state.rack[:1]])
-        slot_racks = jnp.where(assign_p >= 0, rack_pad[jnp.clip(assign_p, 0, b - 1)], -1)
-        dst_rack = state.rack[deltas.dst_broker]
-        s = state.max_replication_factor
-        not_moving = jnp.arange(s, dtype=jnp.int32)[None, :] != deltas.src_slot[:, None]
-        return ((slot_racks == dst_rack[:, None]) & not_moving & (assign_p >= 0)).any(axis=1)
+        dst_rack = deltas.at_dst(state.rack)
+        return (_other_replica_racks(state, deltas)
+                == dst_rack[:, None]).any(axis=1)
 
     def acceptance(self, state, derived, constraint, aux, deltas: CandidateDeltas):
         is_move = deltas.replica_delta > 0
@@ -87,7 +98,7 @@ class RackAwareGoal(Goal):
         # A move improves iff the moving replica currently duplicates a rack
         # and the destination rack is conflict-free; it regresses iff it
         # creates a new conflict.
-        cur_dup = dup[deltas.partition, deltas.src_slot].astype(jnp.float32)
+        cur_dup = deltas.at_src_slot(dup).astype(jnp.float32)
         new_conflict = self._dst_rack_conflict(state, deltas).astype(jnp.float32)
         is_move = deltas.replica_delta > 0
         imp = jnp.where(is_move, cur_dup - new_conflict, 0.0)
@@ -120,29 +131,20 @@ class RackAwareDistributionGoal(RackAwareGoal):
         return jnp.ceil(rf / jnp.maximum(num_racks, 1)).astype(jnp.int32)
 
     def _rack_counts_at(self, state, deltas, rack_of_broker):
-        b = state.num_brokers
-        p = deltas.partition
-        assign_p = state.assignment[p]
-        slot_racks = jnp.where(assign_p >= 0,
-                               jnp.concatenate([state.rack, state.rack[:1]])[
-                                   jnp.clip(assign_p, 0, b - 1)], -1)
-        not_moving = (jnp.arange(state.max_replication_factor, dtype=jnp.int32)[None, :]
-                      != deltas.src_slot[:, None])
-        counts = ((slot_racks == rack_of_broker[:, None]) & not_moving
-                  & (assign_p >= 0)).sum(axis=1)
-        return counts
+        return (_other_replica_racks(state, deltas)
+                == rack_of_broker[:, None]).sum(axis=1)
 
     def acceptance(self, state, derived, constraint, aux, deltas: CandidateDeltas):
-        limit = self._limits(state)[deltas.partition]
-        dst_rack = state.rack[deltas.dst_broker]
+        limit = deltas.at_partition(self._limits(state))
+        dst_rack = deltas.at_dst(state.rack)
         dst_count = self._rack_counts_at(state, deltas, dst_rack)
         is_move = deltas.replica_delta > 0
         return jnp.where(is_move, dst_count + 1 <= limit, True)
 
     def improvement(self, state, derived, constraint, aux, deltas):
-        limit = self._limits(state)[deltas.partition]
-        src_rack = state.rack[deltas.src_broker]
-        dst_rack = state.rack[deltas.dst_broker]
+        limit = deltas.at_partition(self._limits(state))
+        src_rack = deltas.at_src(state.rack)
+        dst_rack = deltas.at_dst(state.rack)
         src_count = self._rack_counts_at(state, deltas, src_rack)  # excludes mover
         dst_count = self._rack_counts_at(state, deltas, dst_rack)
         over_before = jnp.maximum(src_count + 1 - limit, 0) + jnp.maximum(dst_count - limit, 0)

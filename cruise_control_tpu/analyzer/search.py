@@ -212,7 +212,7 @@ def cumulative_select(state: ClusterTensors, deltas, score: jax.Array,
         .at[sel_p].min(rank_eff)
     part_ok = ok & (first_p[sel_p] == rank)
 
-    sub = jax.tree.map(lambda a: a[idx], deltas)
+    sub = jax.tree.map(lambda a: a[idx], deltas.without_grid())
     pot, lbi = pot_lbi_deltas(state, sub)
     sub, has_earlier = attach_cumulative(sub, part_ok, pot, lbi)
     sel = part_ok & recheck(sub, has_earlier)
@@ -357,7 +357,7 @@ def score_round_candidates(state: ClusterTensors, masks: ExclusionMasks,
         accept &= g.acceptance(state, derived, constraint,
                                aux_by_goal[g.name], deltas)
 
-    moving_offline = off[deltas.partition, deltas.src_slot] & (deltas.replica_delta > 0)
+    moving_offline = deltas.at_src_slot(off) & (deltas.replica_delta > 0)
     imp = goal.improvement(state, derived, constraint, aux, deltas)
     imp = jnp.where(moving_offline & jnp.isfinite(imp) & deltas.valid,
                     jnp.maximum(imp, 0.0) + _OFFLINE_BONUS, imp)

@@ -38,12 +38,22 @@ from functools import partial
 # sort: quantize the weight into the low bits of ONE composite integer
 # key so the interleave costs a single single-key sort frame instead of
 # two two-key frames — the candidate replacement the chip campaign
-# prices against ``stride_sort``.
+# prices against ``stride_sort``. The ``accept_*`` classes (PR 26) price
+# the round body's per-broker lookups: ``_ACCEPT_TERMS`` [B] tables read
+# at every candidate of the fused route's grid (256 sources x (B/4 shared
+# destinations + the targeted column) + 256 x 3 leadership) — gathered
+# once per candidate (``accept_flat``), gathered on the grid's margins
+# and broadcast (``accept_margin``, CandidateGrid's form), and the
+# margins with the terms packed into one [B, F] table gathered as rows
+# (``accept_packed``).
 CASE_NAMES = ("topk128", "topk1024", "approx1024", "segsum", "segmax",
               "gather_grid", "scatter_m", "elemwise", "pairwise_m",
               "segsort", "rankfill", "scatter_apply",
               "cell_segsum", "frac_round", "stride_sort",
-              "stride_sort_fused")
+              "stride_sort_fused", "accept_flat", "accept_margin",
+              "accept_packed")
+
+_ACCEPT_TERMS = 100
 
 
 def _build_cases(brokers: int, partitions: int):
@@ -62,6 +72,36 @@ def _build_cases(brokers: int, partitions: int):
     midx = jax.random.randint(key, (m,), 0, brokers)
     mvals = jax.random.normal(key, (m, 4))
     loads = jax.random.normal(key, (brokers, 4))
+
+    # accept_* classes: the fused route's candidate grid at ``brokers``
+    # (pass the PADDED count: 128 / 256 for the benchmark's cells), with
+    # random brokers on its margins. ``grid`` is the solver's own
+    # CandidateGrid, so the margin forms time the code the round runs.
+    from ..analyzer.candidates import CandidateGrid
+    k_src = k_l = 256
+    k_dst = max(16, min(512, brokers // 4))
+    n_rows, n_dst = k_src + k_l, k_dst + k_src + k_l * s
+    akeys = jax.random.split(key, 3)
+    no_rows = jnp.zeros(n_rows, jnp.int32)
+    grid = CandidateGrid(
+        layout=((k_src, k_dst + 1), (k_l, s)),
+        row_src=jax.random.randint(akeys[0], (n_rows,), 0, brokers),
+        dst_margin=jax.random.randint(akeys[1], (n_dst,), 0, brokers),
+        row_partition=no_rows, row_topic=no_rows, row_src_slot=no_rows)
+    tables = jax.random.normal(akeys[2], (_ACCEPT_TERMS, brokers))
+    src_n = grid.from_rows(grid.row_src)       # [N] broker per candidate
+    dst_n = grid.from_dst(grid.dst_margin)
+    half = _ACCEPT_TERMS // 2
+
+    def accept_terms(at_src, at_dst):
+        """The consumer every form shares: 50 source terms compared with
+        50 destination terms per candidate, as the acceptance stack
+        compares them; counted, not and-ed, so every term shows in the
+        result."""
+        ok = jnp.zeros(src_n.shape, jnp.int32)
+        for f in range(half):
+            ok += at_src(f) <= at_dst(half + f) + 0.5
+        return ok.sum().astype(jnp.float32)
 
     def loop(body, carry, iters):
         def c(st):
@@ -195,6 +235,21 @@ def _build_cases(brokers: int, partitions: int):
                 fs, fv, _fi = jax.lax.sort((fk, v, idx), num_keys=1)
                 return v + fv * 1e-9 + (fs[:1] - fs[:1]).astype(v.dtype)
             return loop(bd, x, iters)
+        if which == "accept_flat":
+            return loop(lambda v: v + 1e-9 * accept_terms(
+                lambda f: v[f][src_n], lambda f: v[f][dst_n]), x, iters)
+        if which == "accept_margin":
+            return loop(lambda v: v + 1e-9 * accept_terms(
+                lambda f: grid.from_rows(v[f][grid.row_src]),
+                lambda f: grid.from_dst(v[f][grid.dst_margin])), x, iters)
+        if which == "accept_packed":
+            def bd(v):
+                t = v.T                                  # [B, F]
+                rs, ds = t[grid.row_src], t[grid.dst_margin]  # rows of F
+                return v + 1e-9 * accept_terms(
+                    lambda f: grid.from_rows(rs[:, f]),
+                    lambda f: grid.from_dst(ds[:, f]))
+            return loop(bd, x, iters)
         if which == "scatter_apply":
             # one-shot scatter apply of a full mover batch onto [P, S].
             plane = jnp.zeros((partitions, s), jnp.int32)
@@ -214,7 +269,9 @@ def _build_cases(brokers: int, partitions: int):
               "segmax": w, "gather_grid": gscore, "scatter_m": loads,
               "elemwise": w, "pairwise_m": mvals, "segsort": w,
               "rankfill": w, "scatter_apply": w, "cell_segsum": w,
-              "frac_round": w, "stride_sort": w, "stride_sort_fused": w}
+              "frac_round": w, "stride_sort": w, "stride_sort_fused": w,
+              "accept_flat": tables, "accept_margin": tables,
+              "accept_packed": tables}
     return run, inputs
 
 
