@@ -2004,6 +2004,7 @@ def optimize_goal_in_chain_megabatch(states: ClusterTensors,
             "succeeded": succeeded,
             "objective": float(obj1[b]),
             "violated_on_entry": float(viol0[b]) > 1e-6,
+            "offline_before": int(off0[b]),
             "offline_remaining": int(off1[b]),
         }
         if use_direct:
@@ -2334,6 +2335,7 @@ def optimize_goal_in_chain(state: ClusterTensors, chain: Sequence[Goal],
         "succeeded": succeeded,
         "objective": float(obj),
         "violated_on_entry": float(viol0) > 1e-6,
+        "offline_before": int(offline0),
         "offline_remaining": int(offline),
     }
     if use_direct:

@@ -13,7 +13,7 @@ import dataclasses
 import logging
 import os
 import time
-from typing import Sequence
+from typing import Callable, Sequence
 
 import jax.numpy as jnp
 import numpy as np
@@ -27,7 +27,7 @@ from .constraint import BalancingConstraint, OptimizationOptions
 from .goals import ALL_GOALS
 from .goals.base import Goal
 from .proposals import ExecutionProposal, diff_proposals
-from .search import ExclusionMasks, SearchConfig
+from .search import ExclusionMasks, OptimizationFailureError, SearchConfig
 
 LOG = logging.getLogger(__name__)
 
@@ -127,6 +127,72 @@ def _apportioned_goal_results(goal_chain: Sequence[Goal], infos: list[dict],
         violated_before=info["violated_on_entry"] or not info["succeeded"],
         swaps_applied=info.get("swaps_applied", 0))
         for g, info in zip(goal_chain, infos)]
+
+
+def _count_optimization_failure() -> None:
+    """One more pass that ended in ``OptimizationFailureError``: a hard
+    goal left unsatisfied or a drain left unfinished, counted alike."""
+    from ..utils.sensors import SENSORS
+    SENSORS.count("analyzer_optimization_failures")
+
+
+def _dispatch_spans(span):
+    """The closed ``solver.dispatch`` spans under ``span`` (directly, or
+    under a ``goal.solve`` on the per-goal routes); none with tracing off."""
+    pending = list(getattr(span, "children", ()))
+    while pending:
+        child = pending.pop()
+        if child.name == "solver.dispatch":
+            yield child
+        else:
+            pending.extend(child.children)
+
+
+def ensure_evacuated(goal_chain: Sequence[Goal], infos: Sequence[dict],
+                     final_state: Callable[[], ClusterTensors],
+                     meta: ClusterMeta, options: OptimizationOptions,
+                     span=None) -> None:
+    """The drain's guarantee, checked once a pass where every route's
+    per-goal infos converge: a pass that could move replicas and ends with
+    one still offline (on a DEAD broker: a removal marks its brokers DEAD,
+    so those are the replicas of the brokers being removed) has failed, as
+    an unsatisfied hard goal has (GoalUtils.ensureNoOfflineReplicas, which
+    the reference's replica-moving goals call). It raises, and no partial
+    plan is returned. Reads the host scalars the chain's stats already
+    fetched; only the failure path calls ``final_state`` and reads the
+    device, to name the brokers.
+
+    Also the pass's drain accounting: ``solver_offline_replicas_total
+    {when="before"|"remaining"}`` (the first goal's entry count, the last
+    goal's exit count), ``solver_evacuation_rounds_total`` (rounds of the
+    goals entered with replicas offline), and ``offline_before``,
+    ``offline_remaining``, ``excluded_brokers`` on the pass's
+    ``solver.dispatch`` spans under ``span``."""
+    if not infos:
+        return
+    from ..utils.sensors import SENSORS
+    before = infos[0]["offline_before"]
+    remaining = infos[-1]["offline_remaining"]
+    SENSORS.count("solver_offline_replicas", before,
+                  labels={"when": "before"})
+    SENSORS.count("solver_offline_replicas", remaining,
+                  labels={"when": "remaining"})
+    SENSORS.count("solver_evacuation_rounds", sum(
+        info["rounds"] for info in infos if info["offline_before"] > 0))
+    for dispatch in _dispatch_spans(span):
+        dispatch.set(
+            offline_before=before, offline_remaining=remaining,
+            excluded_brokers=len(options.excluded_brokers_for_replica_move))
+    if remaining == 0 or all(g.leadership_only for g in goal_chain):
+        return
+    from ..model.tensors import offline_replicas
+    state = final_state()
+    held = np.asarray(state.assignment)[np.asarray(offline_replicas(state))]
+    stuck = sorted(meta.broker_ids[b] for b in np.unique(held))
+    raise OptimizationFailureError(
+        f"{remaining} of {before} offline replicas could not be moved off "
+        f"dead or removed brokers {stuck}: no eligible destination under "
+        "the hard goals")
 
 
 # Goals whose direct-transport arm stays ahead of greedy even at sparse
@@ -591,9 +657,13 @@ class GoalOptimizer:
                     seq=self._pass_seq + 1,
                     shape=(state.num_partitions,
                            state.num_brokers)) as flight_pass:
-            return self._optimizations_traced(
-                state, meta, goals, options, _opt_span, flight_pass,
-                t_start=time.time(), initial_state=initial_state)
+            try:
+                return self._optimizations_traced(
+                    state, meta, goals, options, _opt_span, flight_pass,
+                    t_start=time.time(), initial_state=initial_state)
+            except OptimizationFailureError:
+                _count_optimization_failure()
+                raise
 
     def _optimizations_traced(self, state: ClusterTensors, meta: ClusterMeta,
                               goals: Sequence[Goal] | None,
@@ -767,6 +837,7 @@ class GoalOptimizer:
                         masks.excluded_replica_move_brokers).any())
                 stats.fingerprint = violation_fingerprint(hint_viol)
             goal_results = []
+            infos = []
             # Donation gate for the chain's FIRST mutating dispatch: until
             # some goal has actually run a dispatch, the threaded state is
             # still the caller's buffers (``initial`` feeds the proposal
@@ -802,6 +873,7 @@ class GoalOptimizer:
                     gsp.set(rounds=info["rounds"],
                             moves_applied=info["moves_applied"],
                             succeeded=info["succeeded"])
+                infos.append(info)
                 goal_results.append(GoalResult(
                     name=g.name, is_hard=g.is_hard,
                     succeeded=info["succeeded"],
@@ -812,6 +884,8 @@ class GoalOptimizer:
                     or not info["succeeded"],
                     swaps_applied=info.get("swaps_applied", 0)))
 
+        ensure_evacuated(goal_chain, infos, lambda: state, meta, options,
+                         _opt_span)
         if stats.goals_skipped:
             from ..utils.sensors import SENSORS as _S
             _S.count("solver_goals_skipped", stats.goals_skipped)
@@ -1117,6 +1191,21 @@ class GoalOptimizer:
                             # it for the rest of the chain preserves that.
                             errors[b] = self._megabatch_error(info)
                             dead[b] = True
+                for b in range(n):
+                    if dead[b]:
+                        continue
+                    try:
+                        ensure_evacuated(
+                            goal_chain, [infos[b] for infos
+                                         in results_per_goal],
+                            lambda: unstack_state(batched, b), metas[b],
+                            opts_list[b])
+                    except OptimizationFailureError as e:
+                        errors[b] = e
+                        dead[b] = True
+                for e in errors:
+                    if isinstance(e, OptimizationFailureError):
+                        _count_optimization_failure()
                 sp.set(dispatches=physical.dispatch_count,
                        errors=int(dead[cluster_mask].sum()))
             if physical.goals_skipped:
@@ -1607,7 +1696,6 @@ class GoalOptimizer:
     @staticmethod
     def _megabatch_error(info: dict) -> Exception:
         from .chain import StatsRegressionError
-        from .search import OptimizationFailureError
         cls = {"StatsRegressionError": StatsRegressionError,
                "OptimizationFailureError": OptimizationFailureError}.get(
             info.get("error_type"), RuntimeError)

@@ -92,7 +92,7 @@ def test_remove_brokers_moves_all_replicas_off():
     # Every partition broker 3 hosted must be moved away.
     parts_on_3 = [(t, p) for (t, p), st in
                   cc._admin.describe_partitions().items() if 3 in st.replicas]
-    assert {(pr.topic, pr.partition) for pr in held} >= set(parts_on_3)
+    assert {(pr.topic, pr.partition) for pr in held} == set(parts_on_3)
 
 
 def test_add_brokers_routes_load_to_new_broker():
