@@ -24,7 +24,9 @@ import jax
 import jax.numpy as jnp
 
 from ..model.tensors import (
-    ClusterTensors, flatten_slots, is_leader_slot, replica_exists, slot_coords,
+    ClusterTensors, broker_best, broker_flag_at, broker_reduce_form,
+    broker_segments, flatten_slots, is_leader_slot, replica_exists,
+    slot_coords,
 )
 from .derived import DerivedState
 
@@ -363,26 +365,49 @@ def compute_deltas(state: ClusterTensors, derived: DerivedState,
     )
 
 
+# The form select_sources last took in this process ("dense" or "segment",
+# model.tensors.broker_reduce_form): fixed when a program is traced, so it
+# is written then, and the ``solver.dispatch`` spans report it.
+_source_select_traced: str | None = None
+
+
+def source_select() -> str | None:
+    """How the last traced source selection reduced the flat replica axis
+    per broker (None before any trace): the ``source_select`` attribute of
+    the ``solver.dispatch`` spans."""
+    return _source_select_traced
+
+
 @jax.named_scope("round.source_topk")
 def select_sources(state: ClusterTensors, source_score: jax.Array,
                    replica_weight: jax.Array, num_sources: int,
-                   ) -> tuple[jax.Array, jax.Array, jax.Array]:
+                   ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """The move grid's source-replica selection (broker-diverse top-k; see
-    generate_candidates). Returns (cand_p [k], cand_s [k], src_valid [k]).
+    generate_candidates). Returns (cand_p [k], cand_s [k], src_valid [k],
+    on_source [n_flat]): the cards, and which flat replicas sit on a
+    source broker at all (the leadership block ranks the leaders among
+    them).
 
-    Deterministic in its inputs and called by both generate_candidates and
-    callers that need the source list FIRST (to compute per-card targeted
-    destinations, analyzer.fill) — the duplicated trace is structurally
-    identical, so XLA CSE collapses it."""
+    A caller that needs the source list FIRST (to compute per-card
+    targeted destinations, analyzer.fill) hands the whole result on as
+    ``generate_candidates(..., sources=...)``: the selection is traced and
+    run once a round.
+
+    The per-broker reductions take the form ``broker_reduce_form`` gives
+    for these shapes (dense compare-and-reduce, or ``segment_*``); cards
+    and validity are the same under both."""
+    global _source_select_traced
     b = state.num_brokers
     s_dim = state.max_replication_factor
-    exists = replica_exists(state)
-    seg = jnp.where(state.assignment >= 0, state.assignment, b)
-    on_source = (jnp.concatenate([source_score, jnp.array([-1.0])])[seg] > 0.0) & exists
+    seg_flat = broker_segments(state)
+    n_flat = seg_flat.shape[0]
+    form = broker_reduce_form(b, n_flat)
+    _source_select_traced = form
+    on_source = broker_flag_at(source_score > 0.0, seg_flat, form) \
+        & flatten_slots(replica_exists(state))
 
-    flat_weight = flatten_slots(
-        jnp.where(on_source, replica_weight, -jnp.inf))
-    n_flat = flat_weight.shape[0]
+    flat_weight = jnp.where(on_source, flatten_slots(replica_weight),
+                            -jnp.inf)
     k_src = min(num_sources, n_flat)
 
     # Source rows must be BROKER-DIVERSE: conflict-free selection admits at
@@ -396,8 +421,6 @@ def select_sources(state: ClusterTensors, source_score: jax.Array,
     # the best (and second-best) replica of each of the top source brokers.
     quarter = min(k_src // 4, b)
     half = k_src - 2 * quarter            # exact: half + 2*quarter == k_src
-    seg_flat = flatten_slots(seg)
-    idxs = jnp.arange(n_flat, dtype=jnp.int32)
 
     g_w, g_idx = jax.lax.top_k(flat_weight, half)
     # Mask the global block's rows out of the per-broker selection so the
@@ -408,18 +431,8 @@ def select_sources(state: ClusterTensors, source_score: jax.Array,
         jnp.where(jnp.isfinite(g_w), g_idx, n_flat)].set(True)[:n_flat]
     flat_weight_rest = jnp.where(in_global, -jnp.inf, flat_weight)
 
-    def per_broker_best(fw):
-        smax = jax.ops.segment_max(fw, seg_flat, num_segments=b + 1)
-        is_best = jnp.isfinite(fw) & (fw == smax[seg_flat])
-        best = jax.ops.segment_min(jnp.where(is_best, idxs, n_flat),
-                                   seg_flat, num_segments=b + 1)
-        return smax[:b], best[:b]          # [B] weight, [B] flat idx
-
-    w1, best1 = per_broker_best(flat_weight_rest)
-    w2, best2 = per_broker_best(
-        jnp.where(idxs == jnp.concatenate(
-            [best1, jnp.array([n_flat], jnp.int32)])[seg_flat],
-            -jnp.inf, flat_weight_rest))
+    w1, best1 = broker_best(flat_weight_rest, seg_flat, b, form)
+    w2, best2 = broker_best(flat_weight_rest, seg_flat, b, form, skip=best1)
     b_score = jnp.where(jnp.isfinite(w1), source_score, -jnp.inf)
     tb_score, top_brokers = jax.lax.top_k(b_score, quarter)
     broker_ok = jnp.isfinite(tb_score)
@@ -432,7 +445,8 @@ def select_sources(state: ClusterTensors, source_score: jax.Array,
     src_valid &= top_idx < n_flat
     top_idx = jnp.minimum(top_idx, n_flat - 1)
     cand_p, cand_s = slot_coords(top_idx, state.num_partitions, s_dim)
-    return cand_p.astype(jnp.int32), cand_s.astype(jnp.int32), src_valid
+    return (cand_p.astype(jnp.int32), cand_s.astype(jnp.int32), src_valid,
+            on_source)
 
 
 @jax.named_scope("round.candidates")
@@ -442,6 +456,7 @@ def generate_candidates(state: ClusterTensors, derived: DerivedState,
                         num_dests: int, include_leadership: bool,
                         leadership_only: bool = False,
                         extra_dst: "tuple[jax.Array, jax.Array] | None" = None,
+                        sources: "tuple[jax.Array, ...] | None" = None,
                         ) -> "tuple[Candidates, tuple[tuple[int, int], ...]]":
     """Top-k × top-k candidate grid.
 
@@ -454,6 +469,9 @@ def generate_candidates(state: ClusterTensors, derived: DerivedState,
       destination (Goal.target_dests over the select_sources card list),
       appended as one more column of the move block so each source also
       competes with a destination constructed for it.
+    - ``sources``: the ``select_sources`` result for these very scores,
+      weights and ``num_sources``, from a caller that already holds it
+      (it made ``extra_dst`` from the cards); selected here otherwise.
 
     Replica moves: the ``num_sources`` highest-weight replicas living on
     positive-score source brokers × the ``num_dests`` best destinations.
@@ -467,12 +485,11 @@ def generate_candidates(state: ClusterTensors, derived: DerivedState,
     """
     b = state.num_brokers
     s_dim = state.max_replication_factor
-    exists = replica_exists(state)
-    seg = jnp.where(state.assignment >= 0, state.assignment, b)
-    on_source = (jnp.concatenate([source_score, jnp.array([-1.0])])[seg] > 0.0) & exists
-    k_src = min(num_sources, exists.size)
-    cand_p, cand_s, src_valid = select_sources(state, source_score,
-                                               replica_weight, num_sources)
+    if sources is None:
+        sources = select_sources(state, source_score, replica_weight,
+                                 num_sources)
+    cand_p, cand_s, src_valid, on_source = sources
+    k_src = cand_p.shape[0]
 
     layout: list[tuple[int, int]] = []
     parts: list[Candidates] = []
@@ -503,9 +520,8 @@ def generate_candidates(state: ClusterTensors, derived: DerivedState,
     if include_leadership or leadership_only:
         # Leadership candidates: for each top source replica that IS a
         # leader, try every other slot.
-        lead_mask = is_leader_slot(state)
-        lead_weight = jnp.where(on_source & lead_mask, replica_weight, -jnp.inf)
-        flat_lw = flatten_slots(lead_weight)
+        flat_lw = jnp.where(on_source & flatten_slots(is_leader_slot(state)),
+                            flatten_slots(replica_weight), -jnp.inf)
         k_l = min(num_sources, flat_lw.shape[0])
         top_lw, top_lidx = jax.lax.top_k(flat_lw, k_l)
         lp = slot_coords(top_lidx, state.num_partitions,

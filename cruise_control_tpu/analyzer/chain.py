@@ -34,11 +34,15 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 
-from ..model.tensors import ClusterTensors, flatten_slots, offline_replicas
+from ..model.tensors import (
+    ClusterTensors, offline_per_broker, offline_replicas,
+)
 from .agg import (
     AggCarry, apply_deltas_to_agg, compute_agg, maybe_refresh, pot_lbi_deltas,
 )
-from .candidates import compute_deltas, generate_candidates, select_sources
+from .candidates import (
+    compute_deltas, generate_candidates, select_sources, source_select,
+)
 from .fill import targets_enabled
 from .constraint import BalancingConstraint
 from .derived import compute_derived
@@ -206,9 +210,16 @@ def accept_lookup() -> str | None:
     return _accept_lookup_traced
 
 
-def _set_accept_lookup(dispatch, kind: str = "move") -> None:
-    if kind == "move" and _accept_lookup_traced is not None:
+def _set_traced_forms(dispatch, kind: str = "move") -> None:
+    """The traced forms of the move-round body onto a dispatch span:
+    ``accept_lookup``, and ``source_select`` (candidates.source_select:
+    how the source selection reduces the flat replica axis per broker)."""
+    if kind != "move":
+        return
+    if _accept_lookup_traced is not None:
         dispatch.set(accept_lookup=_accept_lookup_traced)
+    if source_select() is not None:
+        dispatch.set(source_select=source_select())
 
 
 def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
@@ -260,26 +271,19 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
         # offline replicas are always sources with maximal weight for
         # non-leadership goals.
         off = offline_replicas(state)  # [P, S]
-        b = state.num_brokers
-        seg = flatten_slots(
-            jnp.where(state.assignment >= 0, state.assignment, b))
-        offline_per_broker = jax.ops.segment_sum(
-            flatten_slots(off.astype(jnp.float32)), seg,
-            num_segments=b + 1)[:b]
         src_score = src_score + jnp.where(is_lead_only, 0.0,
-                                          offline_per_broker)
+                                          offline_per_broker(state, off))
         weight = jnp.where(off & ~is_lead_only, 1e30, weight)
 
     # UNIFORM grid layout: both the move and the leadership block always
     # exist (static shapes shared by every goal); the active goal's traced
     # flags mask out the block it doesn't use. The targeted-destination
-    # column (Goal.target_dests) rides the move block; select_sources here
-    # duplicates generate_candidates' internal selection structurally, so
-    # XLA CSE collapses the two.
-    extra = None
+    # column (Goal.target_dests) rides the move block: it is made from the
+    # cards, so the sources are selected first and handed on.
+    extra = sources = None
     if targets_enabled(state.num_partitions):
-        cand_p, cand_s, src_valid = select_sources(state, src_score, weight,
-                                                   cfg.num_sources)
+        sources = select_sources(state, src_score, weight, cfg.num_sources)
+        cand_p, cand_s, src_valid, _on_source = sources
         # Targets pause while ANY offline replica exists (traced scalar):
         # targeted steering during a drain locks in placements later
         # goals cannot repair (1k drain-50: balancedness 86.0 -> 82.74
@@ -294,7 +298,7 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
                                        weight, cfg.num_sources, cfg.num_dests,
                                        include_leadership=True,
                                        leadership_only=False,
-                                       extra_dst=extra)
+                                       extra_dst=extra, sources=sources)
     (r0, c0), (r1, c1) = layout
     block_ok = jnp.concatenate([
         jnp.broadcast_to(~is_lead_only, (r0 * c0,)),
@@ -876,7 +880,7 @@ def optimize_chain(state: ClusterTensors, chain: Sequence[Goal],
             stats = {k: jax.device_get(v) for k, v in stats.items()}
         infos = _chain_infos_from_stats(goals, stats)
         set_dispatch_rounds(dispatch, infos)
-        _set_accept_lookup(dispatch)
+        _set_traced_forms(dispatch)
     return state, infos
 
 
@@ -1272,7 +1276,7 @@ def run_bounded_pass(enqueue: Callable, st, pass_cap: int,
                                  or (out_of_time is not None and out_of_time())):
                 break
         dispatch.set(rounds=pass_rounds)
-        _set_accept_lookup(dispatch, kind)
+        _set_traced_forms(dispatch, kind)
     return st, applied_total, pass_rounds
 
 
@@ -1698,7 +1702,7 @@ def run_megabatch_pass(enqueue: Callable, st, active0, pass_cap: int,
                 break
         # ccsa: ok[CCSA001] host numpy totals of reads already paid
         dispatch.set(rounds=int(rounds_total.max()) if c else 0)
-        _set_accept_lookup(dispatch, kind)
+        _set_traced_forms(dispatch, kind)
     return st, active_host, applied_total, rounds_total
 
 

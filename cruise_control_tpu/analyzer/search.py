@@ -32,7 +32,8 @@ import jax.numpy as jnp
 
 from ..common.resources import Resource
 from ..model.tensors import (
-    ClusterTensors, flatten_slots, offline_replicas, slot_coords,
+    ClusterTensors, flatten_slots, offline_per_broker, offline_replicas,
+    slot_coords,
 )
 from .agg import pot_lbi_deltas
 from .candidates import (
@@ -306,16 +307,11 @@ def score_round_candidates(state: ClusterTensors, masks: ExclusionMasks,
     # bonus so it wins over pure balance refinements
     # (ClusterModel.selfHealingEligibleReplicas / _fixOfflineReplicasOnly).
     off = offline_replicas(state)  # [P, S]
-    b = state.num_brokers
-    seg = flatten_slots(
-        jnp.where(state.assignment >= 0, state.assignment, b))
-    offline_per_broker = jax.ops.segment_sum(
-        flatten_slots(off.astype(jnp.float32)), seg,
-        num_segments=b + 1)[:b]
+    offline_pb = offline_per_broker(state, off)
     if psum is not None:
-        offline_per_broker = psum(offline_per_broker)
+        offline_pb = psum(offline_pb)
     if not goal.leadership_only:
-        src_score = src_score + offline_per_broker
+        src_score = src_score + offline_pb
         weight = jnp.where(off, 1e30, weight)  # finite: top-k validity uses isfinite
 
     # Targeted destination column (Goal.target_dests over the shared
@@ -328,14 +324,14 @@ def score_round_candidates(state: ClusterTensors, masks: ExclusionMasks,
     # kernels' shared-destination arithmetic agrees).
     from .fill import targets_enabled
     k_eff = k_src or cfg.num_sources
-    extra = None
+    extra = sources = None
     # psum set = partition-sharded mesh: targeted fills are single-device
     # only (device-local fill ranks collide across shards — see
     # parallel/chain_sharded.py).
     if targets_enabled(state.num_partitions) and not goal.leadership_only \
             and psum is None:
-        cand_p, cand_s, src_valid = select_sources(state, src_score, weight,
-                                                   k_eff)
+        sources = select_sources(state, src_score, weight, k_eff)
+        cand_p, cand_s, src_valid, _on_source = sources
         extra = goal.target_dests(state, derived, constraint, aux,
                                   cand_p, cand_s, src_valid)
         if extra is None:
@@ -349,7 +345,7 @@ def score_round_candidates(state: ClusterTensors, masks: ExclusionMasks,
     cand, layout = generate_candidates(state, derived, src_score, dst_score, weight,
                                        k_eff, cfg.num_dests,
                                        goal.include_leadership, goal.leadership_only,
-                                       extra_dst=extra)
+                                       extra_dst=extra, sources=sources)
     deltas = compute_deltas(state, derived, cand)
 
     accept = deltas.valid
@@ -399,11 +395,9 @@ def _per_broker_top_replicas(state: ClusterTensors, weight: jax.Array,
     ``weight[P, S]`` (largest or smallest). Returns (flat_idx[K, j],
     valid[K, j]) into the ``flatten_slots`` replica axis (decode with
     ``slot_coords``)."""
-    from ..model.tensors import replica_exists
+    from ..model.tensors import broker_segments, replica_exists
     exists = replica_exists(state)
-    b = state.num_brokers
-    seg = flatten_slots(
-        jnp.where(state.assignment >= 0, state.assignment, b))
+    seg = broker_segments(state)
     flat_w = flatten_slots(jnp.where(exists, weight, jnp.nan))
 
     def one(broker):

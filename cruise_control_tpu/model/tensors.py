@@ -183,12 +183,124 @@ def slot_coords(flat_idx: jax.Array, num_partitions: int,
     return flat_idx // num_slots, flat_idx % num_slots
 
 
+# ---- per-broker reductions of the flat replica axis ------------------------
+#
+# The round body asks, every round, per-broker questions of the flat
+# replica axis (each broker's best replica, its count of offline replicas)
+# and per-replica questions of a [B] table (is my broker a source). Two
+# forms give the same answers, bit for bit (docs/DESIGN.md "Per-broker
+# reductions of the flat replica axis"):
+#
+# - "segment": ``jax.ops.segment_*`` keyed by broker id and ``table[seg]``.
+#   On the v5e a scatter or a gather costs by the element it touches (5.5-
+#   5.9 ns), whatever B is.
+# - "dense": one masked reduce over the ``[B, n_flat]`` compare
+#   ``arange(B)[:, None] == seg[None, :]``, which XLA fuses and never
+#   materialises. It costs by the CELL, B * n_flat of them a pass.
+#
+# DENSE_BROKER_CELLS is the power of two above the largest ``b * n_flat``
+# measured on the chip (``utils/microbench.py``, PR 28, ms an iteration of each
+# broker's best and second best with the source lookup, segment / dense:
+# 1.97 / 0.092 at 128 brokers x 30,000 replicas, 4.90 / 0.235 at 256 x
+# 75,000, 19.3 / 1.83 at 1,024 x 300,000 = 3.1e8 cells). The forms have not
+# met there; beyond it nothing is measured and a mask XLA did materialise
+# would be gigabytes, so the segment form stays. XLA:CPU runs a scatter as
+# a tight loop and the dense form twenty to fifty times slower at real
+# sizes, so the CPU keeps "segment" (and tier-1 its running time).
+DENSE_BROKER_CELLS = 1 << 29
+
+
+def broker_reduce_form(num_brokers: int, n_flat: int) -> str:
+    """"dense" or "segment": the form the per-broker helpers below take at
+    these (static) shapes on this backend. The ONE place that chooses."""
+    if jax.default_backend() == "cpu":
+        return "segment"
+    return "dense" if num_brokers * n_flat <= DENSE_BROKER_CELLS \
+        else "segment"
+
+
+def broker_segments(state: ClusterTensors) -> jax.Array:
+    """[n_flat] int32 — the broker of every flat replica; empty slots go to
+    the dead bucket ``num_brokers``, which no dense column matches."""
+    return flatten_slots(jnp.where(state.assignment >= 0, state.assignment,
+                                   state.num_brokers))
+
+
+def _broker_mask(seg_flat: jax.Array, b: int) -> jax.Array:
+    """[B, n_flat] bool — row i marks broker i's flat replicas."""
+    return jnp.arange(b, dtype=seg_flat.dtype)[:, None] == seg_flat[None, :]
+
+
+def broker_best(fw: jax.Array, seg_flat: jax.Array, b: int, form: str,
+                skip: jax.Array | None = None,
+                ) -> tuple[jax.Array, jax.Array]:
+    """Per broker, the largest weight ``fw`` among its flat replicas and the
+    LOWEST flat index that attains it: ``(w [B], idx [B])``. A broker with
+    no replica, or none of finite weight, reads ``n_flat`` as its index
+    (and -inf, or its non-finite maximum, as its weight). ``skip [B]``
+    leaves one flat index per broker out, for the second best. Ties break
+    by flat index in both forms: the CPU trajectories are pinned under it
+    (``slot_major_flat``)."""
+    n_flat = fw.shape[0]
+    idxs = jnp.arange(n_flat, dtype=jnp.int32)
+    if form == "dense":
+        mask = _broker_mask(seg_flat, b)
+        if skip is not None:
+            mask &= idxs[None, :] != skip[:, None]
+        masked = jnp.where(mask, fw[None, :], -jnp.inf)
+        w = masked.max(axis=1)
+        # argmax takes the first of equals: the lowest flat index
+        first = jnp.argmax(masked, axis=1).astype(jnp.int32)
+        return w, jnp.where(jnp.isfinite(w), first, n_flat)
+    if skip is not None:
+        dead = jnp.array([n_flat], jnp.int32)
+        fw = jnp.where(idxs == jnp.concatenate([skip, dead])[seg_flat],
+                       -jnp.inf, fw)
+    smax = jax.ops.segment_max(fw, seg_flat, num_segments=b + 1)
+    is_best = jnp.isfinite(fw) & (fw == smax[seg_flat])
+    best = jax.ops.segment_min(jnp.where(is_best, idxs, n_flat), seg_flat,
+                               num_segments=b + 1)
+    # an empty segment reads int32's maximum
+    return smax[:b], jnp.minimum(best[:b], n_flat)
+
+
+def broker_count(flags: jax.Array, seg_flat: jax.Array, b: int,
+                 form: str) -> jax.Array:
+    """[B] float32 — how many flat replicas of each broker carry ``flags``
+    ([n_flat] bool). A count, so the order of summation cannot show (exact
+    below 2**24)."""
+    if form == "dense":
+        return jnp.where(_broker_mask(seg_flat, b) & flags[None, :],
+                         1.0, 0.0).sum(axis=1)
+    return jax.ops.segment_sum(flags.astype(jnp.float32), seg_flat,
+                               num_segments=b + 1)[:b]
+
+
+def broker_flag_at(flag: jax.Array, seg_flat: jax.Array,
+                   form: str) -> jax.Array:
+    """[n_flat] bool — ``flag [B]`` of every flat replica's broker; False
+    in the dead bucket."""
+    if form == "dense":
+        return (_broker_mask(seg_flat, flag.shape[0])
+                & flag[:, None]).any(axis=0)
+    return jnp.concatenate([flag, jnp.array([False])])[seg_flat]
+
+
+def offline_per_broker(state: ClusterTensors, off: jax.Array) -> jax.Array:
+    """[B] float32 — replicas marked in ``off`` ([P, S] bool,
+    ``offline_replicas``) per broker: the self-healing term of the source
+    score."""
+    seg_flat = broker_segments(state)
+    b = state.num_brokers
+    return broker_count(flatten_slots(off), seg_flat, b,
+                        broker_reduce_form(b, seg_flat.shape[0]))
+
+
 def _scatter_to_brokers(state: ClusterTensors, per_slot: jax.Array) -> jax.Array:
     """Sum a [P, S] or [P, S, R] per-replica quantity into per-broker rows
     ([B] or [B, R]). Padded slots route to a dead bucket at index B."""
     b = state.num_brokers
-    seg = flatten_slots(jnp.where(state.assignment >= 0, state.assignment, b))
-    out = jax.ops.segment_sum(flatten_slots(per_slot), seg,
+    out = jax.ops.segment_sum(flatten_slots(per_slot), broker_segments(state),
                               num_segments=b + 1)
     return out[:b]
 

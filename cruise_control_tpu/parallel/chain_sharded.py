@@ -58,7 +58,8 @@ from ..analyzer.search import (
 )
 from ..common.resources import Resource
 from ..model.tensors import (
-    ClusterTensors, flatten_slots, offline_replicas, slot_coords,
+    ClusterTensors, flatten_slots, offline_per_broker, offline_replicas,
+    slot_coords,
 )
 from .mesh import PARTITION_AXIS
 from .sharded import _mask_specs, _psum, _state_specs, mutable_state_specs
@@ -123,15 +124,6 @@ def _global_source_threshold(weight: jax.Array, src_score: jax.Array,
     return jnp.where(keep, weight, -jnp.inf)
 
 
-def _offline_per_broker(state: ClusterTensors, off: jax.Array) -> jax.Array:
-    b = state.num_brokers
-    seg = flatten_slots(
-        jnp.where(state.assignment >= 0, state.assignment, b))
-    local = jax.ops.segment_sum(flatten_slots(off.astype(jnp.float32)), seg,
-                                num_segments=b + 1)[:b]
-    return _psum(local)
-
-
 def _chain_scores(state, derived, active_idx, prior_mask, goals, constraint,
                   num_topics, additive_f, agg=None):
     """(aux_list, src_score, dst_score, weight) for the active goal under
@@ -178,7 +170,7 @@ def _chain_round_local(state: ClusterTensors, agg, masks: ExclusionMasks,
 
     # Self-healing priority (score_round_candidates semantics).
     off = offline_replicas(state)
-    offline_pb = _offline_per_broker(state, off)
+    offline_pb = _psum(offline_per_broker(state, off))
     src_score = src_score + jnp.where(is_lead_only, 0.0, offline_pb)
     weight = jnp.where(off & ~is_lead_only, 1e30, weight)
     if _GLOBAL_THETA and num_shards > 1:
@@ -197,12 +189,12 @@ def _chain_round_local(state: ClusterTensors, agg, masks: ExclusionMasks,
     # off the mesh and its per-round cost with it.
     # Scale gate on the GLOBAL partition count (p_local * num_shards):
     # the threshold's measured meaning is cluster scale.
-    extra = None
+    extra = sources = None
     use_targets = targets_enabled(p_global) and (
         num_shards == 1 or os.environ.get("CC_MESH_TARGETS") == "1")
     if use_targets:
-        cand_p, cand_s, src_valid = select_sources(state, src_score, weight,
-                                                   k_src)
+        sources = select_sources(state, src_score, weight, k_src)
+        cand_p, cand_s, src_valid, _on_source = sources
         t_dst, t_ok = _switch_target_dests(active_idx, goals, aux_list,
                                            state, derived, constraint,
                                            cand_p, cand_s, src_valid,
@@ -215,7 +207,7 @@ def _chain_round_local(state: ClusterTensors, agg, masks: ExclusionMasks,
                                        weight, k_src, cfg.num_dests,
                                        include_leadership=True,
                                        leadership_only=False,
-                                       extra_dst=extra)
+                                       extra_dst=extra, sources=sources)
     (r0, c0), (r1, c1) = layout
     block_ok = jnp.concatenate([
         jnp.broadcast_to(~is_lead_only, (r0 * c0,)),

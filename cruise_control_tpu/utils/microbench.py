@@ -45,13 +45,20 @@ from functools import partial
 # once per candidate (``accept_flat``), gathered on the grid's margins
 # and broadcast (``accept_margin``, CandidateGrid's form), and the
 # margins with the terms packed into one [B, F] table gathered as rows
-# (``accept_packed``).
+# (``accept_packed``). The ``bbest_*`` / ``bcount_*`` classes (PR 28) price
+# the source selection's per-broker reductions of the flat replica axis
+# (model.tensors.broker_best, best and second best with the source lookup,
+# as ``select_sources`` runs them; broker_count): the solver's own
+# ``segment`` and ``dense`` forms, and for the best a sort-based
+# alternative (one three-key ``lax.sort``, the first two of each run).
+# ``topk128`` beside them is the selection's global block alone.
 CASE_NAMES = ("topk128", "topk1024", "approx1024", "segsum", "segmax",
               "gather_grid", "scatter_m", "elemwise", "pairwise_m",
               "segsort", "rankfill", "scatter_apply",
               "cell_segsum", "frac_round", "stride_sort",
               "stride_sort_fused", "accept_flat", "accept_margin",
-              "accept_packed")
+              "accept_packed", "bbest_segment", "bbest_dense", "bbest_sort",
+              "bcount_segment", "bcount_dense")
 
 _ACCEPT_TERMS = 100
 
@@ -77,7 +84,7 @@ def _build_cases(brokers: int, partitions: int):
     # (pass the PADDED count: 128 / 256 for the benchmark's cells), with
     # random brokers on its margins. ``grid`` is the solver's own
     # CandidateGrid, so the margin forms time the code the round runs.
-    from ..analyzer.candidates import CandidateGrid
+    from ..analyzer.candidates import CandidateGrid, _span
     k_src = k_l = 256
     k_dst = max(16, min(512, brokers // 4))
     n_rows, n_dst = k_src + k_l, k_dst + k_src + k_l * s
@@ -102,6 +109,48 @@ def _build_cases(brokers: int, partitions: int):
         for f in range(half):
             ok += at_src(f) <= at_dst(half + f) + 0.5
         return ok.sum().astype(jnp.float32)
+
+    # bbest_* / bcount_*: every flat replica on one of ``brokers`` brokers
+    # or in the dead bucket, as ``broker_segments`` lays them out.
+    from ..model.tensors import broker_best, broker_count, broker_flag_at
+    bseg = jax.random.randint(akeys[0], (n_flat,), 0, brokers + 1)
+    b_ids = jnp.arange(brokers, dtype=jnp.int32)
+
+    def best_two(v, form):
+        """What ``select_sources`` asks per broker, on the carry as the
+        weights: which replicas sit on a source broker, then each
+        broker's best and second best of them."""
+        on = broker_flag_at(_span(v, 0, brokers) > 0.0, bseg, form)
+        fw = jnp.where(on, v, -jnp.inf)
+        w1, i1 = broker_best(fw, bseg, brokers, form)
+        w2, i2 = broker_best(fw, bseg, brokers, form, skip=i1)
+        return w1, i1, w2, i2
+
+    def best_two_sorted(v):
+        """The same four arrays from ONE sort of the axis by (broker,
+        weight descending, flat index): a run's first two entries. The
+        source lookup is the dense one: the sort replaces the pair only."""
+        on = broker_flag_at(_span(v, 0, brokers) > 0.0, bseg, "dense")
+        fw = jnp.where(on, v, -jnp.inf)
+        ss, sw, si = jax.lax.sort(
+            (bseg, -fw, jnp.arange(n_flat, dtype=jnp.int32)), num_keys=3)
+        start = jnp.searchsorted(ss, b_ids, side="left")
+        out = []
+        for pos in (start, start + 1):
+            at = jnp.minimum(pos, n_flat - 1)
+            w = jnp.where((pos < n_flat) & (ss[at] == b_ids), -sw[at],
+                          -jnp.inf)
+            out += [w, jnp.where(jnp.isfinite(w), si[at], n_flat)]
+        return tuple(out)
+
+    def fold(arrays):
+        """Every array into the carry's next value, so none is dead."""
+        total = jnp.float32(0.0)
+        for a in arrays:
+            a = jnp.where(jnp.isfinite(a), a, 1.0) \
+                if jnp.issubdtype(a.dtype, jnp.floating) else a % 7
+            total += a.sum().astype(jnp.float32)
+        return total * 1e-9
 
     def loop(body, carry, iters):
         def c(st):
@@ -250,6 +299,15 @@ def _build_cases(brokers: int, partitions: int):
                     lambda f: grid.from_rows(rs[:, f]),
                     lambda f: grid.from_dst(ds[:, f]))
             return loop(bd, x, iters)
+        if which in ("bbest_segment", "bbest_dense"):
+            form = which.split("_")[1]
+            return loop(lambda v: v + fold(best_two(v, form)), x, iters)
+        if which == "bbest_sort":
+            return loop(lambda v: v + fold(best_two_sorted(v)), x, iters)
+        if which in ("bcount_segment", "bcount_dense"):
+            form = which.split("_")[1]
+            return loop(lambda v: v + fold(
+                [broker_count(v > 0.0, bseg, brokers, form)]), x, iters)
         if which == "scatter_apply":
             # one-shot scatter apply of a full mover batch onto [P, S].
             plane = jnp.zeros((partitions, s), jnp.int32)
@@ -271,7 +329,8 @@ def _build_cases(brokers: int, partitions: int):
               "rankfill": w, "scatter_apply": w, "cell_segsum": w,
               "frac_round": w, "stride_sort": w, "stride_sort_fused": w,
               "accept_flat": tables, "accept_margin": tables,
-              "accept_packed": tables}
+              "accept_packed": tables, "bbest_segment": w, "bbest_dense": w,
+              "bbest_sort": w, "bcount_segment": w, "bcount_dense": w}
     return run, inputs
 
 

@@ -1,0 +1,299 @@
+"""Per-broker reductions of the flat replica axis (model/tensors.py
+``broker_best`` / ``broker_count`` / ``broker_flag_at``, docs/DESIGN.md
+"Per-broker reductions of the flat replica axis"): the dense
+compare-and-reduce form and the ``segment_*`` form give the same answers,
+bit for bit, so the source selection picks the same cards, the chain walks
+the same trajectory, and ``solver.dispatch`` says which form was traced.
+The segment form is the oracle: it is what the CPU runs unforced.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from cruise_control_tpu.analyzer import candidates as cand_mod
+from cruise_control_tpu.analyzer.candidates import select_sources
+from cruise_control_tpu.analyzer.chain import (
+    chain_optimize_full, optimize_chain,
+)
+from cruise_control_tpu.analyzer.constraint import BalancingConstraint
+from cruise_control_tpu.analyzer.optimizer import goals_by_priority
+from cruise_control_tpu.analyzer.search import ExclusionMasks, SearchConfig
+from cruise_control_tpu.config.cruise_control_config import (
+    CruiseControlConfig,
+)
+from cruise_control_tpu.model import tensors
+from cruise_control_tpu.model.fixtures import random_cluster
+from cruise_control_tpu.model.tensors import (
+    BrokerState, broker_best, broker_count, broker_flag_at,
+    broker_reduce_form, broker_segments, flatten_slots, offline_per_broker,
+    offline_replicas, set_broker_state,
+)
+from cruise_control_tpu.utils.tracing import TRACER
+
+FORMS = ("segment", "dense")
+B = 6
+INF = np.inf
+
+# name -> (weights, brokers) of a flat axis; broker B is the dead bucket
+FLAT_AXES = {
+    "ties break by the lowest flat index": (
+        [5.0, 5.0, 1.0, 5.0, 7.0, 7.0, 7.0, 2.0],
+        [0, 0, 0, 1, 2, 2, 2, 1]),
+    "brokers holding 0, 1 and 2 finite weights": (
+        [3.0, -INF, 4.0, 9.0, -INF, -INF, 1.0],
+        [1, 1, 2, 2, 3, 3, 5]),
+    "nothing finite anywhere": ([-INF] * 5, [0, 1, 2, 3, 4]),
+    "the dead bucket holds the heaviest": (
+        [1e30, 2.0, 1e30, 3.0, 3.0], [B, 0, B, 4, 4]),
+    "a drain's 1e30 beside ordinary weights": (
+        [1e30, 1e30, 0.5, 1e30, 0.25, 0.125], [2, 2, 2, 3, 3, 0]),
+    "an infinite weight is no best": ([INF, 1.0, 2.0], [0, 0, 1]),
+    "every replica on one broker": ([1.0, 4.0, 4.0, 2.0], [3, 3, 3, 3]),
+}
+
+
+def _flat(name):
+    w, seg = FLAT_AXES[name]
+    return jnp.asarray(w, jnp.float32), jnp.asarray(seg, jnp.int32)
+
+
+@pytest.mark.parametrize("name", sorted(FLAT_AXES))
+def test_broker_best_and_second_best_equal_under_both_forms(name):
+    fw, seg = _flat(name)
+    n = fw.shape[0]
+    got = {}
+    for form in FORMS:
+        w1, i1 = broker_best(fw, seg, B, form)
+        w2, i2 = broker_best(fw, seg, B, form, skip=i1)
+        got[form] = [np.asarray(x) for x in (w1, i1, w2, i2)]
+    for a, b in zip(got["segment"], got["dense"]):
+        np.testing.assert_array_equal(a, b)
+    # and both are the plain definition: max, lowest index attaining it
+    w1, i1, w2, i2 = got["dense"]
+    w_np, seg_np = np.asarray(fw), np.asarray(seg)
+    for broker in range(B):
+        mine = [i for i in range(n) if seg_np[i] == broker]
+        finite = [i for i in mine if np.isfinite(w_np[i])]
+        if not finite or max(w_np[i] for i in mine) == INF:
+            assert i1[broker] == n
+            continue
+        first = min(finite, key=lambda i: (-w_np[i], i))
+        assert (w1[broker], i1[broker]) == (w_np[first], first)
+        rest = [i for i in finite if i != first]
+        if rest:
+            second = min(rest, key=lambda i: (-w_np[i], i))
+            assert (w2[broker], i2[broker]) == (w_np[second], second)
+        else:
+            assert i2[broker] == n and w2[broker] == -INF
+
+
+@pytest.mark.parametrize("name", sorted(FLAT_AXES))
+def test_broker_count_and_flag_lookup_equal_under_both_forms(name):
+    fw, seg = _flat(name)
+    flags = jnp.isfinite(fw)
+    table = jnp.arange(B) % 2 == 0
+    counts = [np.asarray(broker_count(flags, seg, B, f)) for f in FORMS]
+    looked = [np.asarray(broker_flag_at(table, seg, f)) for f in FORMS]
+    np.testing.assert_array_equal(*counts)
+    np.testing.assert_array_equal(*looked)
+    seg_np = np.asarray(seg)
+    np.testing.assert_array_equal(
+        counts[0], [(np.asarray(flags) & (seg_np == i)).sum()
+                    for i in range(B)])
+    np.testing.assert_array_equal(
+        looked[0], [s < B and s % 2 == 0 for s in seg_np])
+
+
+def test_form_follows_shape_and_backend(monkeypatch):
+    """One place chooses, from the static shapes and the backend: the CPU
+    keeps the segment form at every size; elsewhere the dense form up to
+    DENSE_BROKER_CELLS cells, which holds the benchmark's cells and the
+    largest size the microbench measured (1,024 brokers / 100,000
+    partitions), and nothing beyond."""
+    cells = tensors.DENSE_BROKER_CELLS
+    assert broker_reduce_form(16, 1536) == "segment"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert broker_reduce_form(128, 30_000) == "dense"
+    assert broker_reduce_form(256, 75_000) == "dense"
+    assert broker_reduce_form(1, cells) == "dense"
+    assert broker_reduce_form(1, cells + 1) == "segment"
+    assert broker_reduce_form(1024, 300_000) == "dense"
+    assert broker_reduce_form(2048, 600_000) == "segment"
+    assert broker_reduce_form(7168, 3_000_000) == "segment"
+
+
+def _force(monkeypatch, form):
+    """Steer the one chooser, for every module that imported it. A jitted
+    program traced under another form must be dropped by the caller."""
+    for mod in (tensors, cand_mod):
+        monkeypatch.setattr(mod, "broker_reduce_form", lambda b, n: form)
+
+
+def _cluster(name):
+    """(state, source_score, weight) of one selection scenario."""
+    rng = np.random.default_rng(11)
+    kw = dict(num_brokers=12, num_topics=4, num_partitions=96, rf=3,
+              num_racks=4, seed=3, skew_to_first=2.0)
+    if name == "padded partitions and -1 slots":
+        kw.update(rf=2)
+    state, _meta = random_cluster(**kw)
+    shape = state.assignment.shape
+    score = jnp.asarray(rng.uniform(0.1, 1.0, state.num_brokers),
+                        jnp.float32)
+    weight = jnp.asarray(rng.uniform(1.0, 2.0, shape), jnp.float32)
+    if name == "weight ties":
+        weight = jnp.asarray(rng.integers(1, 4, shape), jnp.float32)
+    elif name == "brokers with 0, 1 and 2 eligible replicas":
+        # -inf all but one replica of broker 1 and two of broker 2, and
+        # every replica of broker 3
+        a = np.asarray(state.assignment)
+        w = np.asarray(weight).copy()
+        for broker, keep in ((1, 1), (2, 2), (3, 0)):
+            at = np.argwhere(a == broker)
+            for p, s in at[keep:]:
+                w[p, s] = -INF
+        weight = jnp.asarray(w)
+    elif name == "no source at all":
+        score = jnp.full(state.num_brokers, -1.0)
+    elif name == "one source broker":
+        score = jnp.where(jnp.arange(state.num_brokers) == 4, 1.0, 0.0)
+    elif name == "replicas on DEAD brokers":
+        state = set_broker_state(state, jnp.asarray([0, 7]),
+                                 BrokerState.DEAD)
+        off = offline_replicas(state)
+        assert int(off.sum()) > 0
+        score = score + offline_per_broker(state, off)
+        weight = jnp.where(off, 1e30, weight)
+    elif name == "padded partitions and -1 slots":
+        a = np.asarray(state.assignment).copy()
+        a[::5, 1] = -1
+        mask = np.asarray(state.partition_mask).copy()
+        mask[-7:] = False
+        state = dataclasses.replace(state, assignment=jnp.asarray(a),
+                                    partition_mask=jnp.asarray(mask))
+    return state, score, weight
+
+
+SCENARIOS = ("plain", "weight ties",
+             "brokers with 0, 1 and 2 eligible replicas", "no source at all",
+             "one source broker", "replicas on DEAD brokers",
+             "padded partitions and -1 slots")
+
+
+@pytest.mark.parametrize("slot_major", (False, True),
+                         ids=("partition-major", "slot-major"))
+@pytest.mark.parametrize("k_src", (16, 64), ids=("k<4b", "k>4b"))
+@pytest.mark.parametrize("name", SCENARIOS)
+def test_select_sources_same_cards_under_both_forms(monkeypatch, name,
+                                                    k_src, slot_major):
+    """Cards, validity and the on-source mask, in both flat layouts, with
+    the per-broker blocks narrower than the brokers (k_src < 4 b: the
+    broker top-k cuts) and as wide (k_src > 4 b: every broker offers its
+    two)."""
+    state, score, weight = _cluster(name)
+    assert (k_src < 4 * state.num_brokers) == (k_src == 16)
+    monkeypatch.setattr(tensors, "slot_major_flat", lambda: slot_major)
+    got = {}
+    try:
+        for form in FORMS:
+            _force(monkeypatch, form)
+            got[form] = [np.asarray(x) for x in
+                         select_sources(state, score, weight, k_src)]
+            assert cand_mod.source_select() == form
+    finally:
+        monkeypatch.undo()
+    for a, b in zip(got["segment"], got["dense"]):
+        np.testing.assert_array_equal(a, b)
+    _p, _s, valid, on_source = got["dense"]
+    if name == "no source at all":
+        assert not valid.any() and not on_source.any()
+    else:
+        assert valid.any() and on_source.any()
+    if name == "replicas on DEAD brokers":
+        # the global block (half the cards or more) is offline replicas
+        # first: the drain's priority rides the 1e30 weight through here
+        p, s, ok, _ = got["dense"]
+        off = np.asarray(offline_replicas(state))
+        assert off[p[ok], s[ok]].sum() >= min(int(off.sum()), k_src // 2)
+
+
+@pytest.mark.parametrize("name", ("plain", "replicas on DEAD brokers",
+                                  "padded partitions and -1 slots"))
+def test_offline_per_broker_equal_under_both_forms(monkeypatch, name):
+    state, _score, _weight = _cluster(name)
+    off = offline_replicas(state)
+    got = {}
+    for form in FORMS:
+        monkeypatch.setattr(tensors, "broker_reduce_form",
+                            lambda b, n, form=form: form)
+        got[form] = np.asarray(offline_per_broker(state, off))
+    np.testing.assert_array_equal(got["segment"], got["dense"])
+    seg = np.asarray(broker_segments(state))
+    flat_off = np.asarray(flatten_slots(off))
+    np.testing.assert_array_equal(
+        got["dense"], [(flat_off & (seg == i)).sum()
+                       for i in range(state.num_brokers)])
+    assert (got["dense"].sum() > 0) == (name == "replicas on DEAD brokers")
+
+
+def test_fused_chain_at_16_512_same_trajectory_with_the_dense_form_forced(
+        monkeypatch):
+    """One fused ``optimize_chain`` pass over the default chain at 16
+    brokers / 512 partitions, a broker dead: final placement, rounds and
+    proposals by goal are the segment form's, and the dispatch span
+    carries the form that was traced."""
+    state, meta = random_cluster(num_brokers=16, num_topics=4,
+                                 num_partitions=512, rf=3, num_racks=4,
+                                 seed=5, skew_to_first=2.0)
+    state = set_broker_state(state, jnp.asarray([3]), BrokerState.DEAD)
+    goals = tuple(goals_by_priority(CruiseControlConfig()))
+    cfg = SearchConfig(num_sources=32, num_dests=6, moves_per_round=32,
+                       max_rounds=60)
+    args = (state, goals, BalancingConstraint(), cfg, meta.num_topics,
+            ExclusionMasks())
+
+    def one_pass():
+        chain_optimize_full.clear_cache()
+        with TRACER.span("test.pass") as root:
+            out = optimize_chain(*args)
+        (dispatch,) = [c for c in root.children
+                       if c.name == "solver.dispatch"]
+        return out, dispatch.attributes
+
+    (st_seg, infos_seg), attrs = one_pass()
+    assert attrs["source_select"] == "segment"
+    assert attrs["accept_lookup"] == "grid"
+    try:
+        _force(monkeypatch, "dense")
+        jax.clear_caches()
+        (st_dense, infos_dense), attrs = one_pass()
+        assert attrs["source_select"] == "dense"
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+
+    np.testing.assert_array_equal(np.asarray(st_seg.assignment),
+                                  np.asarray(st_dense.assignment))
+    np.testing.assert_array_equal(np.asarray(st_seg.leader_slot),
+                                  np.asarray(st_dense.leader_slot))
+    assert int(offline_replicas(st_dense).sum()) == 0
+    assert sum(i["rounds"] for i in infos_seg) > len(goals)
+    assert infos_seg == infos_dense
+
+
+@pytest.mark.parametrize("case", ("bbest_dense", "bbest_sort",
+                                  "bcount_dense"))
+def test_microbench_broker_forms_compute_the_same(case):
+    """The microbench's ``bbest_*`` / ``bcount_*`` classes price the SAME
+    work: every form leaves the carry its segment form leaves."""
+    from cruise_control_tpu.utils.microbench import _build_cases
+    run, inputs = _build_cases(64, 64)
+    oracle = case.split("_")[0] + "_segment"
+    want = np.asarray(run(inputs[oracle], 2, oracle))
+    assert (want != np.asarray(inputs[oracle])).any()
+    np.testing.assert_array_equal(
+        np.asarray(run(inputs[case], 2, case)), want)

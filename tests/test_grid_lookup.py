@@ -83,7 +83,7 @@ def _grid_and_flat():
     rng = np.random.default_rng(0)
     weight = jnp.asarray(rng.uniform(1.0, 2.0, state.assignment.shape),
                          jnp.float32)
-    _p, _s, src_valid = select_sources(state, src_score, weight, K_SRC)
+    _p, _s, src_valid, _on = select_sources(state, src_score, weight, K_SRC)
     targeted = (jnp.asarray(rng.integers(0, B, K_SRC), jnp.int32),
                 src_valid)
     cand, layout = generate_candidates(

@@ -254,6 +254,8 @@ def test_rebalance_dryrun_yields_full_trace_tree(traced_api):
     assert dattrs["route"] == {"stringValue": "fused"}
     # the round body looks tables up on the candidate grid's margins
     assert dattrs["accept_lookup"] == {"stringValue": "grid"}
+    # and reduces the flat replica axis per broker as the CPU does
+    assert dattrs["source_select"] == {"stringValue": "segment"}
     per_goal = [int(r) for r in
                 dattrs["goal_rounds"]["stringValue"].split(",")]
     assert sum(per_goal) == int(dattrs["rounds"]["intValue"])
