@@ -17,7 +17,6 @@ A candidate is (kind, partition, src_slot, dst_broker, dst_slot):
 from __future__ import annotations
 
 import dataclasses
-import os
 from functools import partial
 
 import jax
@@ -647,11 +646,8 @@ def attach_cumulative_segments(sub: CandidateDeltas, considered: jax.Array,
 # time (the backend is not known at import): segment on CPU (measured
 # −13% TopicReplica round cost at 7k), matmul on accelerators (the MXU
 # eats [m, m] matmuls; device-side sorts are comparatively slow and the
-# segment form is unmeasured on the chip). CC_ATTACH overrides.
+# segment form is unmeasured on the chip).
 def _attach_impl() -> str:
-    impl = os.environ.get("CC_ATTACH")
-    if impl:
-        return impl
     return "segment" if jax.default_backend() == "cpu" else "matmul"
 
 

@@ -29,7 +29,7 @@ from __future__ import annotations
 import dataclasses
 import time as _time
 from functools import partial
-from typing import Callable, Sequence
+from typing import Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -41,11 +41,12 @@ from .agg import (
     AggCarry, apply_deltas_to_agg, compute_agg, maybe_refresh, pot_lbi_deltas,
 )
 from .candidates import (
-    compute_deltas, generate_candidates, select_sources, source_select,
+    CandidateDeltas, Candidates, compute_deltas, generate_candidates,
+    select_sources, source_select,
 )
 from .fill import targets_enabled
 from .constraint import BalancingConstraint
-from .derived import compute_derived
+from .derived import DerivedState, compute_derived
 from .goals.base import Goal
 from .search import (
     _EPS_IMPROVEMENT, _OFFLINE_BONUS, ExclusionMasks,
@@ -160,6 +161,27 @@ def _switch_scores(active_idx, goals, aux_list, state, derived, constraint):
                                        aux_list[i]).astype(jnp.float32)))
 
 
+def _chain_scores(state, derived, active_idx, prior_mask, goals, constraint,
+                  num_topics, agg, psum=None):
+    """(is_active [G], aux_list, src_score [B], dst_score [B], weight [P, S])
+    of the active goal: every goal's aux gated to the active and prior
+    goals, then the active goal's scores. Shared by the move round's
+    scoring half and the swap bodies. Under a mesh (``psum``) the sum of
+    partition-additive source scores runs unconditionally
+    (collective-safety) and is selected by a traced flag."""
+    is_active = jnp.arange(len(goals)) == active_idx
+    aux_list = [_gated_aux(prior_mask[i] | is_active[i], g, state, derived,
+                           constraint, num_topics, psum=psum, agg=agg)
+                for i, g in enumerate(goals)]
+    src_score, dst_score, weight = _switch_scores(
+        active_idx, goals, aux_list, state, derived, constraint)
+    if psum is not None:
+        additive_f = jnp.asarray([g.partition_additive_scores for g in goals])
+        src_score = jnp.where(additive_f[active_idx], psum(src_score),
+                              src_score)
+    return is_active, aux_list, src_score, dst_score, weight
+
+
 def _switch_swap_dest_score(active_idx, goals, aux_list, state, derived,
                             constraint):
     """[B] swap counterparty score of the active goal (shared by the
@@ -171,22 +193,17 @@ def _switch_swap_dest_score(active_idx, goals, aux_list, state, derived,
 
 
 def _switch_target_dests(active_idx, goals, aux_list, state, derived,
-                         constraint, cand_p, cand_s, src_valid,
-                         rank_stride: int = 1, rank_offset=0):
+                         constraint, cand_p, cand_s, src_valid):
     """The active goal's targeted-destination column (Goal.target_dests,
     analyzer.fill) — goals without a rule contribute an all-invalid
-    column so every branch returns the same shapes. ``rank_stride``/
-    ``rank_offset`` interleave per-device fill positions on a mesh (see
-    Goal.target_dests)."""
+    column so every branch returns the same shapes."""
 
     def branch(i):
         g = goals[i]
 
         def fn(_):
             td = g.target_dests(state, derived, constraint, aux_list[i],
-                                cand_p, cand_s, src_valid,
-                                rank_stride=rank_stride,
-                                rank_offset=rank_offset)
+                                cand_p, cand_s, src_valid)
             if td is None:
                 return (jnp.zeros_like(cand_p),
                         jnp.zeros(cand_p.shape, dtype=bool))
@@ -222,35 +239,48 @@ def _set_traced_forms(dispatch, kind: str = "move") -> None:
         dispatch.set(source_select=source_select())
 
 
-def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
-                      active_idx: jax.Array,
-                      prior_mask: jax.Array, goals: tuple[Goal, ...],
-                      constraint: BalancingConstraint, cfg: SearchConfig,
-                      num_topics: int, masks: ExclusionMasks,
-                      collect: bool = False,
-                      ) -> tuple[ClusterTensors, "AggCarry | None",
-                                 jax.Array, "jax.Array | None"]:
-    """One search round, chain-parameterized (traced body). ``agg`` is the
-    incrementally-maintained aggregate carry (analyzer.agg): the round reads
-    its per-broker aggregates from it instead of O(P·S) segment-sums and
-    returns it updated by the applied batch (None = recompute-per-round,
-    kept for the oracle paths).
+class ScoredCandidates(NamedTuple):
+    """What ``_scored_candidates`` hands to a round's selection."""
+    derived: DerivedState
+    aux_list: list            # per goal; zeros unless active or prior
+    cand: Candidates
+    layout: tuple             # (rows, cols) of the move, the leadership block
+    deltas: CandidateDeltas   # with their CandidateGrid
+    accept: jax.Array         # [N] valid and accepted by every prior goal
+    score: jax.Array          # [N] f32 improvement, -inf unless accepted
+    is_active: jax.Array      # [G]
+    independent: jax.Array    # the active goal lifts the per-round move cap
+    targets: bool             # static: the move block's last column is targeted
 
-    ``collect`` (trace-time) additionally returns a ``[STAT_WIDTH]`` f32
-    flight-stats row for this round (utils.flight_recorder.STAT_COLUMNS:
-    applied / valid / accepted / positive / winners / active-goal
-    violation) — pure REDUCTIONS over tensors the round already computes
-    (the duplicated ``reduce_per_source`` is structurally identical to
-    the one inside ``cumulative_select``, so XLA CSE collapses the two),
-    never a new selection input: the trajectory is byte-identical with
-    collection on or off (pinned in tests/test_flight_recorder.py).
+
+def _scored_candidates(state: ClusterTensors, agg: "AggCarry | None",
+                       active_idx: jax.Array, prior_mask: jax.Array,
+                       goals: tuple[Goal, ...],
+                       constraint: BalancingConstraint, cfg: SearchConfig,
+                       num_topics: int, masks: ExclusionMasks, *,
+                       global_partitions: int, psum=None,
+                       ) -> ScoredCandidates:
+    """The scoring half of one move round, written ONCE for every route
+    (docs/DESIGN.md "The move round"): derived state -> the goals' aux ->
+    source / destination scores -> the self-healing priority -> sources ->
+    candidate grid -> deltas -> the acceptance stack under ``prior_mask``
+    -> the active goal's improvement -> score. ``_chain_round_body`` (one
+    chip, and under ``vmap`` the megabatch) and
+    ``parallel.chain_sharded._chain_round_local`` (the mesh) call it and
+    differ only in the selection that follows.
+
+    ``psum`` is the mesh, passed the way ``compute_derived`` and
+    ``_gated_aux`` take it: None on one chip; under ``shard_map`` it sums
+    over the partition axis, ``state`` holds this device's partition rows
+    and ``global_partitions`` (``state.num_partitions`` on one chip) is
+    the mesh's total. Every collective it adds runs unconditionally
+    (a ``cond`` whose branches disagree on collectives would deadlock).
 
     The phases carry stable ``jax.named_scope`` names (``round.score``,
     ``round.source_topk``, ``round.candidates``, ``round.deltas``,
-    ``round.accept``, ``round.select``, ``round.apply``,
-    ``round.flight_stats``; ``round.agg_refresh`` in the drivers), some
-    here and some inside the helpers, so a device profile names them.
-    Metadata only: the lowered computation is the same."""
+    ``round.accept``), some here and some inside the helpers, so a device
+    profile names them on every route. Metadata only: the lowered
+    computation is the same."""
     lead_only_f, incl_lead_f, indep_f = _goal_flags(goals)
     is_lead_only = lead_only_f[active_idx]
     has_leadership = incl_lead_f[active_idx]
@@ -258,42 +288,49 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
     with jax.named_scope("round.score"):
         derived = compute_derived(state, masks.excluded_topics,
                                   masks.excluded_replica_move_brokers,
-                                  masks.excluded_leadership_brokers, agg=agg)
-        is_active = jnp.arange(len(goals)) == active_idx
-        aux_list = [_gated_aux(prior_mask[i] | is_active[i], g, state,
-                               derived, constraint, num_topics, agg=agg)
-                    for i, g in enumerate(goals)]
+                                  masks.excluded_leadership_brokers,
+                                  psum=psum, agg=agg)
+        is_active, aux_list, src_score, dst_score, weight = _chain_scores(
+            state, derived, active_idx, prior_mask, goals, constraint,
+            num_topics, agg, psum=psum)
 
-        src_score, dst_score, weight = _switch_scores(
-            active_idx, goals, aux_list, state, derived, constraint)
-
-        # Self-healing priority (see search.score_round_candidates):
-        # offline replicas are always sources with maximal weight for
-        # non-leadership goals.
+        # Self-healing priority: replicas stranded on dead brokers are
+        # always sources with maximal weight for non-leadership goals, and
+        # moving one scores a large bonus below so it wins over pure
+        # balance refinements (ClusterModel.selfHealingEligibleReplicas).
         off = offline_replicas(state)  # [P, S]
-        src_score = src_score + jnp.where(is_lead_only, 0.0,
-                                          offline_per_broker(state, off))
+        offline_pb = offline_per_broker(state, off)
+        if psum is not None:
+            offline_pb = psum(offline_pb)
+        src_score = src_score + jnp.where(is_lead_only, 0.0, offline_pb)
         weight = jnp.where(off & ~is_lead_only, 1e30, weight)
 
     # UNIFORM grid layout: both the move and the leadership block always
     # exist (static shapes shared by every goal); the active goal's traced
     # flags mask out the block it doesn't use. The targeted-destination
     # column (Goal.target_dests) rides the move block: it is made from the
-    # cards, so the sources are selected first and handed on.
+    # cards, so the sources are selected first and handed on. Its scale
+    # gate reads the GLOBAL partition count (the threshold's measured
+    # meaning is cluster scale), and it runs on one shard only: card fill
+    # ranks are device-local against a replicated profile (docs/DESIGN.md
+    # "Known limits").
+    targets = targets_enabled(global_partitions) \
+        and global_partitions == state.num_partitions
     extra = sources = None
-    if targets_enabled(state.num_partitions):
+    if targets:
         sources = select_sources(state, src_score, weight, cfg.num_sources)
         cand_p, cand_s, src_valid, _on_source = sources
-        # Targets pause while ANY offline replica exists (traced scalar):
-        # targeted steering during a drain locks in placements later
-        # goals cannot repair (1k drain-50: balancedness 86.0 -> 82.74
-        # with CpuUsage violated). Self-healing and the drain's rebalance
-        # keep the r4 full-grid semantics; targets resume once healing
-        # completes.
+        # Targets pause while ANY offline replica exists, anywhere on the
+        # mesh (traced scalar): targeted steering during a drain locks in
+        # placements later goals cannot repair (1k drain-50: balancedness
+        # 86.0 -> 82.74 with CpuUsage violated). Self-healing and the
+        # drain's rebalance keep the r4 full-grid semantics; targets
+        # resume once healing completes.
         t_dst, t_ok = _switch_target_dests(active_idx, goals, aux_list,
                                            state, derived, constraint,
                                            cand_p, cand_s, src_valid)
-        extra = (t_dst, t_ok & ~off.any())
+        any_offline = off.any() if psum is None else psum(off.sum()) > 0
+        extra = (t_dst, t_ok & ~any_offline)
     cand, layout = generate_candidates(state, derived, src_score, dst_score,
                                        weight, cfg.num_sources, cfg.num_dests,
                                        include_leadership=True,
@@ -333,8 +370,43 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
         score = jnp.where(accept, imp, -jnp.inf)
 
     independent = indep_f[active_idx] & ~prior_mask.any()
+    return ScoredCandidates(derived, aux_list, cand, layout, deltas, accept,
+                            score, is_active, independent, targets)
+
+
+def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
+                      active_idx: jax.Array,
+                      prior_mask: jax.Array, goals: tuple[Goal, ...],
+                      constraint: BalancingConstraint, cfg: SearchConfig,
+                      num_topics: int, masks: ExclusionMasks,
+                      collect: bool = False,
+                      ) -> tuple[ClusterTensors, "AggCarry | None",
+                                 jax.Array, "jax.Array | None"]:
+    """One search round, chain-parameterized (traced body): the scoring
+    half (``_scored_candidates``), then the one-chip selection. ``agg`` is
+    the incrementally-maintained aggregate carry (analyzer.agg): the round
+    reads its per-broker aggregates from it instead of O(P·S) segment-sums
+    and returns it updated by the applied batch (None = recompute-per-round,
+    kept for the oracle paths).
+
+    ``collect`` (trace-time) additionally returns a ``[STAT_WIDTH]`` f32
+    flight-stats row for this round (utils.flight_recorder.STAT_COLUMNS:
+    applied / valid / accepted / positive / winners / active-goal
+    violation) — pure REDUCTIONS over tensors the round already computes
+    (the duplicated ``reduce_per_source`` is structurally identical to
+    the one inside ``cumulative_select``, so XLA CSE collapses the two),
+    never a new selection input: the trajectory is byte-identical with
+    collection on or off (pinned in tests/test_flight_recorder.py).
+
+    The selection's phases carry the scopes ``round.select``,
+    ``round.apply`` and ``round.flight_stats`` (``round.agg_refresh`` in
+    the drivers), after the scoring half's."""
+    sc = _scored_candidates(state, agg, active_idx, prior_mask, goals,
+                            constraint, cfg, num_topics, masks,
+                            global_partitions=state.num_partitions)
+    derived, aux_list, deltas, score = \
+        sc.derived, sc.aux_list, sc.deltas, sc.score
     m = max(cfg.moves_per_round, cfg.num_sources)
-    is_active_f = is_active
 
     def recheck(sub, has_earlier):
         """Joint acceptance with cumulative pre-deltas (cumulative_select):
@@ -344,12 +416,12 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
         for i, g in enumerate(goals):
             g_acc = g.acceptance(state, derived, constraint, aux_list[i], sub)
             a &= (~prior_mask[i]) | g_acc
-            a &= (~is_active_f[i]) | (~has_earlier) | g_acc
+            a &= (~sc.is_active[i]) | (~has_earlier) | g_acc
         return a
 
     top_idx, sel, sub, pot_d, lbi_d = cumulative_select(
-        state, deltas, score, layout, m, cfg.moves_per_round, independent,
-        recheck, extra_last_col=targets_enabled(state.num_partitions))
+        state, deltas, score, sc.layout, m, cfg.moves_per_round,
+        sc.independent, recheck, extra_last_col=sc.targets)
     if agg is not None:
         with jax.named_scope("round.apply"):
             agg = apply_deltas_to_agg(agg, sub, sel, pot_d, lbi_d)
@@ -357,15 +429,14 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
     # calls it)
     new_state = apply_selected(
         state, sel, deltas.partition[top_idx], deltas.src_slot[top_idx],
-        deltas.dst_broker[top_idx], cand.kind[top_idx],
-        cand.dst_slot[top_idx])
+        deltas.dst_broker[top_idx], sc.cand.kind[top_idx],
+        sc.cand.dst_slot[top_idx])
     applied = sel.sum()
     stat = None
     if collect:
         with jax.named_scope("round.flight_stats"):
-            red_idx = reduce_per_source(
-                score, layout, extra_last_col=targets_enabled(
-                    state.num_partitions))
+            red_idx = reduce_per_source(score, sc.layout,
+                                        extra_last_col=sc.targets)
             viol = _switch_goal_fn(
                 active_idx, goals,
                 lambda g, i: g.broker_violations(
@@ -374,7 +445,7 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
             stat = jnp.stack([
                 applied.astype(jnp.float32),
                 deltas.valid.sum().astype(jnp.float32),
-                accept.sum().astype(jnp.float32),
+                sc.accept.sum().astype(jnp.float32),
                 (score > _EPS_IMPROVEMENT).sum().astype(jnp.float32),
                 (score[red_idx] > _EPS_IMPROVEMENT).sum()
                 .astype(jnp.float32),
@@ -518,12 +589,9 @@ def _chain_swap_body(state: ClusterTensors, agg: "AggCarry | None",
     derived = compute_derived(state, masks.excluded_topics,
                               masks.excluded_replica_move_brokers,
                               masks.excluded_leadership_brokers, agg=agg)
-    is_active = jnp.arange(len(goals)) == active_idx
-    aux_list = [_gated_aux(prior_mask[i] | is_active[i], g, state, derived,
-                           constraint, num_topics, agg=agg)
-                for i, g in enumerate(goals)]
-    src_score, _dst_score, weight = _switch_scores(
-        active_idx, goals, aux_list, state, derived, constraint)
+    _is_active, aux_list, src_score, _dst_score, weight = _chain_scores(
+        state, derived, active_idx, prior_mask, goals, constraint,
+        num_topics, agg)
     dst_score = _switch_swap_dest_score(active_idx, goals, aux_list, state,
                                         derived, constraint)
 

@@ -29,23 +29,15 @@ it:
   shared-destination funnel).
 
 All kernels are O(k·log B) gathers + O(T·B) cumsums — no [k, B]
-materialization — and run unmodified under the partition-sharded mesh
-(inputs are replicated aux/derived aggregates; card ranks are
-device-local, cross-device overfill is vetoed by the joint acceptance
-recheck).
+materialization. Card ranks are device-local, so the move round appends
+the column on ONE partition shard only (``chain._scored_candidates``;
+docs/DESIGN.md "Known limits" has what the interleaved fill measured).
 """
 
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
-
-# Experiment kill-switch: CC_TARGET_DESTS=0 removes the targeted column
-# from every search path (per-goal, chain, sharded) — the control arm for
-# attributing per-round cost and fixed-point depth to this machinery.
-TARGET_DESTS_ON = os.environ.get("CC_TARGET_DESTS", "1") == "1"
 
 # Scale gate (measured at 7k/1M, r5): the per-round cost of the targeted
 # branch (per-card fill ranks + cumulative profiles) buys nothing at
@@ -54,11 +46,11 @@ TARGET_DESTS_ON = os.environ.get("CC_TARGET_DESTS", "1") == "1"
 # already saturates the deficit profile over enough rounds; at tool/mid
 # scale the column clears residuals the shared grid cannot reach. Static
 # per-shape decision (num_partitions is a trace-time constant).
-TARGET_DESTS_MAX_P = int(os.environ.get("CC_TARGET_DESTS_MAX_P", "500000"))
+TARGET_DESTS_MAX_P = 500_000
 
 
 def targets_enabled(num_partitions: int) -> bool:
-    return TARGET_DESTS_ON and num_partitions < TARGET_DESTS_MAX_P
+    return num_partitions < TARGET_DESTS_MAX_P
 
 
 def pow2_width(n: int) -> int:
@@ -67,16 +59,6 @@ def pow2_width(n: int) -> int:
     distinct static width is a new XLA program, so sized widths must come
     from a tiny set)."""
     return 1 << max(0, int(n) - 1).bit_length()
-
-
-# Per-goal-class filter for attribution experiments: comma-separated class
-# names; empty = all classes contribute targeted destinations.
-_TGT_CLASSES = os.environ.get("CC_TGT_CLASSES", "")
-
-
-def class_enabled(goal) -> bool:
-    return (not _TGT_CLASSES
-            or type(goal).__name__ in _TGT_CLASSES.split(","))
 
 
 def row_searchsorted(cum: jax.Array, rows: jax.Array, q: jax.Array,

@@ -193,9 +193,6 @@ class ResourceDistributionGoal(Goal):
     def target_dests(self, state, derived, constraint, aux,
                      cand_p, cand_s, src_valid, rank_stride=1,
                      rank_offset=0):
-        from ..fill import class_enabled
-        if not class_enabled(self):
-            return None
         # Size-matched (first-fit-decreasing) destination per card: the
         # shared top-num_dests list starves the convergence tail — once
         # only small under-band gaps remain, a heavy card fits none of
@@ -318,9 +315,6 @@ class CountDistributionGoal(Goal):
     def target_dests(self, state, derived, constraint, aux,
                      cand_p, cand_s, src_valid, rank_stride=1,
                      rank_offset=0):
-        from ..fill import class_enabled
-        if not class_enabled(self):
-            return None
         # Deficit-proportional fill over the single cluster-wide count
         # band (T = 1 case of the TopicReplica kernel): under-band
         # brokers absorb cards first, then remaining whole-count
@@ -450,9 +444,6 @@ class TopicReplicaDistributionGoal(Goal):
     def target_dests(self, state, derived, constraint, aux,
                      cand_p, cand_s, src_valid, rank_stride=1,
                      rank_offset=0):
-        from ..fill import class_enabled
-        if not class_enabled(self):
-            return None
         # Per-topic deficit fill: the round-count bottleneck of the 7k/1M
         # north star (r4: ~65% of wall-clock) was this goal funneling
         # thousands of per-topic cards through ≤ num_dests shared

@@ -268,9 +268,6 @@ class KafkaAssignerEvenRackAwareGoal(RackAwareGoal):
     def target_dests(self, state, derived, constraint, aux,
                      cand_p, cand_s, src_valid, rank_stride=1,
                      rank_offset=0):
-        from ..fill import class_enabled
-        if not class_enabled(self):
-            return None
         # Per-card RACK-COMPATIBLE destination: the shared top-num_dests
         # list ranks by count headroom alone, and on skewed layouts every
         # listed destination can be rack-conflicted for the specific

@@ -565,8 +565,8 @@ class GoalOptimizer:
         # The width cap bounds SELECTION size m = max(moves, sources) too;
         # with the O(m log m) segment cumulative (candidates.py) the old
         # m² matmul ceiling no longer binds it — the cap stays a measured
-        # quality/throughput knob (CC_WIDE_CAP for experiments).
-        cap = int(os.environ.get("CC_WIDE_CAP", "2048"))
+        # quality/throughput constant.
+        cap = 2048
         return dataclasses.replace(
             search_cfg,
             num_sources=max(search_cfg.num_sources,

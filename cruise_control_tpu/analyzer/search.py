@@ -1,4 +1,5 @@
-"""The batched rebalance search.
+"""The batched rebalance search: the plain per-goal kernels and the
+selection helpers every route shares.
 
 TPU-native replacement for the reference's greedy inner loop
 (AbstractGoal.java:82-135 optimize → rebalanceForBroker → one
@@ -10,15 +11,22 @@ maybeApplyBalancingAction at a time). Each round, ONE fused kernel:
    goal's acceptance for all candidates (the lexicographic-constraint stack
    of SURVEY.md §A.3 as boolean masks),
 4. picks a conflict-free batch of the best improving candidates
-   (scatter-min rank dedup over partition/src/dst), and
+   (rank-order cumulative selection, one move a partition), and
 5. applies them functionally.
 
-The host loop only reads back one scalar ("moves applied") per round.
+Two things live here (docs/DESIGN.md "The move round"):
 
-The round body is shared with the multi-chip path
-(parallel/sharded.py): ``score_round_candidates`` and ``apply_selected``
-take a ``psum`` hook / row offset so the same kernels run replicated or
-partition-sharded.
+- The per-goal kernels (``score_round_candidates``, ``optimize_round(s)``,
+  ``swap_round(s)``, ``optimize_goal``), jitted with (goal, optimized)
+  STATIC: the plain one-chip EQUIVALENCE ORACLE of the chain kernels
+  (tests/test_chain.py, tests/test_analyzer.py). No served route runs
+  them. They keep the full recompute of every aggregate and the flat
+  per-candidate lookups on purpose and share no scoring code with
+  ``analyzer.chain._scored_candidates``: an oracle that shared the
+  production body would check nothing.
+- The selection and apply helpers the production bodies call
+  (``reduce_per_source``, ``cumulative_select``, ``apply_selected``,
+  ``swap_grid``, ``apply_swap_selection``, ``run_carry_loop``).
 """
 
 from __future__ import annotations
@@ -106,7 +114,7 @@ def reduce_per_source(score: jax.Array,
     round at one move. Columns outside the tie window are never chosen, so
     a genuinely better candidate (e.g. the only one fixing a tiny capacity
     violation) cannot be displaced. ``row_offset`` decorrelates devices in
-    the sharded path.
+    the mesh body (parallel.chain_sharded).
 
     ``extra_last_col``: the FIRST block's last column is the targeted-
     destination column (generate_candidates ``extra_dst``); it is kept
@@ -141,42 +149,6 @@ def reduce_per_source(score: jax.Array,
         red_parts.append(offset + jnp.arange(rows) * cols + best_col)
         offset += rows * cols
     return jnp.concatenate(red_parts)
-
-
-def _conflict_free_top_m(score: jax.Array, partition: jax.Array,
-                         src: jax.Array, dst: jax.Array, m: int,
-                         num_partitions: int, num_brokers: int,
-                         dedupe_brokers: bool | jax.Array = True):
-    """Indices of up to ``m`` best-scoring candidates such that no two share
-    a partition — nor, when ``dedupe_brokers`` (goals whose scores depend on
-    per-broker totals), a source or destination broker. Scatter-min of the
-    score-rank per key resolves conflicts in parallel (no sequential scan).
-    ``dedupe_brokers`` may be a traced bool (the chain kernel switches it
-    per active goal at runtime)."""
-    k = min(m, score.shape[0])
-    top_score, top_idx = jax.lax.top_k(score, k)
-    ok = top_score > _EPS_IMPROVEMENT
-    rank = jnp.arange(k, dtype=jnp.int32)
-
-    sel_p = partition[top_idx]
-    sel_src = src[top_idx]
-    sel_dst = dst[top_idx]
-
-    big = jnp.int32(k + 1)
-    rank_eff = jnp.where(ok, rank, big)
-
-    first_p = jnp.full(num_partitions, big, dtype=jnp.int32).at[sel_p].min(rank_eff)
-    accept = ok & (first_p[sel_p] == rank)
-    if dedupe_brokers is False:
-        return top_idx, accept
-    first_src = jnp.full(num_brokers, big, dtype=jnp.int32).at[sel_src].min(rank_eff)
-    first_dst = jnp.full(num_brokers, big, dtype=jnp.int32).at[sel_dst].min(rank_eff)
-    broker_ok = (first_src[sel_src] == rank) & (first_dst[sel_dst] == rank)
-    if dedupe_brokers is True:
-        accept &= broker_ok
-    else:
-        accept &= jnp.where(dedupe_brokers, broker_ok, True)
-    return top_idx, accept
 
 
 @jax.named_scope("round.select")
@@ -282,55 +254,44 @@ def run_rounds_loop(round_body, state: ClusterTensors, max_rounds: int,
 def score_round_candidates(state: ClusterTensors, masks: ExclusionMasks,
                            goal: Goal, optimized: tuple[Goal, ...],
                            constraint: BalancingConstraint, cfg: SearchConfig,
-                           num_topics: int, psum=None, k_src: int | None = None):
-    """Shared round body: derived state → candidate grid → lexicographic
-    acceptance stack → scored candidates. ``psum`` combines partition-
-    additive aggregates across a mesh (None on a single device); ``k_src``
-    overrides the per-device source count in the sharded path.
+                           num_topics: int):
+    """The oracle's scoring half, for ONE static goal: derived state →
+    candidate grid → lexicographic acceptance stack → scored candidates.
+    The production half is ``chain._scored_candidates`` (traced goal index,
+    aggregate carry, grid lookups, the mesh keyword); this one recomputes
+    everything and looks tables up per candidate, and must stay
+    independent of it (module docstring).
 
-    Returns (cand, deltas, score, layout)."""
+    Returns (cand, deltas, score, layout, (derived, aux, aux_by_goal))."""
     derived = compute_derived(state, masks.excluded_topics,
                               masks.excluded_replica_move_brokers,
-                              masks.excluded_leadership_brokers, psum=psum)
-    aux = goal_aux(goal, state, derived, constraint, num_topics, psum)
-    aux_by_goal = {g.name: goal_aux(g, state, derived, constraint, num_topics, psum)
+                              masks.excluded_leadership_brokers)
+    aux = goal_aux(goal, state, derived, constraint, num_topics)
+    aux_by_goal = {g.name: goal_aux(g, state, derived, constraint, num_topics)
                    for g in optimized}
 
     src_score = goal.source_score(state, derived, constraint, aux)
     dst_score = goal.dest_score(state, derived, constraint, aux)
     weight = goal.replica_weight(state, derived, constraint, aux)
-    if psum is not None and goal.partition_additive_scores:
-        src_score = psum(src_score)
 
     # Self-healing has priority: replicas stranded on dead brokers are
     # always sources with maximal weight, and moving one scores a large
     # bonus so it wins over pure balance refinements
     # (ClusterModel.selfHealingEligibleReplicas / _fixOfflineReplicasOnly).
     off = offline_replicas(state)  # [P, S]
-    offline_pb = offline_per_broker(state, off)
-    if psum is not None:
-        offline_pb = psum(offline_pb)
     if not goal.leadership_only:
-        src_score = src_score + offline_pb
+        src_score = src_score + offline_per_broker(state, off)
         weight = jnp.where(off, 1e30, weight)  # finite: top-k validity uses isfinite
 
     # Targeted destination column (Goal.target_dests over the shared
-    # source selection, analyzer.fill): SINGLE-DEVICE only (psum None)
-    # and scale-gated (targets_enabled). Where enabled, it is appended
-    # for every goal — goals without a target rule get an all-invalid
-    # column — so the single-device per-goal and chain kernels share one
-    # move-block column count; the sharded kernels never append it (and
-    # the column stays out of the tie-rotation cycle either way, so the
-    # kernels' shared-destination arithmetic agrees).
+    # source selection, analyzer.fill), scale-gated (targets_enabled).
+    # Where enabled, it is appended for every non-leadership goal — goals
+    # without a target rule get an all-invalid column — so the per-goal
+    # and chain kernels share one move-block column count.
     from .fill import targets_enabled
-    k_eff = k_src or cfg.num_sources
     extra = sources = None
-    # psum set = partition-sharded mesh: targeted fills are single-device
-    # only (device-local fill ranks collide across shards — see
-    # parallel/chain_sharded.py).
-    if targets_enabled(state.num_partitions) and not goal.leadership_only \
-            and psum is None:
-        sources = select_sources(state, src_score, weight, k_eff)
+    if targets_enabled(state.num_partitions) and not goal.leadership_only:
+        sources = select_sources(state, src_score, weight, cfg.num_sources)
         cand_p, cand_s, src_valid, _on_source = sources
         extra = goal.target_dests(state, derived, constraint, aux,
                                   cand_p, cand_s, src_valid)
@@ -339,11 +300,11 @@ def score_round_candidates(state: ClusterTensors, masks: ExclusionMasks,
                      jnp.zeros(cand_p.shape, dtype=bool))
         else:
             # Targets pause while any offline replica exists (see
-            # chain._chain_round_body).
+            # chain._scored_candidates).
             extra = (extra[0], extra[1] & ~off.any())
 
     cand, layout = generate_candidates(state, derived, src_score, dst_score, weight,
-                                       k_eff, cfg.num_dests,
+                                       cfg.num_sources, cfg.num_dests,
                                        goal.include_leadership, goal.leadership_only,
                                        extra_dst=extra, sources=sources)
     deltas = compute_deltas(state, derived, cand)

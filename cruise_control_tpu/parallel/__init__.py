@@ -6,14 +6,12 @@ over ICI/DCN instead of locks.
 """
 
 from .chain_sharded import optimize_chain_sharded
-from .mesh import PARTITION_AXIS, make_mesh, partition_sharding, replicated_sharding
-from .sharded import (
-    optimize_goal_sharded, shard_cluster, sharded_optimize_round,
-    sharded_swap_round,
+from .mesh import (
+    PARTITION_AXIS, make_mesh, partition_sharding, replicated_sharding,
+    shard_cluster,
 )
 
 __all__ = [
     "PARTITION_AXIS", "make_mesh", "partition_sharding", "replicated_sharding",
-    "optimize_chain_sharded", "optimize_goal_sharded", "shard_cluster",
-    "sharded_optimize_round", "sharded_swap_round",
+    "optimize_chain_sharded", "shard_cluster",
 ]

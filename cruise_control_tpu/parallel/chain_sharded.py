@@ -6,17 +6,15 @@ structure as ``analyzer.chain.chain_optimize_full``) with:
 
 - partition-indexed tensors sharded along the mesh axis ``"p"``, broker
   aggregates psum'd (ICI collectives) — the sharding model of
-  ``parallel.sharded``;
+  ``parallel.mesh``;
 - the active goal as a TRACED index (``lax.switch``) and prior goals as a
-  traced mask — ONE compilation per (mesh, chain, search config), not the
-  per-(goal, prior-chain) ``lru_cache`` blowup of the per-goal sharded
-  drivers (VERDICT round 2, missing #2);
+  traced mask — ONE compilation per (mesh, chain, search config);
 - one host dispatch and one stacked stats readback for the whole chain.
 
 Collectives appear inside ``scan``/``while_loop``/``cond`` bodies; every
 control-flow predicate is replicated (psum'd counters, the scanned goal
 index), so all devices execute identical programs and the collectives
-match — the same contract the fused per-goal sharded drivers rely on.
+match.
 
 Reference parity: GoalOptimizer.java:435-524 run under SPMD instead of a
 precompute thread pool (SURVEY.md §2.11 row 1).
@@ -25,7 +23,6 @@ precompute thread pool (SURVEY.md §2.11 row 1).
 from __future__ import annotations
 
 import dataclasses
-import os
 from functools import lru_cache, partial
 
 import jax
@@ -35,219 +32,63 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..analyzer.candidates import (
     Candidates, CandidateDeltas, attach_cumulative, compute_deltas,
-    generate_candidates, select_sources,
 )
 from ..analyzer.agg import (
     AggDelta, apply_deltas_to_agg, compute_agg, pot_lbi_deltas,
 )
 from ..analyzer.chain import (
-    _chain_infos_from_stats, _gated_aux, _goal_flags, _switch_scores,
-    _switch_swap_dest_score, _switch_target_dests,
-    excluded_hosting_replicas, set_dispatch_rounds,
+    _chain_infos_from_stats, _chain_scores, _gated_aux, _scored_candidates,
+    _switch_swap_dest_score, excluded_hosting_replicas, set_dispatch_rounds,
 )
 from ..analyzer.constraint import BalancingConstraint
 from ..analyzer.derived import compute_derived
 from ..analyzer.direct import (
     _direct_rounds_driver, direct_eligible, sparse_rounding_seed,
 )
-from ..analyzer.fill import targets_enabled
 from ..analyzer.search import (
-    _OFFLINE_BONUS, _EPS_IMPROVEMENT, ExclusionMasks, SearchConfig,
+    _EPS_IMPROVEMENT, ExclusionMasks, SearchConfig,
     _per_broker_top_replicas, apply_selected, reduce_per_source,
     run_carry_loop,
 )
 from ..common.resources import Resource
-from ..model.tensors import (
-    ClusterTensors, flatten_slots, offline_per_broker, offline_replicas,
-    slot_coords,
+from ..model.tensors import ClusterTensors, offline_replicas, slot_coords
+from .mesh import (
+    PARTITION_AXIS, _mask_specs, _psum, _state_specs, mutable_state_specs,
 )
-from .mesh import PARTITION_AXIS
-from .sharded import _mask_specs, _psum, _state_specs, mutable_state_specs
-
-
-# Per-device source-width policy for the sharded move grid. Measured on the
-# 1k/100k fixture, 8 virtual devices (tools/bench_mesh.py, rounds are
-# deterministic):
-# - "split"  — exact num_sources//shards per device: each device surfaces
-#   only its LOCAL top slice; 1,352 rounds vs 492 single-device (r4).
-# - "oversample4" — 4x the split width (r4 trial): 2,513 rounds — WORSE
-#   (wider per-device grids admit weaker local sources; recorded negative,
-#   commit 7e538cd).
-# - "full" (default) — full num_sources width per device: every device's
-#   grid is a SUPERSET of the single-device grid restricted to its shard,
-#   so the union covers the global top-k and the search trajectory tracks
-#   the single-device one (rounds ≈ single-device). Per-device grid work
-#   stays at single-device scale (redundant across devices) — on real
-#   chips the non-grid phases (derived state, scores, [P]-indexed work)
-#   still shard, and round-count parity is what lets 8 chips beat 1 at
-#   all.
-# - CC_MESH_THETA=1 additionally masks sources below the global top-k_src
-#   weight threshold. Measured NEGATIVE at 1k/8dev (balancedness 86.0 →
-#   83.55, extra violated goal): the mask starves the broker-diversity
-#   source blocks and thins the leadership block, so it is OFF by
-#   default; kept behind the env var as a measured-negative record.
-_SRC_WIDTH_POLICY = os.environ.get("CC_MESH_SRC_WIDTH", "full")
-_GLOBAL_THETA = os.environ.get("CC_MESH_THETA", "0") == "1"
-
-
-def _per_device_source_width(num_sources: int, num_shards: int) -> int:
-    if _SRC_WIDTH_POLICY == "split":
-        return max(16, min(num_sources, max(1, num_sources // num_shards)))
-    if _SRC_WIDTH_POLICY == "oversample4":
-        return max(16, min(num_sources,
-                           4 * max(1, num_sources // num_shards)))
-    return num_sources  # "full"
-
-
-def _global_source_threshold(weight: jax.Array, src_score: jax.Array,
-                             state: ClusterTensors, k_src: int) -> jax.Array:
-    """Mask ``weight`` so only the GLOBAL top-``k_src`` eligible replicas
-    stay finite. Eligibility mirrors generate_candidates' on-source mask
-    (replica exists, broker source-score > 0). The threshold is exact: the
-    k-th largest of the union of per-device top-k covers the global top-k.
-    Offline replicas carry weight 1e30, so self-healing sources always
-    survive the cut."""
-    from ..model.tensors import replica_exists
-
-    b = state.num_brokers
-    exists = replica_exists(state)
-    seg = jnp.where(state.assignment >= 0, state.assignment, b)
-    on_source = (jnp.concatenate([src_score, jnp.array([-1.0])])[seg]
-                 > 0.0) & exists
-    w_eff = jnp.where(on_source, weight, -jnp.inf)
-    k = min(k_src, w_eff.size)
-    local_top, _ = jax.lax.top_k(flatten_slots(w_eff), k)
-    g_top = jax.lax.all_gather(local_top, PARTITION_AXIS).reshape(-1)
-    theta = jax.lax.top_k(g_top, k)[0][-1]
-    # -inf theta (fewer than k eligible replicas globally) keeps all.
-    keep = w_eff >= jnp.where(jnp.isfinite(theta), theta, -jnp.inf)
-    return jnp.where(keep, weight, -jnp.inf)
-
-
-def _chain_scores(state, derived, active_idx, prior_mask, goals, constraint,
-                  num_topics, additive_f, agg=None):
-    """(aux_list, src_score, dst_score, weight) for the active goal under
-    the mesh. The psum of partition-additive source scores runs
-    unconditionally (collective-safety) and is selected by a traced flag."""
-    is_active = jnp.arange(len(goals)) == active_idx
-    aux_list = [_gated_aux(prior_mask[i] | is_active[i], g, state, derived,
-                           constraint, num_topics, psum=_psum, agg=agg)
-                for i, g in enumerate(goals)]
-    src_score, dst_score, weight = _switch_scores(
-        active_idx, goals, aux_list, state, derived, constraint)
-    src_score = jnp.where(additive_f[active_idx], _psum(src_score), src_score)
-    return aux_list, src_score, dst_score, weight
 
 
 def _chain_round_local(state: ClusterTensors, agg, masks: ExclusionMasks,
                        active_idx: jax.Array, prior_mask: jax.Array, *,
                        goals, constraint: BalancingConstraint,
                        cfg: SearchConfig, num_topics: int, num_shards: int):
-    """One chain-parameterized sharded search round (per-device body):
-    the sharded analogue of ``analyzer.chain._chain_round_body``. ``agg``
-    is the incrementally-maintained GLOBAL aggregate carry (replicated on
-    every device; the selected batch is replicated too, so the update
-    needs no further collectives). Returns (new_state, new_agg, applied)."""
+    """One chain-parameterized sharded search round (per-device body): the
+    scoring half of ``analyzer.chain`` (``_scored_candidates`` with the
+    mesh's ``psum``), then the mesh's own selection — per-source reduction
+    with a device-decorrelating rotation, the card gather, the rank-order
+    cumulative recheck on the owning device, the apply by row offset.
+    Every device scores a FULL ``cfg.num_sources``-wide grid over its own
+    partition rows, so the union covers the global top-k and the search
+    tracks the one-chip trajectory (docs/DESIGN.md "Known limits" has the
+    widths and masks that measured worse). ``agg`` is the
+    incrementally-maintained GLOBAL aggregate carry (replicated on every
+    device; the selected batch is replicated too, so the update needs no
+    further collectives). Returns (new_state, new_agg, applied)."""
     shard = jax.lax.axis_index(PARTITION_AXIS)
     p_local = state.num_partitions
     p_global = p_local * num_shards
     offset = shard * p_local
-    k_src = _per_device_source_width(cfg.num_sources, num_shards)
 
-    lead_only_f, incl_lead_f, indep_f = _goal_flags(goals)
-    additive_f = jnp.asarray([g.partition_additive_scores for g in goals])
-    is_lead_only = lead_only_f[active_idx]
-    has_leadership = incl_lead_f[active_idx]
+    sc = _scored_candidates(state, agg, active_idx, prior_mask, goals,
+                            constraint, cfg, num_topics, masks,
+                            global_partitions=p_global, psum=_psum)
+    derived, aux_list, cand, deltas, score = \
+        sc.derived, sc.aux_list, sc.cand, sc.deltas.without_grid(), sc.score
 
-    derived = compute_derived(state, masks.excluded_topics,
-                              masks.excluded_replica_move_brokers,
-                              masks.excluded_leadership_brokers, psum=_psum,
-                              agg=agg)
-    is_active = jnp.arange(len(goals)) == active_idx
-    aux_list, src_score, dst_score, weight = _chain_scores(
-        state, derived, active_idx, prior_mask, goals, constraint,
-        num_topics, additive_f, agg=agg)
-
-    # Self-healing priority (score_round_candidates semantics).
-    off = offline_replicas(state)
-    offline_pb = _psum(offline_per_broker(state, off))
-    src_score = src_score + jnp.where(is_lead_only, 0.0, offline_pb)
-    weight = jnp.where(off & ~is_lead_only, 1e30, weight)
-    if _GLOBAL_THETA and num_shards > 1:
-        weight = _global_source_threshold(weight, src_score, state, k_src)
-
-    # Targeted-destination column (Goal.target_dests). Card fill ranks
-    # are device-local against a REPLICATED deficit/headroom profile, so
-    # a naive fill has every device claim the same positions — measured
-    # at 1k/8dev that drops balancedness 86.0 → 74.5 with three extra
-    # violated goals. The SHARD-OFFSET fill (device d's cards take
-    # interleaved global positions rank·num_shards + d, CC_MESH_TARGETS=1
-    # to enable) fixes the quality collapse — measured 86.0 with the
-    # violated set pinned — but buys NO round reduction (672 vs 667 at
-    # 1k/8dev: the mesh's round inflation lives in selection, not
-    # destination starvation), so the default keeps the targeted branch
-    # off the mesh and its per-round cost with it.
-    # Scale gate on the GLOBAL partition count (p_local * num_shards):
-    # the threshold's measured meaning is cluster scale.
-    extra = sources = None
-    use_targets = targets_enabled(p_global) and (
-        num_shards == 1 or os.environ.get("CC_MESH_TARGETS") == "1")
-    if use_targets:
-        sources = select_sources(state, src_score, weight, k_src)
-        cand_p, cand_s, src_valid, _on_source = sources
-        t_dst, t_ok = _switch_target_dests(active_idx, goals, aux_list,
-                                           state, derived, constraint,
-                                           cand_p, cand_s, src_valid,
-                                           rank_stride=num_shards,
-                                           rank_offset=shard)
-        # Targets pause while any offline replica exists ANYWHERE on the
-        # mesh (psum'd below via offline_pb; see chain._chain_round_body).
-        extra = (t_dst, t_ok & ~(_psum(off.sum()) > 0))
-    cand, layout = generate_candidates(state, derived, src_score, dst_score,
-                                       weight, k_src, cfg.num_dests,
-                                       include_leadership=True,
-                                       leadership_only=False,
-                                       extra_dst=extra, sources=sources)
-    (r0, c0), (r1, c1) = layout
-    block_ok = jnp.concatenate([
-        jnp.broadcast_to(~is_lead_only, (r0 * c0,)),
-        jnp.broadcast_to(has_leadership, (r1 * c1,)),
-    ])
-    cand = dataclasses.replace(cand, valid=cand.valid & block_ok)
-    deltas = compute_deltas(state, derived, cand)
-
-    accept = deltas.valid
-    for i, g in enumerate(goals):
-        accept &= (~prior_mask[i]) | g.acceptance(state, derived, constraint,
-                                                  aux_list[i], deltas)
-
-    moving_offline = off[deltas.partition, deltas.src_slot] \
-        & (deltas.replica_delta > 0)
-
-    def imp_branch(i):
-        g = goals[i]
-
-        def fn(_):
-            return g.improvement(state, derived, constraint, aux_list[i],
-                                 deltas).astype(jnp.float32)
-        return fn
-
-    imp = jax.lax.switch(active_idx,
-                         [imp_branch(i) for i in range(len(goals))], 0)
-    imp = jnp.where(moving_offline & jnp.isfinite(imp) & deltas.valid,
-                    jnp.maximum(imp, 0.0) + _OFFLINE_BONUS, imp)
-    score = jnp.where(accept, imp, -jnp.inf)
-
-    # Device-decorrelating rotation offset: with thin per-device slices
-    # different devices should lean toward different destinations among
-    # ties; with FULL-width grids each device already holds distinct
-    # (local) sources. Measured at 1k/8dev: zeroing the offset
-    # (CC_MESH_ROT=flat) is neutral — 649 vs 667 rounds at identical
-    # quality — so the offset stays (it strictly helps thinner widths).
-    rot_offset = 0 if os.environ.get("CC_MESH_ROT") == "flat" \
-        else shard * k_src
+    # Device-decorrelating rotation offset: different devices lean toward
+    # different destinations among ties.
     red_idx = reduce_per_source(
-        score, layout, row_offset=rot_offset, extra_last_col=use_targets)
+        score, sc.layout, row_offset=shard * cfg.num_sources,
+        extra_last_col=sc.targets)
     k_local = red_idx.shape[0]
 
     def gather(x):
@@ -311,12 +152,11 @@ def _chain_round_local(state: ClusterTensors, agg, masks: ExclusionMasks,
         # exactly one device owns each row.
         g_acc_owned = _psum(jnp.where(own, g_acc, False).astype(jnp.int32)) > 0
         accept &= (~prior_mask[i]) | g_acc_owned
-        accept &= (~is_active[i]) | (~has_earlier) | g_acc_owned
+        accept &= (~sc.is_active[i]) | (~has_earlier) | g_acc_owned
 
-    independent = indep_f[active_idx] & ~prior_mask.any()
     sel = part_ok & accept
     within_cap = jnp.cumsum(sel.astype(jnp.int32)) <= cfg.moves_per_round
-    sel &= jnp.where(independent, True, within_cap)
+    sel &= jnp.where(sc.independent, True, within_cap)
 
     # ``sel`` is computed from gathered, replicated data — identical on
     # every device, so its sum is already the global count, and the
@@ -338,10 +178,18 @@ def _chain_swap_local(state: ClusterTensors, agg, masks: ExclusionMasks,
                       goals, constraint: BalancingConstraint, num_topics: int,
                       num_shards: int, k_brokers: int = 8,
                       j_replicas: int = 4, moves: int = 8):
-    """Chain-parameterized sharded swap round — the card-gather kernel of
-    ``parallel.sharded._swap_round_local`` with the active goal as a traced
-    switch and prior acceptance as a traced mask. ``agg`` as in
-    ``_chain_round_local``; returns (new_state, new_agg, applied)."""
+    """Chain-parameterized sharded swap round, a card-gather kernel: the
+    two replicas of a swap live on ARBITRARY partition shards, so each
+    device finds its top-j heaviest / lightest replicas per candidate
+    broker and judges every prior goal's per-partition LEG acceptance
+    locally; the tiny replica cards are all-gathered (O(K·j·K) a device,
+    independent of the partition count); every device merges them, builds
+    the K x K x j x j pairing grid, applies net acceptance and the active
+    goal's net improvement and selects ONE conflict-free batch, identical
+    everywhere; each device applies the legs that land in its shard. The
+    active goal is a traced switch, prior acceptance a traced mask.
+    ``agg`` as in ``_chain_round_local``; returns (new_state, new_agg,
+    applied)."""
     shard = jax.lax.axis_index(PARTITION_AXIS)
     p_local = state.num_partitions
     p_global = p_local * num_shards
@@ -350,14 +198,13 @@ def _chain_swap_local(state: ClusterTensors, agg, masks: ExclusionMasks,
     s_dim = state.max_replication_factor
     j = j_replicas
 
-    additive_f = jnp.asarray([g.partition_additive_scores for g in goals])
     derived = compute_derived(state, masks.excluded_topics,
                               masks.excluded_replica_move_brokers,
                               masks.excluded_leadership_brokers, psum=_psum,
                               agg=agg)
-    aux_list, src_score, _dst_score, weight = _chain_scores(
+    _is_active, aux_list, src_score, _dst_score, weight = _chain_scores(
         state, derived, active_idx, prior_mask, goals, constraint,
-        num_topics, additive_f, agg=agg)
+        num_topics, agg, psum=_psum)
 
     # Swap counterparties rank by swap_dest_score (broker-indexed, mesh-
     # safe). NOTE: swap IMPROVEMENT on the mesh stays net-transfer-based

@@ -7,7 +7,7 @@ SPMD program over a pod slice: each host process owns its local chips,
 ``jax.distributed.initialize`` wires the processes into a single runtime,
 and the solver mesh spans every device — collectives ride ICI within a
 slice and DCN across slices. No hand-rolled RPC: the sharded kernels in
-``sharded.py`` are topology-agnostic (they see one mesh).
+``chain_sharded.py`` are topology-agnostic (they see one mesh).
 
 Usage (one process per host, e.g. under GKE/ray/mpi):
 

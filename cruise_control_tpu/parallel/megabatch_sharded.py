@@ -9,7 +9,7 @@ goal stats — gets a ``shard_map`` twin that places
 ``batch_width / n_devices`` cluster slots per device:
 
 - EVERY stacked field shards along the leading cluster axis (unlike the
-  partition-axis solver in ``parallel/sharded.py``, there are no
+  partition-axis solver in ``parallel/chain_sharded.py``, there are no
   replicated topology planes here — ``stack_states`` stacks the whole
   pytree, so capacity/rack/broker planes carry the cluster axis too);
 - clusters are INDEPENDENT, so the per-device body is literally the

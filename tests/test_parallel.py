@@ -6,21 +6,32 @@ embedded brokers + model-level simulation) — here the mesh IS real SPMD, just
 on virtual devices.
 """
 
+import dataclasses
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
 
 from cruise_control_tpu.analyzer.constraint import BalancingConstraint
 from cruise_control_tpu.analyzer.derived import compute_derived
+from cruise_control_tpu.analyzer.agg import compute_agg
+from cruise_control_tpu.analyzer.chain import _scored_candidates
 from cruise_control_tpu.analyzer.goals import (
-    RackAwareGoal, ReplicaDistributionGoal, NetworkOutboundUsageDistributionGoal,
-    TopicReplicaDistributionGoal,
+    LeaderReplicaDistributionGoal, NetworkOutboundUsageDistributionGoal,
+    PreferredLeaderElectionGoal, RackAwareGoal, ReplicaCapacityGoal,
+    ReplicaDistributionGoal, TopicReplicaDistributionGoal,
 )
 from cruise_control_tpu.analyzer.search import ExclusionMasks, SearchConfig, optimize_goal
 from cruise_control_tpu.model.fixtures import random_cluster
 from cruise_control_tpu.model.tensors import broker_load, broker_replica_counts
 from cruise_control_tpu.parallel import (
-    make_mesh, optimize_goal_sharded, shard_cluster,
+    make_mesh, optimize_chain_sharded, shard_cluster,
+)
+from cruise_control_tpu.parallel.mesh import (
+    _mask_specs, _psum, _state_specs,
 )
 
 CONSTRAINT = BalancingConstraint()
@@ -53,8 +64,8 @@ def test_sharded_replica_distribution_balances(mesh, cluster):
     state, meta = cluster
     goal = ReplicaDistributionGoal()
     sharded = shard_cluster(state, mesh)
-    out, info = optimize_goal_sharded(sharded, goal, (), CONSTRAINT, CFG,
-                                      meta.num_topics, mesh)
+    out, (info,) = optimize_chain_sharded(sharded, (goal,), CONSTRAINT, CFG,
+                                          meta.num_topics, mesh)
     assert info["moves_applied"] > 0
     # Single-device reference run reaches the same satisfied end state.
     out_ref, info_ref = optimize_goal(state, goal, (), CONSTRAINT, CFG,
@@ -65,19 +76,22 @@ def test_sharded_replica_distribution_balances(mesh, cluster):
     assert counts.max() - counts.min() <= counts_ref.max() - counts_ref.min() + 2
 
 
+def _rack_violation(state) -> float:
+    full = jax.device_get(state)
+    derived = compute_derived(full)
+    return float(RackAwareGoal().broker_violations(
+        full, derived, CONSTRAINT, None).sum())
+
+
 def test_sharded_respects_prior_goal_acceptance(mesh, cluster):
     state, meta = cluster
-    rack = RackAwareGoal()
     sharded = shard_cluster(state, mesh)
-    out, _ = optimize_goal_sharded(sharded, rack, (), CONSTRAINT, CFG,
-                                   meta.num_topics, mesh)
-    out2, _ = optimize_goal_sharded(out, ReplicaDistributionGoal(), (rack,),
-                                    CONSTRAINT, CFG, meta.num_topics, mesh)
+    out, infos = optimize_chain_sharded(
+        sharded, (RackAwareGoal(), ReplicaDistributionGoal()), CONSTRAINT,
+        CFG, meta.num_topics, mesh)
+    assert infos[1]["moves_applied"] > 0
     # Rack-awareness must not regress after the second goal ran.
-    full = jax.device_get(out2)
-    derived = compute_derived(full)
-    viol = rack.broker_violations(full, derived, CONSTRAINT, None)
-    assert float(viol.sum()) <= 1e-6
+    assert _rack_violation(out) <= 1e-6
 
 
 def test_sharded_resource_distribution_improves_balance(mesh, cluster):
@@ -85,27 +99,34 @@ def test_sharded_resource_distribution_improves_balance(mesh, cluster):
     goal = NetworkOutboundUsageDistributionGoal()
     before = np.asarray(broker_load(state))[:, 2]
     sharded = shard_cluster(state, mesh)
-    out, info = optimize_goal_sharded(sharded, goal, (), CONSTRAINT, CFG,
-                                      meta.num_topics, mesh)
+    out, _infos = optimize_chain_sharded(sharded, (goal,), CONSTRAINT, CFG,
+                                         meta.num_topics, mesh)
     after = np.asarray(broker_load(jax.device_get(out)))[:, 2]
     assert after.std() < before.std()
 
 
 def test_sharded_swap_round_matches_single_device(mesh, cluster):
-    """The card-gather swap kernel must find the same swap batch as the
-    single-device swap round: per-broker global top-j merged from per-shard
-    top-j is exact, and selection is score-rank deterministic."""
-    from cruise_control_tpu.analyzer.search import swap_round
-    from cruise_control_tpu.parallel import sharded_swap_round
+    """The chain's card-gather swap kernel must find the same swap batch as
+    the single-device chain swap round: per-broker global top-j merged from
+    per-shard top-j is exact, and selection is score-rank deterministic."""
+    from cruise_control_tpu.analyzer.chain import chain_swap_rounds
+    from cruise_control_tpu.parallel.chain_sharded import (
+        _make_chain_phase_kernels,
+    )
 
     state, meta = cluster
-    goal = NetworkOutboundUsageDistributionGoal()
+    goals = (NetworkOutboundUsageDistributionGoal(),)
     masks = ExclusionMasks()
-    ref_state, ref_n = swap_round(state, goal, (), CONSTRAINT,
-                                  meta.num_topics, masks)
-    sharded = shard_cluster(state, mesh)
-    out, n = sharded_swap_round(sharded, goal, (), CONSTRAINT,
-                                meta.num_topics, masks, mesh)
+    idx, prior, one = jnp.int32(0), jnp.asarray([False]), jnp.int32(1)
+    ref_state, ref_n, ref_rounds = chain_swap_rounds(
+        state, idx, prior, goals, CONSTRAINT, meta.num_topics, masks,
+        budget=one)
+    assert int(ref_rounds) == 1 and int(ref_n) > 0
+    swap = _make_chain_phase_kernels(
+        mesh, goals, CONSTRAINT, CFG, meta.num_topics,
+        (False, False, False), 8, 64)[1]
+    out, n, rounds = swap(shard_cluster(state, mesh), masks, idx, prior, one)
+    assert int(rounds) == 1
     assert int(n) == int(ref_n)
     np.testing.assert_array_equal(np.asarray(jax.device_get(out).assignment),
                                   np.asarray(ref_state.assignment))
@@ -115,29 +136,12 @@ def test_sharded_swap_respects_prior_rack_goal(mesh, cluster):
     """Swap legs are leg-accepted by prior structural goals on the owning
     device: rack-awareness must survive a swap phase under the mesh."""
     state, meta = cluster
-    rack = RackAwareGoal()
     sharded = shard_cluster(state, mesh)
-    out, _ = optimize_goal_sharded(sharded, rack, (), CONSTRAINT, CFG,
-                                   meta.num_topics, mesh)
-    goal = NetworkOutboundUsageDistributionGoal()
-    out2, info = optimize_goal_sharded(out, goal, (rack,), CONSTRAINT, CFG,
-                                       meta.num_topics, mesh)
-    full = jax.device_get(out2)
-    derived = compute_derived(full)
-    viol = rack.broker_violations(full, derived, CONSTRAINT, None)
-    assert float(viol.sum()) <= 1e-6
-
-
-def test_sharded_driver_fuses_rounds(mesh, cluster):
-    """The fused while_loop driver makes host round-trips per PHASE, not
-    per round: many rounds, few round-trips."""
-    state, meta = cluster
-    sharded = shard_cluster(state, mesh)
-    out, info = optimize_goal_sharded(sharded, ReplicaDistributionGoal(), (),
-                                      CONSTRAINT, CFG, meta.num_topics, mesh)
-    assert info["rounds"] > 3
-    # move phase + final check only (no swap support on this goal).
-    assert info["host_roundtrips"] <= 2
+    out, infos = optimize_chain_sharded(
+        sharded, (RackAwareGoal(), NetworkOutboundUsageDistributionGoal()),
+        CONSTRAINT, CFG, meta.num_topics, mesh)
+    assert infos[1]["moves_applied"] > 0 and infos[1]["rounds"] > 0
+    assert _rack_violation(out) <= 1e-6
 
 
 def test_distributed_single_process_path(mesh, cluster):
@@ -152,9 +156,92 @@ def test_distributed_single_process_path(mesh, cluster):
     assert gmesh.devices.size == len(jax.devices())
     state, meta = cluster
     sharded = shard_cluster(state, gmesh)
-    out, res = optimize_goal_sharded(sharded, ReplicaDistributionGoal(), (),
-                                     CONSTRAINT, CFG, meta.num_topics, gmesh)
+    out, (res,) = optimize_chain_sharded(
+        sharded, (ReplicaDistributionGoal(),), CONSTRAINT, CFG,
+        meta.num_topics, gmesh)
     assert res["succeeded"]
+
+
+# The five goals of tests/test_chain.py's CHAIN: structural, capacity,
+# resource distribution (partition-additive scores), leader distribution,
+# leadership-only.
+SEAM_CHAIN = (RackAwareGoal(), ReplicaCapacityGoal(),
+              NetworkOutboundUsageDistributionGoal(),
+              LeaderReplicaDistributionGoal(), PreferredLeaderElectionGoal())
+
+
+# A capacity that the skewed fixture's first brokers exceed, so that the
+# capacity goal has sources too.
+SEAM_CONSTRAINT = BalancingConstraint(max_replicas_per_broker=24)
+
+
+def _scoring_half(i: int, num_topics: int, psum):
+    """The move round's scoring half for goal ``i`` of SEAM_CHAIN under its
+    prior goals, as arrays: (score, accept, the deltas' leaves)."""
+    prior = jnp.asarray([j < i for j in range(len(SEAM_CHAIN))])
+
+    def half(state, masks):
+        agg = compute_agg(state, num_topics, psum=psum)
+        sc = _scored_candidates(
+            state, agg, jnp.int32(i), prior, SEAM_CHAIN, SEAM_CONSTRAINT,
+            CFG, num_topics, masks, global_partitions=state.num_partitions,
+            psum=psum)
+        assert sc.deltas.grid is not None
+        return sc.score, sc.accept, jax.tree.leaves(sc.deltas.without_grid())
+
+    return half
+
+
+@pytest.mark.parametrize("i", range(len(SEAM_CHAIN)))
+def test_scoring_half_on_a_mesh_of_one_equals_one_chip(i, cluster):
+    """The seam itself: with ``psum`` set, on a mesh of ONE device (every
+    sum is the identity, targets stay on), ``_scored_candidates`` returns
+    score, accept and deltas byte-equal to the one-chip call. Every leader
+    sits on its second replica, so the leadership-only goal has work."""
+    state, meta = cluster
+    state = dataclasses.replace(state,
+                                leader_slot=jnp.ones_like(state.leader_slot))
+    masks = ExclusionMasks()
+    mesh1 = make_mesh(1)
+    one_chip = jax.jit(_scoring_half(i, meta.num_topics, None))(state, masks)
+    on_mesh = jax.jit(shard_map(
+        _scoring_half(i, meta.num_topics, _psum), mesh=mesh1,
+        in_specs=(_state_specs(), _mask_specs((False, False, False))),
+        out_specs=P(), check_vma=False))(shard_cluster(state, mesh1), masks)
+    assert np.isfinite(np.asarray(one_chip[0])).any()
+    for a, b in zip(jax.tree.leaves(one_chip), jax.tree.leaves(on_mesh),
+                    strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_mesh_round_body_scores_on_the_candidate_grid(mesh, cluster,
+                                                      monkeypatch):
+    """The mesh body's deltas carry a CandidateGrid: per-broker tables are
+    looked up on the grid's margins under ``shard_map`` as on one chip."""
+    from cruise_control_tpu.analyzer import chain as chain_mod
+    from cruise_control_tpu.parallel import chain_sharded
+
+    seen = []
+    real = chain_mod._scored_candidates
+
+    def spy(*args, **kwargs):
+        sc = real(*args, **kwargs)
+        seen.append((kwargs["psum"], sc.deltas.grid))
+        return sc
+
+    monkeypatch.setattr(chain_sharded, "_scored_candidates", spy)
+    state, meta = cluster
+    goals = (ReplicaDistributionGoal(),)
+    move = chain_sharded._make_chain_phase_kernels.__wrapped__(
+        mesh, goals, CONSTRAINT, CFG, meta.num_topics,
+        (False, False, False), 8, 64)[0]
+    _out, applied, rounds = move(
+        shard_cluster(state, mesh), ExclusionMasks(), jnp.int32(0),
+        jnp.asarray([False]), jnp.int32(1))
+    assert int(rounds) == 1 and int(applied) > 0
+    assert seen and all(psum is _psum and grid is not None
+                        for psum, grid in seen)
+    assert chain_mod.accept_lookup() == "grid"
 
 
 def test_sharded_full_chain_matches_single_device_outcome(mesh, cluster):
@@ -164,10 +251,6 @@ def test_sharded_full_chain_matches_single_device_outcome(mesh, cluster):
     trajectory equality is not expected — per-device top-k candidate
     generation explores a different, equally valid move order.)"""
     from cruise_control_tpu.analyzer.chain import optimize_chain
-    from cruise_control_tpu.analyzer.goals import (
-        PreferredLeaderElectionGoal, ReplicaCapacityGoal,
-    )
-    from cruise_control_tpu.parallel import optimize_chain_sharded
 
     state, meta = cluster
     chain = (RackAwareGoal(), ReplicaCapacityGoal(),
@@ -206,9 +289,6 @@ def test_sharded_bounded_dispatch_matches_fused(mesh, cluster):
     the IDENTICAL trajectory to the fused whole-chain mesh kernel — same
     final assignment and per-goal moves/swaps (both run the same per-device
     round bodies; only dispatch boundaries differ)."""
-    from cruise_control_tpu.analyzer.goals import ReplicaCapacityGoal
-    from cruise_control_tpu.parallel import optimize_chain_sharded
-
     state, meta = cluster
     chain = (RackAwareGoal(), ReplicaCapacityGoal(),
              ReplicaDistributionGoal(),
@@ -259,12 +339,8 @@ def test_goal_optimizer_uses_mesh(mesh, cluster):
 def test_sharded_topic_replica_aux_psum(mesh, cluster):
     """TopicReplicaDistributionGoal's [T, B] aux is additive across shards —
     the production sharded chain kernel (psum'd aux + joint cumulative
-    selection) must reach the single-device outcome. The LEGACY per-goal
-    sharded driver is excluded: its narrower per-device candidate slice can
-    strand a last violation the fused paths fix (pre-existing; the
-    production path replaced it)."""
+    selection) must reach the single-device outcome."""
     from cruise_control_tpu.analyzer.chain import optimize_chain
-    from cruise_control_tpu.parallel import optimize_chain_sharded
 
     state, meta = cluster
     goal = TopicReplicaDistributionGoal()
@@ -286,8 +362,6 @@ def test_sharded_topic_replica_aux_psum(mesh, cluster):
 
 
 def _direct_chain():
-    from cruise_control_tpu.analyzer.goals import ReplicaCapacityGoal
-
     return (RackAwareGoal(), ReplicaCapacityGoal(),
             ReplicaDistributionGoal(), TopicReplicaDistributionGoal())
 
@@ -301,7 +375,6 @@ def test_sharded_direct_prepass_mesh1_matches_single_device_bytes(cluster):
     from cruise_control_tpu.analyzer.chain import (
         DispatchStats, MegastepConfig, optimize_goal_in_chain,
     )
-    from cruise_control_tpu.parallel import optimize_chain_sharded
 
     state, meta = cluster
     chain = _direct_chain()
@@ -339,7 +412,6 @@ def test_sharded_direct_prepass_runs_deterministically_on_mesh(mesh,
     from cruise_control_tpu.analyzer.chain import (
         DispatchStats, MegastepConfig, optimize_goal_in_chain,
     )
-    from cruise_control_tpu.parallel import optimize_chain_sharded
 
     state, meta = cluster
     chain = _direct_chain()
