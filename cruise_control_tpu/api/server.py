@@ -1807,7 +1807,9 @@ class _Handler(BaseHTTPRequestHandler):
                 content_type = extra.pop("Content-Type",
                                          "text/plain; charset=utf-8")
             else:
-                data = json.dumps(body, indent=2).encode()
+                # compact, as upstream's Gson writes it; any ``indent``
+                # takes CPython off its C encoder (docs/DESIGN.md)
+                data = json.dumps(body, separators=(",", ":")).encode()
                 content_type = extra.pop("Content-Type", "application/json")
         self._send(method, t0, status, data, content_type, extra)
 

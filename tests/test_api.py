@@ -3,6 +3,7 @@ two-step review, security (reference parity: servlet/ test ideas —
 KafkaCruiseControlServletEndpointTest, UserTaskManagerTest, purgatory and
 security suites — against the stdlib server)."""
 
+import http.client
 import json
 import threading
 import time
@@ -458,6 +459,76 @@ def test_http_server_round_trip(cc):
     finally:
         server.shutdown()
         api.shutdown()
+
+
+# ---- the body's JSON text on the wire (ISSUE 30) ---------------------------
+
+@pytest.fixture(scope="module")
+def wire(cc):
+    server, api = make_server(cc, host="127.0.0.1", port=0)
+    api._async_wait_s = 180
+    threading.Thread(target=server.serve_forever, daemon=True).start()
+    yield server.server_address[1], api
+    server.shutdown()
+    server.server_close()
+    api.shutdown()
+
+
+def _raw(port, method, path):
+    """(status, headers, raw bytes) of one request over a real socket."""
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=300)
+    try:
+        conn.request(method, f"/kafkacruisecontrol/{path}")
+        resp = conn.getresponse()
+        return resp.status, resp.headers, resp.read()
+    finally:
+        conn.close()
+
+
+@pytest.mark.parametrize("method,path,expected", [
+    ("GET", "proposals?verbose=true", 200),
+    ("POST", "remove_broker?brokerid=3&dryrun=true&verbose=true", 200),
+    ("GET", "state", 200),
+    ("GET", "load", 200),
+    ("GET", "partition_load", 200),
+    ("GET", "kafka_cluster_state", 200),
+    ("GET", "user_tasks", 200),
+    ("GET", "partition_load?resource=warp", 400),
+], ids=["proposals", "remove_broker", "state", "load", "partition_load",
+        "kafka_cluster_state", "user_tasks", "error"])
+def test_json_bodies_are_compact_and_parse_to_the_same_document(
+        wire, method, path, expected):
+    """Every JSON response is the C encoder's compact text (what
+    upstream's Gson writes): nothing between tokens, key order and number
+    text the encoder's own; the document a client parses is the one the
+    indented text held."""
+    port, api = wire
+    status, headers, raw = _raw(port, method, path)
+    assert status == expected, raw[:400]
+    assert headers["Content-Type"] == "application/json"
+    parsed = json.loads(raw)
+    assert raw == json.dumps(parsed, separators=(",", ":")).encode()
+    assert b"\n " not in raw
+    assert int(headers["Content-Length"]) == len(raw)
+    if path.startswith("proposals"):
+        # same model generation: the facade answers with the same plan
+        _status, body, _extra = api.handle(
+            "GET", "/kafkacruisecontrol/proposals", "verbose=true")
+        assert len(body["proposals"]) > 0
+        assert parsed == json.loads(json.dumps(body, indent=2))
+
+
+def test_text_bodies_pass_the_encoder_by(wire):
+    """``json=false`` tables are written as they are: the text and one
+    newline, byte for byte."""
+    port, api = wire
+    _status, body, _extra = api.handle(
+        "GET", "/kafkacruisecontrol/kafka_cluster_state", "json=false")
+    status, headers, raw = _raw(port, "GET", "kafka_cluster_state?json=false")
+    assert status == 200
+    assert headers["Content-Type"].startswith("text/plain")
+    assert raw == (body["__text__"] + "\n").encode()
+    assert int(headers["Content-Length"]) == len(raw)
 
 
 # ---- console client ------------------------------------------------------

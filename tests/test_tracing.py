@@ -504,6 +504,14 @@ def _request_traces(endpoint="PROPOSALS"):
             and _attrs(t["root"]).get("endpoint") == endpoint]
 
 
+def _await_request_traces():
+    """The request's traces, once its root has closed (after the write)."""
+    deadline = time.monotonic() + 5
+    while not _request_traces() and time.monotonic() < deadline:
+        time.sleep(0.01)
+    return _request_traces()
+
+
 _SERVED_TREE = (
     "http.handle", "http.serialize", "http.write", "monitor.cluster_model",
     "analyzer.optimize", "solver.dispatch", "solver.enqueue", "solver.wait",
@@ -572,10 +580,7 @@ def test_served_proposals_is_one_trace_rooted_at_the_request(served):
     status, headers, body = _http_get(
         port, "proposals?verbose=true&ignore_proposal_cache=true")
     assert status == 200 and "summary" in body
-    deadline = time.monotonic() + 5      # the root closes after the write
-    while not _request_traces() and time.monotonic() < deadline:
-        time.sleep(0.01)
-    traces = _request_traces()
+    traces = _await_request_traces()
     assert len(traces) == 1
     root = traces[0]["root"]
     names = [n["name"] for n in _walk(root)]
@@ -605,10 +610,7 @@ def test_children_of_the_request_cover_it(served):
     port, _api, _route = served
     TRACER.clear()
     _http_get(port, "proposals?verbose=true&ignore_proposal_cache=true")
-    deadline = time.monotonic() + 5
-    while not _request_traces() and time.monotonic() < deadline:
-        time.sleep(0.01)
-    root = _request_traces()[0]["root"]
+    root = _await_request_traces()[0]["root"]
     direct = [c for c in root["children"]
               if c["name"] in ("http.handle", "http.serialize", "http.write")]
     assert [c["name"] for c in direct] == \
@@ -616,6 +618,23 @@ def test_children_of_the_request_cover_it(served):
     covered = sum(c["durationMs"] for c in direct)
     assert covered >= 0.95 * root["durationMs"]
     assert covered <= root["durationMs"] + 1e-6
+
+
+def test_root_span_bytes_is_the_compact_texts_length(served):
+    """What ``GET /trace`` shows of a body's size is the wire's: the
+    length of the compact JSON text of the document (ISSUE 30)."""
+    import urllib.request
+    port, _api, _route = served
+    TRACER.clear()
+    with urllib.request.urlopen(
+            f"http://127.0.0.1:{port}/kafkacruisecontrol/proposals"
+            "?verbose=true&ignore_proposal_cache=true", timeout=300) as resp:
+        raw = resp.read()
+    body = json.loads(raw)
+    assert body["proposals"]
+    attrs = _attrs(_await_request_traces()[0]["root"])
+    assert int(attrs["bytes"]) == len(raw) \
+        == len(json.dumps(body, separators=(",", ":")).encode())
 
 
 def test_202_and_its_poll_share_the_task_and_the_first_trace(
@@ -677,10 +696,7 @@ def test_a_served_request_keeps_its_cluster(served):
     port, _api, route = served
     TRACER.clear()
     _http_get(port, "proposals?verbose=true&ignore_proposal_cache=true")
-    deadline = time.monotonic() + 5
-    while not _request_traces() and time.monotonic() < deadline:
-        time.sleep(0.01)
-    trace = _request_traces()[0]
+    trace = _await_request_traces()[0]
     if route == "fleet":
         assert trace["cluster"] == "alpha"
         assert [t["traceId"] for t in TRACER.traces(cluster="alpha")] == \
