@@ -20,9 +20,8 @@ far the solver is from what the problem needs, and which bound it is
 from __future__ import annotations
 
 import json
-import os
 
-from .deployment import HERE
+from .deployment import path_of
 
 _WORD = 4
 
@@ -38,7 +37,7 @@ def proposal_bytes(cfg: dict) -> int:
 def peak(device_kind: str) -> dict:
     """The peak table's row of a device; a kind that is not in the table
     is an error, not a default."""
-    path = os.path.join(HERE, "peaks.json")
+    path = path_of("peaks.json")
     with open(path) as f:
         table = json.load(f)
     if device_kind not in table:

@@ -13,17 +13,20 @@ them are checked) and nothing else.
 
 Recipe (copied from ``chip_smoke.build_cluster`` / ``capacities`` and
 ``SyntheticSampler``; the originals are listed in PERF.md for deletion):
-broker placement weights ``exp(-placement_skew * i / (B - 1))``, the first
-B partitions on a ring so every broker hosts something, partition load a
-uniform draw raised to ``load_skew``, homogeneous capacity putting the
+where the replicas start is the configuration's ``placement`` rule
+(``placements/<name>.py``, found by name as a metric's reader is;
+``skewed_random`` where the file names none), partition load a uniform
+draw raised to ``load_skew``, homogeneous capacity putting the
 cluster-average utilisation of every resource at ``target_utilization``.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 import os
+import re
 
 import numpy as np
 
@@ -37,15 +40,32 @@ _CPU_LEADER_IN, _CPU_LEADER_OUT, _CPU_FOLLOWER_IN = 0.7, 0.15, 0.15
 
 OPERATIONS = ("proposals", "rebalance", "add_broker", "remove_broker")
 
+# The one place the harness takes its directories from: every file found
+# by name is sought under here, when it is sought (a test points it at a
+# directory of its own).
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def path_of(*parts: str) -> str:
+    return os.path.join(HERE, *parts)
 
 
 def load_json(kind: str, name: str) -> dict:
     """``benchmarks/<kind>/<name>.json``: configurations, traffic mixes and
     metrics are found by the name ``BENCHMARK.json`` gives."""
-    path = os.path.join(HERE, kind, f"{name}.json")
-    with open(path) as f:
+    with open(path_of(kind, f"{name}.json")) as f:
         return json.load(f)
+
+
+def load_module(kind: str, name: str):
+    """``benchmarks/<kind>/<name>.py``, run anew: a metric's reader, a
+    placement rule or an operation's rule, found by the name a file gives."""
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_" + re.sub(r"\W", "_", name),
+        path_of(kind, f"{name}.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
 
 @dataclasses.dataclass(frozen=True)
@@ -99,23 +119,13 @@ def build(cfg: dict) -> Deployment:
         raise ValueError("need at least one partition per hosting broker "
                          "and RF hosting brokers")
     rng = np.random.default_rng(int(cfg.get("instance_seed", 0)))
-    weights = np.exp(-float(cfg["placement_skew"]) * np.arange(len(hosts))
-                     / max(1, len(hosts) - 1))
-    cdf = np.cumsum(weights)
-
-    def draw(n):
-        return np.minimum(np.searchsorted(cdf, rng.random((n, rf)) * cdf[-1]),
-                          len(hosts) - 1)
-
-    replicas = draw(partitions)
-    while True:     # re-draw only the rows that drew one broker twice
-        srt = np.sort(replicas, axis=1)
-        dup = (srt[:, 1:] == srt[:, :-1]).any(axis=1)
-        if not dup.any():
-            break
-        replicas[dup] = draw(int(dup.sum()))
-    ring = (np.arange(len(hosts))[:, None] + np.arange(rf)) % len(hosts)
-    replicas[:len(hosts)] = ring
+    broker_rack = np.arange(brokers) % racks
+    rule = cfg.get("placement", "skewed_random")
+    replicas = np.asarray(load_module("placements", rule).place(
+        cfg, hosts, broker_rack[hosts], rng))
+    if replicas.shape != (partitions, rf):
+        raise ValueError(f"placement {rule!r} gave {replicas.shape}, not "
+                         f"{(partitions, rf)}")
     assignment = hosts[replicas]
 
     h = rng.random(partitions) ** float(cfg["load_skew"])
@@ -140,7 +150,7 @@ def build(cfg: dict) -> Deployment:
     return Deployment(
         brokers=brokers, racks=racks, topics=topics, rf=rf,
         assignment=assignment.astype(np.int64),
-        broker_rack=np.arange(brokers) % racks,
+        broker_rack=broker_rack,
         alive=np.ones(brokers, dtype=bool),
         leader_load=leader, follower_load=follower, capacity=capacity,
         operation=operation, operation_brokers=op_brokers)

@@ -1,15 +1,20 @@
 """Planted faults: the answers of a sound run, altered where they are
 produced, one guarantee at a time. Each has to read above the limit on the
 number named beside it; ``readings.py --faults`` reads them on the chip at
-the cell's own size and ``tests/test_faults.py`` at a small size.
+the cell's own size, ``tests/test_run.py`` and ``tests/test_seams.py`` at a
+small size.
 
 A fault is ``f(proposals, dep) -> proposals`` over one body's list of
-moves (``READ_FAULTS``: over one read's body). None alters its input.
+moves (``READ_FAULTS``: over one read's body). None alters its input. The
+faults of an operation's own guarantee sit with its rule,
+``guarantees/<operation>.py``; ``planted`` gives both.
 """
 
 from __future__ import annotations
 
 import copy
+
+from .reference import operation_rule
 
 
 def _alter(proposals: list, change) -> list:
@@ -94,6 +99,12 @@ FAULTS = {
     stale_old: "stale_old", unknown_partition: "unknown_partition",
     same_rack: "rack_violations", pile_up: "over_capacity",
 }
+
+
+def planted(operation: str) -> dict:
+    """``FAULTS`` and those of the operation's own rule."""
+    rule = operation_rule(operation)
+    return {**FAULTS, **(rule.FAULTS if rule else {})}
 
 
 def stale_read(body, dep):

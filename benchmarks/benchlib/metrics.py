@@ -10,10 +10,8 @@ out of the line; it never returns 0 for a share of a roofline or a peak.
 from __future__ import annotations
 
 import dataclasses
-import importlib.util
-import os
 
-from .deployment import HERE, load_json
+from .deployment import load_json, load_module
 from .sut import series_total
 
 
@@ -45,19 +43,26 @@ def rounds(solve) -> int:
                for g in solve.body["summary"]["goals"].values())
 
 
-def program_seconds(ctx: Context) -> float:
+def program_seconds(ctx: Context) -> float | None:
     """Device seconds, in the traced window, of the XLA programs whose
-    names hold one of the metric's ``programs`` parts."""
-    return sum(sec for name, sec in ctx.trace["modules"].items()
-               if any(part in name for part in ctx.param["programs"]))
+    names hold one of the metric's ``programs`` parts. None, and a line
+    that says so, where they are under the metric's
+    ``least_share_of_busy`` of the device's busy seconds: the trace has
+    then lost module events, and a time per round taken from it would read
+    fast (the metric's file has the readings)."""
+    seconds = sum(sec for name, sec in ctx.trace["modules"].items()
+                  if any(part in name for part in ctx.param["programs"]))
+    busy_s = ctx.trace["busy_s"]
+    if seconds < ctx.param["least_share_of_busy"] * busy_s:
+        print(f"trace: {ctx.param['name']} not reported: its programs "
+              f"took {seconds:.6f} s of the device's {busy_s:.6f} busy "
+              f"seconds, under {ctx.param['least_share_of_busy']:g} of them",
+              flush=True)
+        return None
+    return seconds
 
 
 def read_metric(name: str, ctx: Context) -> float | None:
-    path = os.path.join(HERE, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    value = module.read(dataclasses.replace(
+    value = load_module("metrics", name).read(dataclasses.replace(
         ctx, param=load_json("metrics", name)))
     return None if value is None else float(value)

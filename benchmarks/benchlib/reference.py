@@ -14,12 +14,21 @@ of CPU); RackAwareGoal wants min(RF, racks) distinct racks per partition;
 a capacity goal wants every alive broker's summed load of a resource at or
 under ``capacity * threshold``; ReplicaCapacityGoal wants at most
 ``max_replicas_per_broker`` replicas on a broker.
+
+What an OPERATION promises beyond the goals is a file of its own,
+``guarantees/<operation>.py``, found by the name the configuration gives
+(``operation_rule``): its counts join ``NUMBERS`` and its planted faults
+``faults.FAULTS``, so that a deployment under a new operation brings its
+rule and edits nothing here.
 """
 
 from __future__ import annotations
 
+import os
+
 import numpy as np
 
+from . import deployment
 from .deployment import RESOURCES, Deployment
 
 # The program sums a broker's load in float32; the reference in float64.
@@ -29,6 +38,22 @@ _ROUNDING = 1e-5
 NUMBERS = ("unknown_partition", "stale_old", "rf_broken", "dup_broker",
            "dead_broker", "leader_not_replica", "on_removed_broker",
            "rack_violations", "over_capacity")
+
+
+def operation_rule(operation: str):
+    """``guarantees/<operation>.py`` where the operation has a rule of its
+    own, else None."""
+    if not os.path.isfile(deployment.path_of("guarantees",
+                                             f"{operation}.py")):
+        return None
+    return deployment.load_module("guarantees", operation)
+
+
+def numbers_of(operation: str) -> tuple[str, ...]:
+    """The names a cell under ``operation`` compares: ``NUMBERS`` and its
+    rule's."""
+    rule = operation_rule(operation)
+    return NUMBERS + (tuple(rule.NUMBERS) if rule else ())
 
 
 def broker_loads(dep: Deployment, assignment: np.ndarray,
@@ -52,10 +77,16 @@ def rack_violations(dep: Deployment, assignment: np.ndarray) -> int:
     return int((distinct < min(dep.rf, dep.racks)).sum())
 
 
+def placed(dep: Deployment, assignment: np.ndarray) -> np.ndarray:
+    """[P, RF] bool: the replicas of ``assignment`` on a broker that did
+    not hold their partition in the deployment as built."""
+    return ~(assignment[:, :, None] == dep.assignment[:, None, :]).any(axis=2)
+
+
 def evaluate(dep: Deployment, guarantees: dict, proposals: list) -> dict:
-    """Counts of breached guarantees (``NUMBERS``) after the proposals are
-    applied to the deployment, plus ``info``: readings that are printed
-    but not compared."""
+    """Counts of breached guarantees (``numbers_of`` the deployment's
+    operation) after the proposals are applied to the deployment, plus
+    ``info``: readings that are printed but not compared."""
     n = dict.fromkeys(NUMBERS, 0)
     assignment = dep.assignment.copy()
     leader_col = np.zeros(dep.partitions, dtype=np.int64)
@@ -98,8 +129,13 @@ def evaluate(dep: Deployment, guarantees: dict, proposals: list) -> dict:
     if dep.operation == "remove_broker":
         n["on_removed_broker"] = int(
             np.isin(assignment, dep.operation_brokers).sum())
+    rule = operation_rule(dep.operation)
+    if rule is not None:
+        n.update(rule.count(dep, assignment, leader_col, proposals))
     before = broker_loads(dep, dep.assignment,
                           np.zeros(dep.partitions, dtype=np.int64))
+    new_here = placed(dep, assignment)
+    touched = (assignment != dep.assignment).any(axis=1) | (leader_col != 0)
     info = {
         # Printed, not compared: at 100 partitions a broker no answer comes
         # near the ceiling, so no control or fault gives an upper reading.
@@ -110,6 +146,8 @@ def evaluate(dep: Deployment, guarantees: dict, proposals: list) -> dict:
             (before / (dep.capacity * thresholds)).max()),
         "rack_violations_before": rack_violations(dep, dep.assignment),
         "moved_partitions": len(seen),
+        "leadership_only": int((touched & ~new_here.any(axis=1)).sum()),
+        "replicas_placed": int(new_here.sum()),
     }
     return {"numbers": n, "info": info}
 
@@ -152,7 +190,10 @@ def read_mismatch(dep: Deployment, endpoint: str, body: dict | None) -> bool:
     raise ValueError(f"no reference for the read {endpoint!r}")
 
 
-def worst(evaluations: list[dict]) -> dict:
-    """The largest reading of each number over a window's bodies."""
+def worst(evaluations: list[dict], names=NUMBERS) -> dict:
+    """The largest reading of each number over a window's bodies, under
+    whatever names the evaluations carry; a window that completed no body
+    reads 0 under ``names`` (and fails ``no_proposal``)."""
+    names = list(evaluations[0]["numbers"]) if evaluations else names
     return {k: max((e["numbers"][k] for e in evaluations), default=0)
-            for k in NUMBERS}
+            for k in names}

@@ -16,6 +16,13 @@ SPANS = "trace_span_seconds"
 SEGMENTS = "journey_segment_seconds"
 
 
+def endpoint(ctx: Context) -> str:
+    """The ``endpoint`` label of the cell's own requests: its operation as
+    the program's router names it (``proposals`` -> ``PROPOSALS``,
+    ``remove_broker`` -> ``REMOVE_BROKER``)."""
+    return str(ctx.cfg.get("operation", "proposals")).upper()
+
+
 def span_count(ctx: Context, span: str, **labels) -> float:
     return ctx.delta(SPANS + "_count", span=span, **labels)
 
@@ -33,11 +40,15 @@ def ms_per_solve(ctx: Context, seconds: float) -> float:
     return 1000.0 * seconds / len(ctx.solves)
 
 
-def read_spans(ctx: Context) -> float | None:
-    """Milliseconds a proposal spent in the metric's ``spans`` (of its
-    ``endpoint``'s requests where it names one); None where the first of
-    them never closed in the window."""
-    labels = {k: ctx.param[k] for k in ("endpoint",) if k in ctx.param}
+def read_spans(ctx: Context, **labels) -> float | None:
+    """Milliseconds a proposal spent in the metric's ``spans``; None where
+    the first of them never closed in the window."""
     if not ctx.solves or not span_count(ctx, ctx.param["spans"][0], **labels):
         return None
     return ms_per_solve(ctx, span_seconds(ctx, ctx.param["spans"], **labels))
+
+
+def read_endpoint_spans(ctx: Context) -> float | None:
+    """``read_spans`` over the requests of the cell's own endpoint (the
+    ``http.*`` spans carry the label)."""
+    return read_spans(ctx, endpoint=endpoint(ctx))

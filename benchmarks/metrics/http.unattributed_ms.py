@@ -2,17 +2,16 @@
 the named pieces covers (serialize, write, model refresh, optimizer, render):
 front door, task engine, facade, and whatever no span covers."""
 from benchlib.spans import (
-    ms_per_solve, segment_seconds, span_count, span_seconds,
+    endpoint, ms_per_solve, segment_seconds, span_count, span_seconds,
 )
 
 
 def read(ctx):
-    p = ctx.param
-    if not ctx.solves or not span_count(ctx, p["root"],
-                                        endpoint=p["endpoint"]):
+    p, at = ctx.param, endpoint(ctx)
+    if not ctx.solves or not span_count(ctx, p["root"], endpoint=at):
         return None
-    rest = span_seconds(ctx, [p["root"]], endpoint=p["endpoint"]) \
-        - span_seconds(ctx, p["less_http_spans"], endpoint=p["endpoint"]) \
+    rest = span_seconds(ctx, [p["root"]], endpoint=at) \
+        - span_seconds(ctx, p["less_http_spans"], endpoint=at) \
         - span_seconds(ctx, p["less_spans"]) \
-        - segment_seconds(ctx, p["less_segments"], p["endpoint"])
+        - segment_seconds(ctx, p["less_segments"], at)
     return ms_per_solve(ctx, rest)

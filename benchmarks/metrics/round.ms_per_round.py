@@ -5,6 +5,5 @@ from benchlib.metrics import program_seconds, rounds
 
 def read(ctx):
     n = sum(rounds(s) for s in ctx.traced_solves)
-    if ctx.trace is None or not n:
-        return None
-    return 1000.0 * program_seconds(ctx) / n or None
+    seconds = program_seconds(ctx) if ctx.trace is not None and n else None
+    return 1000.0 * seconds / n if seconds else None

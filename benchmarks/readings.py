@@ -2,14 +2,18 @@
 """Readings for the limits of ``correct``: one cell on many seeds in one
 process (set-up compiles once), as the program stands or as its control.
 
-    python3 benchmarks/readings.py --workload <cell> --seeds 1,2,3 --seconds 8 [--control <name>] [--faults]
+    python3 benchmarks/readings.py --workload <cell> --seeds 1,2,3 --seconds 8 [--control <name>] [--patch <file>] [--faults]
 
 A control is the program itself with a path of its own switched on: a
 patch of the configuration's ``controls`` that takes goals out of the
 chain, which breaks the guarantee that they hold after the moves. It has
-to come out as not correct. ``--faults`` also applies every planted fault
-of ``benchlib/faults.py`` to the window's answers and prints what each
-reads. The benchmark's own runs never run either.
+to come out as not correct. ``--patch`` lays the keys of a JSON file over
+the cell's configuration: a deployment that is no cell yet (another
+``placement``, ``operation``, ``operation_brokers``) read on the chip
+before the PR that adds it. ``--faults`` also applies every planted fault
+of ``benchlib/faults.py`` and of the operation's own rule
+(``guarantees/<operation>.py``) to the window's answers and prints what
+each reads. The benchmark's own runs never run any of these.
 """
 
 from __future__ import annotations
@@ -31,6 +35,8 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--control", help="name of one of the "
                     "configuration's controls")
+    ap.add_argument("--patch", help="JSON file of configuration keys "
+                    "laid over the cell's (after the control's)")
     ap.add_argument("--faults", action="store_true")
     ap.add_argument("--read-rates", help="comma-separated reads/s: sweep "
                     "the open loop's rate on the first seed (to find what "
@@ -47,6 +53,9 @@ def main(argv: list[str] | None = None) -> int:
     if args.control:
         with open(os.path.join(run.ROOT, config["file"])) as f:
             patch = json.load(f)["controls"][args.control]["patch"]
+    if args.patch:
+        with open(args.patch) as f:
+            patch = {**(patch or {}), **json.load(f)}
     verdicts = []
     seeds = [int(s) for s in args.seeds.split(",")]
     rates = [None] * len(seeds)
@@ -66,7 +75,8 @@ def main(argv: list[str] | None = None) -> int:
             "metrics": {k: v["value"] for k, v in result["metrics"].items()},
             "compared": {k: v[0] for k, v in result["compared"].items()},
             "proposals": result["workload"]["proposals"],
-            "memory_peak_bytes": result["device"]["memory_peak_bytes"],
+            "request_s": result["workload"]["request_s"],
+            "device": result["device"],
             "idle_gaps": result.get("breakdown", {}).get("idle_gaps"),
             "faulted": result.get("faulted"),
         }), flush=True)

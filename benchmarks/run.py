@@ -196,7 +196,8 @@ def compare(dep, cfg: dict, mix: dict, window: Window, good: list,
     bodies = [s.body["proposals"] for s in good]
     evaluations = [reference.evaluate(dep, cfg["guarantees"], b)
                    for b in bodies]
-    compared = {k: [v, 0] for k, v in reference.worst(evaluations).items()}
+    compared = {k: [v, 0] for k, v in reference.worst(
+        evaluations, reference.numbers_of(dep.operation)).items()}
     checked = [r for r in window.reads if r.keep and r.status == 200]
     if window.reads:
         wrong = [r for r in checked
@@ -213,8 +214,8 @@ def compare(dep, cfg: dict, mix: dict, window: Window, good: list,
     compared["no_proposal"] = [0 if good or not mix.get("solvers") else 1, 0]
     faulted = {}
     if faults:
-        from benchlib.faults import FAULTS, READ_FAULTS
-        for fault, number in FAULTS.items():
+        from benchlib.faults import READ_FAULTS, planted
+        for fault, number in planted(dep.operation).items():
             faulted[fault.__name__] = {number: reference.worst([
                 reference.evaluate(dep, cfg["guarantees"], fault(b, dep))
                 for b in bodies])[number]}
@@ -223,9 +224,12 @@ def compare(dep, cfg: dict, mix: dict, window: Window, good: list,
                 reference.read_mismatch(dep, r.endpoint, fault(r.body, dep))
                 for r in checked)}
     info = evaluations[-1]["info"] if evaluations else {}
+    by_goal = {name: int(g["rounds"]) for name, g in
+               good[-1].body["summary"]["goals"].items()
+               if int(g["rounds"])} if good else {}
     say(f"comparison: {len(evaluations)} bodies and {len(checked)} reads "
         f"against the reference in {time.monotonic() - t_ref:.3f} s; "
-        f"last body: {info}")
+        f"last body: {info}, rounds by goal {by_goal}")
     return compared, faulted
 
 
@@ -234,11 +238,12 @@ def run_cell(benchmark: dict, workload: str, seed: int, seconds: float,
              cfg_patch: dict | None = None, faults: bool = False,
              mix_patch: dict | None = None) -> dict:
     """One run of one cell; returns the result line's object.
-    ``cfg_patch`` (a control) and ``faults`` (every planted fault of
-    ``benchlib/faults.py`` applied in turn to the window's answers, under
-    the key ``faulted``) and ``mix_patch`` (the sweep for the rate the reads
-    sustain) are for ``readings.py`` and the tests: the command never sets
-    them."""
+    ``cfg_patch`` (a control, or a deployment that is no cell yet) and
+    ``faults`` (every planted fault of ``benchlib/faults.py`` and of the
+    operation's own rule applied in turn to the window's answers, under
+    the key ``faulted``) and ``mix_patch`` (the sweep for the rate the
+    reads sustain) are for ``readings.py`` and the tests: the command never
+    sets them."""
     import jax
     from benchlib.sut import Served
 
@@ -298,6 +303,11 @@ def run_cell(benchmark: dict, workload: str, seed: int, seconds: float,
     gc.collect()
 
     reduced = tracer.reduce() if tracer is not None else None
+    if reduced is not None:
+        say(f"trace: {reduced['requests']} requests in a window of "
+            f"{reduced['window_s']:.6f} s, device busy "
+            f"{reduced['busy_s']:.6f} s; seconds by program: "
+            f"{json.dumps(reduced['modules'])}")
     good = [s for s in window.solves if s.status == 200 and s.body
             and not s.body.get("stale") and "summary" in s.body]
     failed = len(window.solves) - len(good)

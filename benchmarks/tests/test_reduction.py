@@ -67,6 +67,45 @@ def test_recorded_excerpt():
     assert r["idle_gaps"][0][0].startswith("request: after last device op")
 
 
+@pytest.mark.parametrize("program_s,reported", [
+    (2.814, True),      # 0.995 of busy: PR 28's capture at 100 brokers
+    (1.4, False),       # half of busy: the odd line of PR 29
+])
+def test_program_seconds_far_short_of_busy_are_not_reported(
+        program_s, reported, capsys):
+    """``round.ms_per_round`` and ``solver_roofline`` divide by the
+    seconds of the solver's programs: where the trace lost module events
+    those fall far short of the seconds in which the device ran an
+    operation, and both readers give nothing and say why."""
+    from benchlib.metrics import Context, read_metric
+    with open(os.path.join(BENCH, "configs", "kafka-100b-10kp.json")) as f:
+        cfg = json.load(f)
+
+    class Solve:
+        body = {"summary": {"goals": {"a": {"rounds": 200},
+                                      "b": {"rounds": 6}}}}
+
+    ctx = Context(
+        cfg=cfg, mix={}, seconds=45.0, setup_s=50.0, t0=0.0, at_setup={},
+        at_close={}, solves=[Solve()] * 7, reads=[],
+        device={"kind": "TPU v5 lite"}, traced_solves=[Solve()] * 7,
+        trace={"modules": {"jit_chain_optimize_full": program_s,
+                           "jit_cluster_stats": 0.01},
+               "busy_s": 2.828, "window_s": 4.997, "requests": 7})
+    ms, share = (read_metric(name, ctx) for name in
+                 ("round.ms_per_round", "solver_roofline"))
+    said = capsys.readouterr().out
+    if reported:
+        assert ms == pytest.approx(1000 * 2.814 / (7 * 206))
+        assert share == pytest.approx(
+            100 * (15 * 162_100 * 4 / 819e9) / (2.814 / 7))
+        assert said == ""
+    else:
+        assert ms is None and share is None
+        assert said.count("not reported") == 2
+        assert "1.400000 s of the device's 2.828000 busy seconds" in said
+
+
 def test_op_name():
     assert trace.op_name("%fusion.7 = f32[8]{0} fusion(f32[8] %p), "
                          "kind=kLoop") == "fusion.7"

@@ -80,8 +80,8 @@ def test_sound_run_is_correct(sound):
 
 
 def test_every_planted_fault_reads_over_its_limit(sound):
-    from benchlib.faults import FAULTS, READ_FAULTS
-    for fault, number in {**FAULTS, **READ_FAULTS}.items():
+    from benchlib.faults import READ_FAULTS, planted
+    for fault, number in {**planted("proposals"), **READ_FAULTS}.items():
         reading = sound["faulted"][fault.__name__][number]
         limit = sound["compared"][number][1]
         assert reading > limit, fault.__name__
@@ -130,7 +130,7 @@ def test_the_control_is_not_correct(tiny, cpu_device, control, number):
 
 
 @pytest.mark.parametrize("operation,brokers", [
-    ("rebalance", []), ("remove_broker", [3, 7]), ("add_broker", [14, 15])])
+    ("rebalance", []), ("remove_broker", [3, 7])])
 def test_operations_are_data(tiny, cpu_device, operation, brokers):
     """A deployment's operation and its brokers are keys of its file."""
     import run
@@ -139,3 +139,22 @@ def test_operations_are_data(tiny, cpu_device, operation, brokers):
         cfg_patch={"operation": operation, "operation_brokers": brokers})
     assert result["correct"] is True, result["compared"]
     assert result["workload"]["proposals"] > 0
+
+
+def test_add_broker_is_held_to_its_operations_rule(tiny, cpu_device):
+    """``guarantees/add_broker.py`` is found by the operation's name and
+    its count printed beside the limit 0. What the program answers (whether
+    it is ``correct``, how many moves, what the count reads) is not held
+    here: today's program places replicas on old brokers, a faithful one
+    may not, and the PR that repairs it cannot edit this file. The start is
+    Kafka's own (``placement: kafka_rack_aware``), which a faithful
+    program can scale out; the skewed start it may have to refuse."""
+    import run
+    result = run.run_cell(
+        tiny, "tiny.rebalance", 7, 1.0, False, cpu_device, time.monotonic(),
+        cfg_patch={"operation": "add_broker", "operation_brokers": [14, 15],
+                   "placement": "kafka_rack_aware"})
+    reading, limit = result["compared"]["onto_old_broker"]
+    assert limit == 0 and reading >= 0
+    assert result["correct"] is all(
+        v <= lim for v, lim in result["compared"].values())
