@@ -27,7 +27,7 @@ from ..model.tensors import (
     broker_segments, flatten_slots, is_leader_slot, replica_exists,
     slot_coords,
 )
-from .derived import DerivedState
+from .derived import DerivedState, dest_columns_ok, broker_masks_at
 
 KIND_MOVE = 0
 KIND_LEADERSHIP = 1
@@ -297,7 +297,11 @@ def compute_deltas(state: ClusterTensors, derived: DerivedState,
         assign_p, jnp.maximum(src_slot, 0)[:, None], axis=1)[:, 0] >= 0)
     dst_in_range = (dst_broker >= 0) & (dst_broker < b)
     dst_safe = jnp.clip(dst_broker, 0, b - 1)
-    dst_alive = derived.alive[dst_safe] & dst_in_range
+    src_safe = jnp.clip(src_broker, 0, b - 1)
+    src_offline = ~derived.alive[src_safe]
+    dst_alive, dst_may_lead, dst_may_receive = broker_masks_at(
+        derived, dst_safe, src_offline)
+    dst_alive &= dst_in_range
 
     # Destination must not already host the partition (moves only);
     # comparing against all S slots of the partition.
@@ -309,18 +313,15 @@ def compute_deltas(state: ClusterTensors, derived: DerivedState,
     # replica.isLeader()). Offline replicas are exempt — self-healing
     # placement must proceed even onto leadership-excluded brokers
     # (eligibleReplicasForSwap's !isOriginalOffline carve-out).
-    src_safe = jnp.clip(src_broker, 0, b - 1)
-    src_offline = ~derived.alive[src_safe]
-    lead_dst_ok = (~moving_is_leader) | src_offline \
-        | derived.allowed_leadership[dst_safe]
-    move_ok = (~already_hosts) & derived.allowed_replica_move[dst_safe] \
+    lead_dst_ok = (~moving_is_leader) | src_offline | dst_may_lead
+    move_ok = (~already_hosts) & dst_may_receive \
         & (src_broker != dst_broker) & lead_dst_ok
     # Leadership: destination slot must hold a live replica on an
     # allowed-for-leadership broker, and differ from the current leader.
     dst_slot_live = jnp.take_along_axis(
         assign_p, jnp.maximum(cand.dst_slot, 0)[:, None], axis=1)[:, 0] >= 0
     lead_ok = dst_slot_live & (cand.dst_slot != leader_slot_p) & (cand.dst_slot >= 0) \
-        & derived.allowed_leadership[dst_safe] & (leader_slot_p >= 0)
+        & dst_may_lead & (leader_slot_p >= 0)
 
     valid = cand.valid & derived.movable_partition[p] & src_exists & dst_alive \
         & jnp.where(is_move, move_ok, lead_ok)
@@ -461,7 +462,9 @@ def generate_candidates(state: ClusterTensors, derived: DerivedState,
 
     - ``source_score[B]``: how much each broker needs to shed (>0 = source).
     - ``dest_score[B]``: how attractive each broker is as a destination
-      (-inf = not eligible).
+      (-inf = not eligible). The move block's columns are taken among
+      ``derived.dest_columns_ok`` alone, whatever the goal: with NEW
+      brokers present the columns ARE the new brokers.
     - ``replica_weight[P, S]``: which replicas are worth moving (higher =
       try first; the per-goal analogue of SortedReplicas score functions).
     - ``extra_dst``: optional (dst [k_src], ok [k_src]) per-card TARGETED
@@ -494,7 +497,8 @@ def generate_candidates(state: ClusterTensors, derived: DerivedState,
     parts: list[Candidates] = []
     if not leadership_only:
         k_dst = min(num_dests, b)
-        _dst_score, dst_idx = jax.lax.top_k(dest_score, k_dst)
+        _dst_score, dst_idx = jax.lax.top_k(
+            jnp.where(dest_columns_ok(derived), dest_score, -jnp.inf), k_dst)
         dst_valid = jnp.isfinite(_dst_score)
         cols_dst = jnp.broadcast_to(dst_idx.astype(jnp.int32)[None, :],
                                     (k_src, k_dst))

@@ -24,7 +24,7 @@ from .constraint import BalancingConstraint
 @partial(jax.tree_util.register_dataclass,
          data_fields=["broker_load", "broker_replicas", "broker_leaders",
                       "pot_nw_out", "alive", "new_brokers", "allowed_replica_move",
-                      "allowed_leadership", "avg_util", "avg_replicas",
+                      "replica_dest_ok", "allowed_leadership", "avg_util", "avg_replicas",
                       "avg_leaders", "movable_partition"],
          meta_fields=[])
 @dataclasses.dataclass(frozen=True)
@@ -36,6 +36,7 @@ class DerivedState:
     alive: jax.Array              # [B] bool
     new_brokers: jax.Array        # [B] bool
     allowed_replica_move: jax.Array  # [B] bool (alive & not excluded as dest)
+    replica_dest_ok: jax.Array    # [B] bool (may RECEIVE a replica; below)
     allowed_leadership: jax.Array    # [B] bool
     avg_util: jax.Array           # [R] — Σload / Σcapacity over allowed brokers
     avg_replicas: jax.Array       # scalar f32 over alive brokers
@@ -77,6 +78,15 @@ def compute_derived(state: ClusterTensors,
                if excluded_leadership_brokers is None else excluded_leadership_brokers)
     allowed_rm = alive & ~excl_rm
     allowed_ld = alive & ~excl_ld
+    # The scale-out's rule, written once (docs/DESIGN.md "The scale-out's
+    # rule"): while a broker is NEW, replicas move only onto NEW brokers,
+    # never among the old ones (upstream's REST documentation of POST
+    # /add_broker: "the replicas are only moved from the existing brokers
+    # to the new brokers, not among existing brokers"; SURVEY.md Appendix
+    # A.2 item 6). With no NEW broker the field IS allowed_replica_move.
+    # Whatever picks or accepts a replica's destination reads this field;
+    # the one exemption, an offline replica's, is ``broker_masks_at``'s.
+    dest_ok = allowed_rm & (new_b | ~new_b.any())
 
     # avgUtilizationPercentage = Σ load / Σ capacity over brokers allowed
     # replica moves (ResourceDistributionGoal.java:245-248).
@@ -96,10 +106,46 @@ def compute_derived(state: ClusterTensors,
     return DerivedState(
         broker_load=load, broker_replicas=reps, broker_leaders=leads,
         pot_nw_out=pot, alive=alive, new_brokers=new_b,
-        allowed_replica_move=allowed_rm, allowed_leadership=allowed_ld,
+        allowed_replica_move=allowed_rm, replica_dest_ok=dest_ok,
+        allowed_leadership=allowed_ld,
         avg_util=avg_util, avg_replicas=avg_reps, avg_leaders=avg_leads,
         movable_partition=movable,
     )
+
+
+def dest_columns_ok(derived: DerivedState) -> jax.Array:
+    """[B] bool: the brokers a round may offer as destination COLUMNS:
+    ``replica_dest_ok``, widened to every broker allowed replica moves
+    while a replica is offline (a dead broker still hosts one), so that
+    self-healing is not held up by a scale-out. Which candidates of a
+    widened column are legitimate is still ``broker_masks_at``'s to say."""
+    healing = ((derived.broker_replicas > 0) & ~derived.alive).any()
+    return derived.replica_dest_ok | (healing & derived.allowed_replica_move)
+
+
+def broker_masks_at(derived: DerivedState, dst: jax.Array,
+                    src_offline: jax.Array,
+                    ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The per-candidate lookup of the per-broker masks, for ``dst`` [N]
+    (in-range destination broker indices): ([N] alive, [N] allowed
+    leadership, [N] may RECEIVE the candidate's replica). ONE lookup a
+    candidate: the four per-broker masks ride one table of bits (a gather
+    on the chip costs by the element it produces, and with no broker
+    excluded XLA folded the separate lookups into one anyway; PERF.md,
+    PR 32).
+
+    An online replica is received only where ``replica_dest_ok``; an
+    OFFLINE one (``src_offline``: its broker is dead) on any broker
+    allowed replica moves, NEW brokers or not (assumed from upstream's
+    ``GoalUtils.eligibleBrokers``, which the reference tree on this
+    machine does not hold; upstream also lets a replica return to its
+    original broker, which the tensors do not record: docs/DESIGN.md)."""
+    bits = (derived.alive, derived.allowed_leadership,
+            derived.replica_dest_ok, derived.allowed_replica_move)
+    table = sum(mask.astype(jnp.int8) << i for i, mask in enumerate(bits))
+    alive, may_lead, online_ok, offline_ok = (
+        (table[dst] >> i) & 1 == 1 for i in range(len(bits)))
+    return alive, may_lead, jnp.where(src_offline, offline_ok, online_ok)
 
 
 def resource_limits(state: ClusterTensors, derived: DerivedState,

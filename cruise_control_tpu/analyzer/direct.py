@@ -588,9 +588,7 @@ def _direct_sweep(state: ClusterTensors, goals: tuple[Goal, ...], index: int,
     stride = int(rank_stride)
 
     alive = derived.alive
-    has_new = derived.new_brokers.any()
-    elig_dst = jnp.where(has_new, derived.new_brokers,
-                         derived.allowed_replica_move) & alive
+    elig_dst = derived.replica_dest_ok
     cnt = counts.astype(f32)
 
     # --- target distribution: integral surplus / deficit / headroom ------

@@ -275,12 +275,3 @@ def donor_widened_shed(values: jax.Array, lower, upper,
     over = jnp.maximum(values - upper, 0.0)
     donor = jnp.where(under_any, jnp.maximum(values - lower, 0.0), 0.0)
     return jnp.where(derived.alive, over + donor, 0.0)
-
-
-def new_broker_gate(derived: DerivedState, deltas: CandidateDeltas) -> jax.Array:
-    """When NEW brokers exist, only they may receive replicas
-    (ResourceDistributionGoal.rebalanceByMovingLoadIn:444-447)."""
-    has_new = derived.new_brokers.any()
-    dst_is_new = deltas.at_dst(derived.new_brokers)
-    is_move = deltas.replica_delta > 0
-    return jnp.where(has_new & is_move, dst_is_new, True)

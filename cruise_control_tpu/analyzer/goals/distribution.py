@@ -26,20 +26,11 @@ from ..derived import count_limits, resource_limits
 from ..fill import (
     best_fit_dests, deficit_fill_dests, exclusive_rank, rank_within_group,
 )
-from .base import Goal, donor_widened_shed, new_broker_gate, pair_improvement
+from .base import Goal, donor_widened_shed, pair_improvement
 
 
 def _band_viol(value, lower, upper):
     return jnp.maximum(value - upper, 0.0) + jnp.maximum(lower - value, 0.0)
-
-
-def _dest_eligible(derived):
-    """Destination eligibility shared by dest_score and the targeted-dest
-    kernels (new-broker gating per
-    ResourceDistributionGoal.rebalanceByMovingLoadIn:444-447)."""
-    has_new = derived.new_brokers.any()
-    return jnp.where(has_new, derived.new_brokers,
-                     derived.allowed_replica_move) & derived.alive
 
 
 def _int_deficit_headroom(counts, lower, upper):
@@ -138,8 +129,7 @@ class ResourceDistributionGoal(Goal):
         gap_after = gap_before - 2 * d
         var_gain = (gap_before ** 2 - gap_after ** 2) * 1e-6
         return jnp.where(deltas.valid,
-                         imp + jnp.where(imp > 0, var_gain, 0.0), -jnp.inf) \
-            * new_broker_gate(derived, deltas)
+                         imp + jnp.where(imp > 0, var_gain, 0.0), -jnp.inf)
 
     def source_score(self, state, derived, constraint, aux):
         r = int(self.resource)
@@ -159,9 +149,8 @@ class ResourceDistributionGoal(Goal):
         load = derived.broker_load[:, r]
         headroom = upper - load
         under_bonus = jnp.maximum(lower - load, 0.0) * 10.0
-        has_new = derived.new_brokers.any()
-        eligible = jnp.where(has_new, derived.new_brokers, derived.allowed_replica_move)
-        return jnp.where(eligible & (headroom > 0), headroom + under_bonus, -jnp.inf)
+        return jnp.where(derived.allowed_replica_move & (headroom > 0),
+                         headroom + under_bonus, -jnp.inf)
 
     def replica_weight(self, state, derived, constraint, aux):
         # TWO-SIDED FIT-PRIORITY ordering (r5): replicas that can actually
@@ -181,7 +170,7 @@ class ResourceDistributionGoal(Goal):
         lower, upper, _cap = self._limits(state, derived, constraint)
         load = derived.broker_load[:, r]
         headroom = upper - load
-        elig = _dest_eligible(derived) & (headroom > 0)
+        elig = derived.replica_dest_ok & (headroom > 0)
         max_gap = jnp.max(jnp.where(elig, headroom, 0.0))
         b = state.num_brokers
         src_room = jnp.concatenate([load - lower, jnp.array([0.0])])[
@@ -204,7 +193,7 @@ class ResourceDistributionGoal(Goal):
         size = replica_load_column(state, r)[cand_p, cand_s]
         rank = exclusive_rank(src_valid) * rank_stride + rank_offset
         dst, ok = best_fit_dests(size, rank, headroom,
-                                 _dest_eligible(derived) & (headroom > 0))
+                                 derived.replica_dest_ok & (headroom > 0))
         return dst, ok & src_valid \
             & ~self._low_util(derived, constraint)
 
@@ -290,8 +279,7 @@ class CountDistributionGoal(Goal):
         # unconditional variance term would accept O(P) in-band churn.
         var_gain = (gap_before ** 2 - (gap_before - 2 * d) ** 2) * 1e-6
         return jnp.where(deltas.valid,
-                         imp + jnp.where(imp > 0, var_gain, 0.0), -jnp.inf) \
-            * new_broker_gate(derived, deltas)
+                         imp + jnp.where(imp > 0, var_gain, 0.0), -jnp.inf)
 
     def source_score(self, state, derived, constraint, aux):
         lower, upper = self._limits(derived, constraint)
@@ -302,9 +290,8 @@ class CountDistributionGoal(Goal):
         counts = self._counts(derived)
         headroom = upper - counts
         under_bonus = jnp.maximum(lower - counts, 0.0) * 10.0
-        has_new = derived.new_brokers.any()
-        eligible = jnp.where(has_new, derived.new_brokers, derived.allowed_replica_move)
-        return jnp.where(eligible & (headroom > 0), headroom + under_bonus, -jnp.inf)
+        return jnp.where(derived.allowed_replica_move & (headroom > 0),
+                         headroom + under_bonus, -jnp.inf)
 
     def replica_weight(self, state, derived, constraint, aux):
         w = -replica_load_total(state)  # light replicas first
@@ -326,7 +313,7 @@ class CountDistributionGoal(Goal):
         rank = exclusive_rank(src_valid) * rank_stride + rank_offset
         dst, ok = deficit_fill_dests(
             jnp.zeros_like(cand_p), rank, deficit,
-            headroom, _dest_eligible(derived))
+            headroom, derived.replica_dest_ok)
         return dst, ok & src_valid
 
     def direct_spec(self, state, derived, constraint, aux, num_topics):
@@ -415,8 +402,7 @@ class TopicReplicaDistributionGoal(Goal):
         # Band-fixing tiebreak only (see ResourceDistributionGoal).
         var_gain = ((src_cnt - dst_cnt) ** 2 - (src_cnt - dst_cnt - 2 * d) ** 2) * 1e-6
         return jnp.where(deltas.valid,
-                         imp + jnp.where(imp > 0, var_gain, 0.0), -jnp.inf) \
-            * new_broker_gate(derived, deltas)
+                         imp + jnp.where(imp > 0, var_gain, 0.0), -jnp.inf)
 
     def _over_donor(self, derived, aux):
         """[T, B] — per-(topic, broker) shed pressure with donor widening."""
@@ -429,9 +415,7 @@ class TopicReplicaDistributionGoal(Goal):
 
     def dest_score(self, state, derived, constraint, aux):
         headroom = jnp.maximum(aux["upper"][:, None] - aux["counts"], 0.0).sum(axis=0)
-        has_new = derived.new_brokers.any()
-        eligible = jnp.where(has_new, derived.new_brokers, derived.allowed_replica_move)
-        return jnp.where(eligible, headroom, -jnp.inf)
+        return jnp.where(derived.allowed_replica_move, headroom, -jnp.inf)
 
     def replica_weight(self, state, derived, constraint, aux):
         b = state.num_brokers
@@ -460,7 +444,7 @@ class TopicReplicaDistributionGoal(Goal):
         rank = rank_within_group(t, src_valid) * rank_stride + rank_offset
         dst, ok = deficit_fill_dests(t, rank,
                                      deficit, headroom,
-                                     _dest_eligible(derived))
+                                     derived.replica_dest_ok)
         return dst, ok & src_valid
 
     def direct_spec(self, state, derived, constraint, aux, num_topics):

@@ -18,6 +18,7 @@ from typing import Callable, Sequence
 import jax.numpy as jnp
 import numpy as np
 
+from ..common.broker_state import BrokerState
 from ..config.abstract_config import resolve_class
 from ..config.cruise_control_config import CruiseControlConfig
 from ..model.stats import ClusterModelStats, cluster_stats
@@ -26,7 +27,9 @@ from .chain import optimize_chain, optimize_goal_in_chain
 from .constraint import BalancingConstraint, OptimizationOptions
 from .goals import ALL_GOALS
 from .goals.base import Goal
-from .proposals import ExecutionProposal, diff_proposals
+from .proposals import (
+    ExecutionProposal, FetchedDiff, compare_diff, fetch_diff,
+)
 from .search import ExclusionMasks, OptimizationFailureError, SearchConfig
 
 LOG = logging.getLogger(__name__)
@@ -193,6 +196,57 @@ def ensure_evacuated(goal_chain: Sequence[Goal], infos: Sequence[dict],
         f"{remaining} of {before} offline replicas could not be moved off "
         f"dead or removed brokers {stuck}: no eligible destination under "
         "the hard goals")
+
+
+def ensure_only_new_brokers_receive(fetched: FetchedDiff,
+                                    infos: Sequence[dict], meta: ClusterMeta,
+                                    span=None) -> None:
+    """The scale-out's guarantee, checked once a pass beside the drain's
+    (``ensure_evacuated``), on the arrays the proposal diff has already
+    fetched: one numpy pass, no device read of its own. A pass that ran
+    with a NEW broker and whose plan places a replica on a broker that is
+    neither NEW nor a replica of that partition before the plan has broken
+    what ``POST /add_broker`` documents (``derived.replica_dest_ok`` names
+    the source); it raises, and no partial plan is returned. A replica that
+    was OFFLINE (its slot sat on a DEAD broker) is exempt, as it is in the
+    search (``derived.broker_masks_at``). 0 by construction: this is the
+    program's own witness, not a filter.
+
+    Also the pass's scale-out accounting, only when a NEW broker exists:
+    ``solver_scale_out_replicas_total{onto="new"|"old"}`` (replicas the
+    plan places on NEW brokers / on others, the exempt ones among them),
+    ``solver_scale_out_rounds_total`` (the rounds of the goals run with a
+    NEW broker present), and ``new_brokers``, ``placed_on_new``,
+    ``placed_on_old`` on the pass's ``solver.dispatch`` spans under
+    ``span``."""
+    new = fetched.broker_state == int(BrokerState.NEW)
+    if not new.any():
+        return
+    from ..utils.sensors import SENSORS
+    a0, a1 = fetched.a0, fetched.a1
+    placed = (a1 >= 0) & fetched.mask[:, None] \
+        & ~(a1[:, :, None] == a0[:, None, :]).any(axis=2)
+    on_new = placed & new[np.maximum(a1, 0)]
+    on_old = placed & ~on_new
+    # a move keeps its slot, so the slot's broker before the plan is the
+    # replica's: offline if that broker was DEAD
+    was_offline = (a0 >= 0) & (fetched.broker_state[np.maximum(a0, 0)]
+                               == int(BrokerState.DEAD))
+    breach = on_old & ~was_offline
+    n_new, n_old = int(on_new.sum()), int(on_old.sum())
+    SENSORS.count("solver_scale_out_replicas", n_new, labels={"onto": "new"})
+    SENSORS.count("solver_scale_out_replicas", n_old, labels={"onto": "old"})
+    SENSORS.count("solver_scale_out_rounds",
+                  sum(info["rounds"] for info in infos))
+    for dispatch in _dispatch_spans(span):
+        dispatch.set(new_brokers=int(new.sum()), placed_on_new=n_new,
+                     placed_on_old=n_old)
+    if breach.any():
+        onto = sorted(meta.broker_ids[b] for b in np.unique(a1[breach]))
+        raise OptimizationFailureError(
+            f"{int(breach.sum())} replicas placed on brokers {onto} that "
+            "are not new while new brokers exist: add_broker moves "
+            "replicas only from the existing brokers onto the new ones")
 
 
 # Goals whose direct-transport arm stays ahead of greedy even at sparse
@@ -907,7 +961,9 @@ class GoalOptimizer:
         with TRACER.span("analyzer.proposal_diff") as dsp:
             with TRACER.span("diff.stats"):
                 stats_after = cluster_stats(state)
-            proposals = diff_proposals(initial, state, meta)
+            fetched = fetch_diff(initial, state)
+            ensure_only_new_brokers_receive(fetched, infos, meta, _opt_span)
+            proposals = compare_diff(fetched, meta)
             dsp.set(num_proposals=len(proposals))
         _opt_span.set(num_proposals=len(proposals),
                       violated_goals_after=",".join(violated_after),
@@ -1267,8 +1323,16 @@ class GoalOptimizer:
                               if not r.succeeded]
             with cluster_label(cid) if cid is not None \
                     else contextlib.nullcontext():
-                proposals = diff_proposals(initial_states[b], final,
-                                           metas[b])
+                fetched = fetch_diff(initial_states[b], final)
+                try:
+                    ensure_only_new_brokers_receive(
+                        fetched, [infos[b] for infos in results_per_goal],
+                        metas[b])
+                except OptimizationFailureError as e:
+                    _count_optimization_failure()
+                    out.append(e)
+                    continue
+                proposals = compare_diff(fetched, metas[b])
                 result = OptimizerResult(
                     proposals=proposals, goal_results=goal_results,
                     stats_before=stats_before[b],

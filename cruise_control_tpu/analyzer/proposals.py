@@ -79,27 +79,52 @@ def _ordered_replicas(assignment_row: np.ndarray, leader_slot: int,
     return ids, leader_id
 
 
-def diff_proposals(initial: ClusterTensors, final: ClusterTensors,
-                   meta: ClusterMeta) -> list[ExecutionProposal]:
-    """Set of ExecutionProposals for partitions whose replica set, order, or
-    leader changed (AnalyzerUtils.getDiff)."""
+@dataclasses.dataclass(frozen=True)
+class FetchedDiff:
+    """What ``fetch_diff`` brought to the host: the initial and the final
+    placement, and the little of the initial model that reading a plan off
+    them takes."""
+
+    a0: np.ndarray            # [P, S] initial assignment (broker indices)
+    a1: np.ndarray            # [P, S] final assignment
+    l0: np.ndarray            # [P] initial leader slot
+    l1: np.ndarray            # [P] final leader slot
+    mask: np.ndarray          # [P] bool real partitions
+    disk_mb: np.ndarray       # [P] leader disk load
+    broker_state: np.ndarray  # [B] int8 BrokerState codes of the initial
+
+
+def fetch_diff(initial: ClusterTensors, final: ClusterTensors) -> FetchedDiff:
+    """The one device read of the proposal diff (span ``diff.fetch``)."""
     from ..common.resources import Resource
     from ..utils.tracing import TRACER
     from ..utils.xla_telemetry import record_transfer
 
     with TRACER.span("diff.fetch"):
-        a0 = np.asarray(initial.assignment)
-        a1 = np.asarray(final.assignment)
-        l0 = np.asarray(initial.leader_slot)
-        l1 = np.asarray(final.leader_slot)
-        mask = np.asarray(initial.partition_mask)
-        disk_mb = np.asarray(initial.leader_load[:, int(Resource.DISK)])
-        record_transfer(sum(x.nbytes for x in (a0, a1, l0, l1, mask,
-                                               disk_mb)),
+        fetched = FetchedDiff(
+            a0=np.asarray(initial.assignment),
+            a1=np.asarray(final.assignment),
+            l0=np.asarray(initial.leader_slot),
+            l1=np.asarray(final.leader_slot),
+            mask=np.asarray(initial.partition_mask),
+            disk_mb=np.asarray(initial.leader_load[:, int(Resource.DISK)]),
+            broker_state=np.asarray(initial.broker_state))
+        record_transfer(sum(x.nbytes for x in vars(fetched).values()),
                         direction="d2h", source="proposal_diff")
+    return fetched
 
+
+def compare_diff(fetched: FetchedDiff,
+                 meta: ClusterMeta) -> list[ExecutionProposal]:
+    """Set of ExecutionProposals for partitions whose replica set, order, or
+    leader changed (AnalyzerUtils.getDiff), from the fetched arrays (span
+    ``diff.compare``)."""
+    from ..utils.tracing import TRACER
+
+    a0, a1, l0, l1 = fetched.a0, fetched.a1, fetched.l0, fetched.l1
+    disk_mb = fetched.disk_mb
     with TRACER.span("diff.compare"):
-        changed = ((a0 != a1).any(axis=1) | (l0 != l1)) & mask
+        changed = ((a0 != a1).any(axis=1) | (l0 != l1)) & fetched.mask
         proposals: list[ExecutionProposal] = []
         for p in np.nonzero(changed)[0]:
             old_reps, old_leader = _ordered_replicas(a0[p], int(l0[p]),
@@ -114,3 +139,9 @@ def diff_proposals(initial: ClusterTensors, final: ClusterTensors,
                 old_replicas=old_reps, new_replicas=new_reps,
                 new_leader=new_leader, data_to_move_mb=float(disk_mb[p])))
     return proposals
+
+
+def diff_proposals(initial: ClusterTensors, final: ClusterTensors,
+                   meta: ClusterMeta) -> list[ExecutionProposal]:
+    """``compare_diff`` of ``fetch_diff``."""
+    return compare_diff(fetch_diff(initial, final), meta)

@@ -370,6 +370,24 @@ def _per_broker_top_replicas(state: ClusterTensors, weight: jax.Array,
     return jax.vmap(one)(brokers)
 
 
+def swap_brokers(derived: DerivedState, src_score: jax.Array,
+                 dst_score: jax.Array, k: int):
+    """The ``k`` overloaded brokers and the ``k`` counterparties of a swap
+    round: (src_brokers, src_ok, dst_brokers, dst_ok). A swap places a
+    replica on BOTH ends, so both are taken among
+    ``derived.replica_dest_ok`` alone: with a NEW broker present swaps run
+    between new brokers only (the scale-out's rule), and a broker excluded
+    from replica moves is on neither side. Swap sources are never offline:
+    no exemption here. Shared by ``swap_grid`` and the mesh's swap body."""
+    ok = derived.replica_dest_ok
+    src_vals, src_brokers = jax.lax.top_k(
+        jnp.where(ok & (src_score > 0), src_score, -jnp.inf), k)
+    dst_vals, dst_brokers = jax.lax.top_k(
+        jnp.where(ok, dst_score, -jnp.inf), k)
+    return (src_brokers, jnp.isfinite(src_vals),
+            dst_brokers, jnp.isfinite(dst_vals))
+
+
 def swap_grid(state: ClusterTensors, derived: DerivedState,
               src_score: jax.Array, dst_score: jax.Array, weight: jax.Array,
               k_brokers: int = 8, j_replicas: int = 4):
@@ -386,11 +404,8 @@ def swap_grid(state: ClusterTensors, derived: DerivedState,
     from .candidates import CandidateDeltas
 
     k = min(k_brokers, state.num_brokers)
-    src_vals, src_brokers = jax.lax.top_k(
-        jnp.where(src_score > 0, src_score, -jnp.inf), k)
-    dst_vals, dst_brokers = jax.lax.top_k(dst_score, k)
-    src_b_ok = jnp.isfinite(src_vals)
-    dst_b_ok = jnp.isfinite(dst_vals)
+    src_brokers, src_b_ok, dst_brokers, dst_b_ok = swap_brokers(
+        derived, src_score, dst_score, k)
 
     heavy_idx, heavy_ok = _per_broker_top_replicas(
         state, weight, src_brokers, j_replicas, largest=True)    # [K, j]
@@ -413,9 +428,7 @@ def swap_grid(state: ClusterTensors, derived: DerivedState,
 
     base_valid = src_b_ok[si] & dst_b_ok[di] & heavy_ok[si, ai] \
         & light_ok[di, bi] & (src_b != dst_b) \
-        & derived.movable_partition[p1] & derived.movable_partition[p2] \
-        & derived.allowed_replica_move[dst_b] \
-        & derived.allowed_replica_move[src_b]
+        & derived.movable_partition[p1] & derived.movable_partition[p2]
     # Distinct partitions, cross-hosting checks.
     base_valid &= p1 != p2
     base_valid &= ~(state.assignment[p1] == dst_b[:, None]).any(axis=1)

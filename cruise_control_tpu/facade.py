@@ -1459,8 +1459,11 @@ class CruiseControl:
                     data_from: str | None = None,
                     allow_capacity_estimation: bool = True,
                     reason: str = "", uuid: str = "") -> OperationResult:
-        """AddBrokersRunnable — mark NEW; the new-broker gate routes load
-        onto them (ResourceDistributionGoal.rebalanceByMovingLoadIn:444)."""
+        """AddBrokersRunnable — mark NEW and run the chain: while a broker
+        is NEW, replicas move only onto NEW brokers, in every goal, swap
+        and transport (``analyzer.derived.DerivedState.replica_dest_ok``,
+        docs/DESIGN.md "The scale-out's rule"); a plan that breaks this
+        raises (``optimizer.ensure_only_new_brokers_receive``)."""
         chain, state, meta = self._chain_and_model(
             goals, use_ready_default_goals, data_from,
             allow_capacity_estimation)

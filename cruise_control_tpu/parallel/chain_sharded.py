@@ -48,7 +48,7 @@ from ..analyzer.direct import (
 from ..analyzer.search import (
     _EPS_IMPROVEMENT, ExclusionMasks, SearchConfig,
     _per_broker_top_replicas, apply_selected, reduce_per_source,
-    run_carry_loop,
+    run_carry_loop, swap_brokers,
 )
 from ..common.resources import Resource
 from ..model.tensors import ClusterTensors, offline_replicas, slot_coords
@@ -216,11 +216,8 @@ def _chain_swap_local(state: ClusterTensors, agg, masks: ExclusionMasks,
                                         derived, constraint)
 
     k = min(k_brokers, b)
-    src_vals, src_brokers = jax.lax.top_k(
-        jnp.where(src_score > 0, src_score, -jnp.inf), k)
-    dst_vals, dst_brokers = jax.lax.top_k(dst_score, k)
-    src_b_ok = jnp.isfinite(src_vals)
-    dst_b_ok = jnp.isfinite(dst_vals)
+    src_brokers, src_b_ok, dst_brokers, dst_b_ok = swap_brokers(
+        derived, src_score, dst_score, k)
 
     heavy_idx, heavy_ok = _per_broker_top_replicas(
         state, weight, src_brokers, j, largest=True)

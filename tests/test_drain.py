@@ -90,6 +90,8 @@ def facade(dep, route):
         states.append(PartitionState(topic, part, tuple(reps), reps[0],
                                      isr=tuple(reps)))
     backend = InMemoryAdminBackend(states)
+    for b in range(dep.brokers):    # a new broker hosts nothing yet
+        backend.revive_broker(b)
     monitor = LoadMonitor(
         config, backend, samplers=[DeploymentSampler(dep)],
         capacity_resolver=StaticCapacityResolver({}, {

@@ -115,6 +115,8 @@ def test_add_brokers_routes_load_to_new_broker():
     res = cc.add_brokers([4], dryrun=True)
     gained = [pr for pr in res.proposals if 4 in pr.new_replicas]
     assert gained, "new broker must receive replicas"
+    assert all(set(pr.replicas_to_add) <= {4} for pr in res.proposals), \
+        "no old broker may gain a replica"
 
 
 def test_demote_brokers_sheds_leadership_only():

@@ -41,14 +41,14 @@ from cruise_control_tpu.model.tensors import (
 B, K_SRC, K_DST = 16, 24, 5
 
 
-def _cluster():
+def _cluster(new=(14, 15)):
     """Offline replicas (a dead broker), new brokers, and below an
     excluded broker: every mask the goals read per broker is mixed."""
     state, meta = random_cluster(num_brokers=B, num_topics=5,
                                  num_partitions=120, rf=3, num_racks=4,
                                  seed=7, skew_to_first=2.0)
     state = set_broker_state(state, jnp.asarray([2]), BrokerState.DEAD)
-    state = set_broker_state(state, jnp.asarray([14, 15]), BrokerState.NEW)
+    state = set_broker_state(state, jnp.asarray(new), BrokerState.NEW)
     assert int(offline_replicas(state).sum()) > 0
     return state, meta
 
@@ -193,8 +193,12 @@ def _default_chain():
 
 def test_whole_chain_pass_same_with_layout_passed_and_withheld(monkeypatch):
     """One ``optimize_chain`` pass over the default chain: assignment,
-    leader slots, rounds and per-goal stats do not depend on the form."""
-    state, meta = _cluster()
+    leader slots, rounds and per-goal stats do not depend on the form.
+    A new broker on each of the four racks: while a broker is NEW replicas
+    move only onto NEW brokers (``derived.replica_dest_ok``), and the
+    cluster is drawn without regard to racks, so two would leave
+    RackAwareGoal a partition it cannot repair."""
+    state, meta = _cluster(new=(12, 13, 14, 15))
     goals = _default_chain()
     assert len(goals) == 15
     args = (state, goals, BalancingConstraint(), CHAIN_CFG, meta.num_topics,

@@ -95,21 +95,30 @@ def test_random_self_healing_drains_dead_brokers(dist):
     _assert_consistent(final, meta)
 
 
-def test_random_new_broker_gating():
+@pytest.mark.parametrize("chain,new", [
+    (["ReplicaDistributionGoal", "NetworkOutboundUsageDistributionGoal"],
+     [14, 15]),
+    # the cluster is drawn without regard to racks, so RackAwareGoal has
+    # replicas to move: one new broker on each of the four racks offers
+    # every partition a rack it does not use (two would leave the hard
+    # goal unsatisfiable under the rule, as upstream's does)
+    (None, [12, 13, 14, 15])], ids=["two-goals", "default-chain"])
+def test_random_new_broker_gating(chain, new):
     """NEW_BROKERS verification (RandomClusterNewBrokerTest): brokers in NEW
-    state are the only ones gaining replicas during distribution passes."""
+    state are the only ones gaining replicas, under two distribution goals
+    and under the full default chain (hard goals, PotentialNwOutGoal and
+    the swap rounds included: ``derived.replica_dest_ok``)."""
     state, meta = _cluster(Dist.LINEAR, seed=11)
-    new = [14, 15]
     state = set_broker_state(state, jnp.asarray(new), BrokerState.NEW)
-    before = np.asarray(broker_replica_counts(state))
+    before = np.asarray(state.assignment)
     opt = GoalOptimizer(CFG)
     final, _res = opt.optimizations(
-        state, meta, goals=goals_by_priority(
-            CFG, ["ReplicaDistributionGoal",
-                  "NetworkOutboundUsageDistributionGoal"]))
-    after = np.asarray(broker_replica_counts(final))
-    gained = np.nonzero(after > before)[0]
-    assert set(gained.tolist()) <= set(new), gained
+        state, meta, goals=goals_by_priority(CFG, chain))
+    after = np.asarray(final.assignment)
+    placed = (after >= 0) \
+        & ~(after[:, :, None] == before[:, None, :]).any(axis=2)
+    assert placed.any()
+    assert set(np.unique(after[placed]).tolist()) <= set(new)
 
 
 def test_random_excluded_brokers_for_replica_move_gain_nothing():
