@@ -28,7 +28,8 @@ from .constraint import BalancingConstraint, OptimizationOptions
 from .goals import ALL_GOALS
 from .goals.base import Goal
 from .proposals import (
-    ExecutionProposal, FetchedDiff, compare_diff, fetch_diff,
+    ExecutionProposal, FetchedDiff, compare_diff, count_leadership_only,
+    fetch_diff,
 )
 from .search import ExclusionMasks, OptimizationFailureError, SearchConfig
 
@@ -55,7 +56,7 @@ class GoalResult:
 
 @dataclasses.dataclass
 class OptimizerResult:
-    proposals: list[ExecutionProposal]
+    proposals: Sequence[ExecutionProposal]
     goal_results: list[GoalResult]
     stats_before: ClusterModelStats
     stats_after: ClusterModelStats
@@ -68,7 +69,7 @@ class OptimizerResult:
     def summary(self) -> dict:
         return {
             "num_proposals": len(self.proposals),
-            "num_leadership_only": sum(p.is_leadership_only for p in self.proposals),
+            "num_leadership_only": count_leadership_only(self.proposals),
             "violated_goals_before": self.violated_goals_before,
             "violated_goals_after": self.violated_goals_after,
             "balancedness_before": round(self.balancedness_before, 3),

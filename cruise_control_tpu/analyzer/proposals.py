@@ -4,12 +4,17 @@ Reference parity: AnalyzerUtils.getDiff:47-130 + ExecutionProposal.java —
 proposals are NOT accumulated during search; they are the diff between the
 initial and final (replica list, leader) state, so transient intra-search
 shuffles cost nothing (SURVEY.md §A.5). The tensor model gets this for free
-by comparing assignment/leader arrays.
+by comparing assignment/leader arrays, and the comparison is one of arrays
+end to end: ``compare_diff`` orders, maps and filters the changed rows in
+numpy and returns them as ``ProposalColumns``, a
+``Sequence[ExecutionProposal]`` that builds an object only for a reader
+that asks for one (the executor; not the served dry-run body).
 """
 
 from __future__ import annotations
 
 import dataclasses
+from collections.abc import Iterator, Sequence
 
 import numpy as np
 
@@ -58,25 +63,112 @@ class ExecutionProposal:
         return tuple(sorted(set(self.old_replicas) - set(self.new_replicas)))
 
 
-def _ordered_replicas(assignment_row: np.ndarray, leader_slot: int,
-                      broker_ids: list[int]) -> tuple[tuple[int, ...], int]:
-    """Replica broker ids with the leader first (ExecutionProposal
-    convention), -1-padded slots dropped."""
-    slots = [s for s, b in enumerate(assignment_row) if b >= 0]
-    if not slots:
-        return (), -1
-    leader_b = int(assignment_row[leader_slot]) if 0 <= leader_slot < len(assignment_row) \
-        and assignment_row[leader_slot] >= 0 else -1
-    ordered = []
-    if leader_b >= 0:
-        ordered.append(leader_b)
-    for s in slots:
-        b = int(assignment_row[s])
-        if b != leader_b:
-            ordered.append(b)
-    ids = tuple(broker_ids[b] for b in ordered)
-    leader_id = broker_ids[leader_b] if leader_b >= 0 else -1
-    return ids, leader_id
+@dataclasses.dataclass(frozen=True, eq=False)
+class ProposalColumns(Sequence):
+    """A plan as columns, one row a changed partition in ascending partition
+    index: what ``compare_diff`` returns. It IS a
+    ``Sequence[ExecutionProposal]``: indexing and iteration build the
+    objects on demand (counter ``proposal_objects_materialized_total``), a
+    slice is columns again. The readers of the served dry-run path
+    (``count_leadership_only``, ``proposal_rows``) read the columns and
+    build none."""
+
+    partition_index: Sequence[tuple[str, int]]  # ClusterMeta's, not copied
+    rows: np.ndarray             # [n] partition indices
+    old_replicas: np.ndarray     # [n, S] broker ids leader-first, -1 pads right
+    new_replicas: np.ndarray     # [n, S]
+    old_leader: np.ndarray       # [n] broker id, -1 for none
+    new_leader: np.ndarray       # [n]
+    data_to_move_mb: np.ndarray  # [n]
+
+    def __len__(self) -> int:
+        return len(self.rows)
+
+    def __getitem__(self, i):
+        if not isinstance(i, slice):
+            i = range(len(self))[i]
+            return next(iter(self[i:i + 1]))
+        # Every field but the first is a column.
+        return dataclasses.replace(
+            self, **{f.name: getattr(self, f.name)[i]
+                     for f in dataclasses.fields(self)[1:]})
+
+    def __iter__(self) -> Iterator[ExecutionProposal]:
+        from ..utils.sensors import SENSORS
+        SENSORS.count("proposal_objects_materialized", len(self))
+        return iter([
+            ExecutionProposal(
+                topic=topic, partition=pnum, old_leader=old_leader,
+                old_replicas=tuple(old), new_replicas=tuple(new),
+                new_leader=new_leader, data_to_move_mb=mb)
+            for ((topic, pnum), old_leader, old, new, new_leader), mb
+            in zip(self.row_values(), self.data_to_move_mb.tolist())])
+
+    def row_values(self) -> Iterator[tuple]:
+        """((topic, partition), old leader, old replicas, new replicas, new
+        leader) a row, as Python values; the replicas are fresh lists."""
+        index = self.partition_index
+        return zip([index[p] for p in self.rows.tolist()],
+                   self.old_leader.tolist(), _unpadded(self.old_replicas),
+                   _unpadded(self.new_replicas), self.new_leader.tolist())
+
+
+def _unpadded(replicas: np.ndarray) -> list[list[int]]:
+    """The rows of a right-padded ``[n, S]`` id array as lists, the -1 pads
+    trimmed where a row has one."""
+    lists = replicas.tolist()
+    present = replicas >= 0
+    if not present.all():
+        lengths = present.sum(axis=1)
+        for i in np.nonzero(lengths < replicas.shape[1])[0].tolist():
+            del lists[i][lengths[i]:]
+    return lists
+
+
+def proposal_rows(proposals: Sequence[ExecutionProposal]) -> Iterator[tuple]:
+    """``ProposalColumns.row_values`` of any plan: columns are read as
+    columns, a plain list of objects through its attributes."""
+    if isinstance(proposals, ProposalColumns):
+        return proposals.row_values()
+    return (((p.topic, p.partition), p.old_leader, list(p.old_replicas),
+             list(p.new_replicas), p.new_leader) for p in proposals)
+
+
+def count_leadership_only(proposals: Sequence[ExecutionProposal]) -> int:
+    """Proposals with the same replica SET under another leader
+    (``ExecutionProposal.is_leadership_only``); columns all rows at once."""
+    if not isinstance(proposals, ProposalColumns):
+        return sum(p.is_leadership_only for p in proposals)
+    old, new = proposals.old_replicas, proposals.new_replicas
+    both = old[:, :, None] == new[:, None, :]
+    same_set = ((both.any(axis=2) | (old < 0)).all(axis=1)
+                & (both.any(axis=1) | (new < 0)).all(axis=1))
+    return int((same_set
+                & (proposals.old_leader != proposals.new_leader)).sum())
+
+
+def _leader_first(assignment: np.ndarray, leader_slot: np.ndarray,
+                  broker_ids: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """Replica broker ids ``[n, S]`` with the leader first
+    (ExecutionProposal convention) and the others in slot order, -1 slots
+    dropped (the pads end up on the right), and the leader's id ``[n]``. A
+    leader slot out of range or on a -1 slot gives leader -1 and plain slot
+    order; a second slot on the leader's broker is dropped."""
+    n, s = assignment.shape
+    slot_ok = (leader_slot >= 0) & (leader_slot < s)
+    leader = np.maximum(-1, np.where(
+        slot_ok, assignment[np.arange(n), np.where(slot_ok, leader_slot, 0)],
+        -1))
+    at_leader_slot = (np.arange(s) == leader_slot[:, None]) \
+        & (leader >= 0)[:, None]
+    dropped = (assignment < 0) \
+        | ((assignment == leader[:, None]) & ~at_leader_slot)
+    order = np.argsort(np.where(at_leader_slot, 0, 1 + dropped), axis=1,
+                       kind="stable")
+    ordered = np.take_along_axis(np.where(dropped, -1, assignment), order,
+                                 axis=1)
+    return (np.where(ordered >= 0, broker_ids[ordered], -1),
+            np.where(leader >= 0, broker_ids[leader], -1))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,34 +206,34 @@ def fetch_diff(initial: ClusterTensors, final: ClusterTensors) -> FetchedDiff:
     return fetched
 
 
-def compare_diff(fetched: FetchedDiff,
-                 meta: ClusterMeta) -> list[ExecutionProposal]:
-    """Set of ExecutionProposals for partitions whose replica set, order, or
-    leader changed (AnalyzerUtils.getDiff), from the fetched arrays (span
-    ``diff.compare``)."""
+def compare_diff(fetched: FetchedDiff, meta: ClusterMeta) -> ProposalColumns:
+    """The partitions whose replica set, order, or leader changed
+    (AnalyzerUtils.getDiff), from the fetched arrays, as columns (span
+    ``diff.compare``): a comparison of arrays end to end, no Python object
+    a move. Rows outside ``partition_mask`` never appear; a changed row
+    whose leader-first order and leader are what they were (the leader's
+    broker changed slots with slot 0's, say) is no proposal."""
     from ..utils.tracing import TRACER
 
-    a0, a1, l0, l1 = fetched.a0, fetched.a1, fetched.l0, fetched.l1
-    disk_mb = fetched.disk_mb
-    with TRACER.span("diff.compare"):
-        changed = ((a0 != a1).any(axis=1) | (l0 != l1)) & fetched.mask
-        proposals: list[ExecutionProposal] = []
-        for p in np.nonzero(changed)[0]:
-            old_reps, old_leader = _ordered_replicas(a0[p], int(l0[p]),
-                                                     meta.broker_ids)
-            new_reps, new_leader = _ordered_replicas(a1[p], int(l1[p]),
-                                                     meta.broker_ids)
-            if old_reps == new_reps and old_leader == new_leader:
-                continue
-            topic, pnum = meta.partition_index[p]
-            proposals.append(ExecutionProposal(
-                topic=topic, partition=pnum, old_leader=old_leader,
-                old_replicas=old_reps, new_replicas=new_reps,
-                new_leader=new_leader, data_to_move_mb=float(disk_mb[p])))
-    return proposals
+    with TRACER.span("diff.compare") as span:
+        changed = np.nonzero(((fetched.a0 != fetched.a1).any(axis=1)
+                              | (fetched.l0 != fetched.l1)) & fetched.mask)[0]
+        broker_ids = np.asarray(meta.broker_ids, dtype=np.int64)
+        old, old_leader = _leader_first(fetched.a0[changed],
+                                        fetched.l0[changed], broker_ids)
+        new, new_leader = _leader_first(fetched.a1[changed],
+                                        fetched.l1[changed], broker_ids)
+        differs = (old != new).any(axis=1) | (old_leader != new_leader)
+        rows = changed[differs]
+        span.set(changed_rows=len(changed), proposals=len(rows))
+        return ProposalColumns(
+            partition_index=meta.partition_index, rows=rows,
+            old_replicas=old[differs], new_replicas=new[differs],
+            old_leader=old_leader[differs], new_leader=new_leader[differs],
+            data_to_move_mb=fetched.disk_mb[rows])
 
 
 def diff_proposals(initial: ClusterTensors, final: ClusterTensors,
-                   meta: ClusterMeta) -> list[ExecutionProposal]:
+                   meta: ClusterMeta) -> ProposalColumns:
     """``compare_diff`` of ``fetch_diff``."""
     return compare_diff(fetch_diff(initial, final), meta)

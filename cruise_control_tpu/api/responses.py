@@ -12,6 +12,7 @@ from __future__ import annotations
 import numpy as np
 
 from ..analyzer.optimizer import OptimizerResult
+from ..analyzer.proposals import proposal_rows
 from ..common.resources import Resource
 from ..executor.admin import AdminBackend
 from ..facade import OperationResult
@@ -353,18 +354,19 @@ def optimization_result(op: OperationResult, verbose: bool = False) -> dict:
                 body["loadBeforeOptimization"] = _stats_dict(r.stats_before)
                 body["loadAfterOptimization"] = _stats_dict(r.stats_after)
     with jny.seg("proposal_diff") as seg:
-        proposals = list(op.proposals)
+        proposals = op.proposals
         body["numProposals"] = len(proposals)
         if not verbose and len(proposals) > _NON_VERBOSE_PROPOSAL_CAP:
             body["proposalsTruncated"] = True
             proposals = proposals[:_NON_VERBOSE_PROPOSAL_CAP]
         body["proposals"] = [
-            {"topicPartition": {"topic": p.topic, "partition": p.partition},
-             "oldLeader": p.old_leader,
-             "oldReplicas": list(p.old_replicas),
-             "newReplicas": list(p.new_replicas),
-             "newLeader": p.new_leader}
-            for p in proposals]
+            {"topicPartition": {"topic": topic, "partition": partition},
+             "oldLeader": old_leader,
+             "oldReplicas": old_replicas,
+             "newReplicas": new_replicas,
+             "newLeader": new_leader}
+            for (topic, partition), old_leader, old_replicas, new_replicas,
+            new_leader in proposal_rows(proposals)]
         seg.set(numProposals=len(proposals))
     body.update(op.extra)
     return envelope(body)
