@@ -52,7 +52,7 @@ from .search import (
     _EPS_IMPROVEMENT, _OFFLINE_BONUS, ExclusionMasks,
     OptimizationFailureError, SearchConfig, apply_selected,
     apply_swap_selection, cumulative_select, goal_aux, reduce_per_source,
-    run_carry_loop, swap_grid,
+    prior_card_dest_ok, run_carry_loop, swap_grid,
 )
 from ..utils.flight_recorder import NO_FLIGHT, STAT_WIDTH as _FLIGHT_STATS
 from ..utils.tracing import TRACER
@@ -190,6 +190,17 @@ def _switch_swap_dest_score(active_idx, goals, aux_list, state, derived,
         active_idx, goals,
         lambda g, i: g.swap_dest_score(state, derived, constraint,
                                        aux_list[i]).astype(jnp.float32))
+
+
+def _switch_swap_light_weight(active_idx, goals, aux_list, state, derived,
+                              constraint):
+    """[P, S] what a replica weighs in a swap under the active goal
+    (``Goal.swap_light_weight``; shared by the single-device and sharded
+    swap bodies, so the light side is ONE decision on every route)."""
+    return _switch_goal_fn(
+        active_idx, goals,
+        lambda g, i: g.swap_light_weight(state, derived, constraint,
+                                         aux_list[i]).astype(jnp.float32))
 
 
 def _switch_target_dests(active_idx, goals, aux_list, state, derived,
@@ -379,7 +390,7 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
                       prior_mask: jax.Array, goals: tuple[Goal, ...],
                       constraint: BalancingConstraint, cfg: SearchConfig,
                       num_topics: int, masks: ExclusionMasks,
-                      collect: bool = False,
+                      stats: "str | None" = None,
                       ) -> tuple[ClusterTensors, "AggCarry | None",
                                  jax.Array, "jax.Array | None"]:
     """One search round, chain-parameterized (traced body): the scoring
@@ -389,14 +400,17 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
     and returns it updated by the applied batch (None = recompute-per-round,
     kept for the oracle paths).
 
-    ``collect`` (trace-time) additionally returns a ``[STAT_WIDTH]`` f32
-    flight-stats row for this round (utils.flight_recorder.STAT_COLUMNS:
+    ``stats`` (trace-time): ``"row"`` additionally returns a
+    ``[STAT_WIDTH]`` f32 flight-stats row for this round (utils.flight_recorder.STAT_COLUMNS:
     applied / valid / accepted / positive / winners / active-goal
     violation) — pure REDUCTIONS over tensors the round already computes
     (the duplicated ``reduce_per_source`` is structurally identical to
     the one inside ``cumulative_select``, so XLA CSE collapses the two),
     never a new selection input: the trajectory is byte-identical with
     collection on or off (pinned in tests/test_flight_recorder.py).
+    ``"tally"`` (the whole-chain dispatch, which keeps no ring) returns in
+    its place the row's two sums that price the acceptance stack,
+    ``[valid, accepted]``: two reductions over the candidate axis.
 
     The selection's phases carry the scopes ``round.select``,
     ``round.apply`` and ``round.flight_stats`` (``round.agg_refresh`` in
@@ -432,8 +446,9 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
         deltas.dst_broker[top_idx], sc.cand.kind[top_idx],
         sc.cand.dst_slot[top_idx])
     applied = sel.sum()
+    assert stats in (None, "row", "tally"), stats
     stat = None
-    if collect:
+    if stats == "row":
         with jax.named_scope("round.flight_stats"):
             red_idx = reduce_per_source(score, sc.layout,
                                         extra_last_col=sc.targets)
@@ -452,6 +467,10 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
                 viol,
             ])
         assert stat.shape == (_FLIGHT_STATS,)
+    elif stats == "tally":
+        with jax.named_scope("round.flight_stats"):
+            stat = jnp.stack([deltas.valid.sum().astype(jnp.float32),
+                              sc.accept.sum().astype(jnp.float32)])
     return new_state, agg, applied, stat
 
 
@@ -486,7 +505,7 @@ def _chain_rounds_driver(state: ClusterTensors, active_idx: jax.Array,
         a = maybe_refresh(a, s, num_topics, rounds_done)
         ns, na, applied, stat = _chain_round_body(
             s, a, active_idx, prior_mask, goals, constraint, cfg,
-            num_topics, masks, collect=collect)
+            num_topics, masks, stats="row" if collect else None)
         if collect:
             ring = ring.at[rounds_done % ring_rounds].set(stat)
             return (ns, na, ring), applied
@@ -595,8 +614,12 @@ def _chain_swap_body(state: ClusterTensors, agg: "AggCarry | None",
     dst_score = _switch_swap_dest_score(active_idx, goals, aux_list, state,
                                         derived, constraint)
 
+    light_weight = _switch_swap_light_weight(active_idx, goals, aux_list,
+                                             state, derived, constraint)
+
     fwd, rev, net, p1, s1, p2, s2, src_b, dst_b, base_valid = swap_grid(
-        state, derived, src_score, dst_score, weight)
+        state, derived, src_score, dst_score, weight, light_weight,
+        partial(prior_card_dest_ok, goals, prior_mask, state))
 
     accept = base_valid
     for i, g in enumerate(goals):
@@ -827,7 +850,9 @@ def chain_optimize_full(state: ClusterTensors, goals: tuple[Goal, ...],
 
     Returns (final_state, per_goal_stats) where per_goal_stats is a dict of
     [G]-arrays: viol_before/after, obj_before/after, offline_before,
-    moves, swaps, rounds.
+    moves, swaps, rounds, and cand_valid / cand_accepted (f32: each goal's
+    move rounds' valid candidates, and those of them that every earlier
+    goal's acceptance let through; ``_chain_round_body`` ``stats="tally"``).
     """
     g_count = len(goals)
     supports_swap = jnp.asarray([g.supports_swap for g in goals])
@@ -852,11 +877,11 @@ def chain_optimize_full(state: ClusterTensors, goals: tuple[Goal, ...],
             # optimize_goal_in_chain, on device). The aggregate carry is
             # computed once per goal and threaded through both phases.
             def outer_cond(c):
-                _s, _a, _m, _sw, rounds, last_swapped, first = c
+                _s, _a, _t, _m, _sw, rounds, last_swapped, first = c
                 return (first | (last_swapped > 0)) & (rounds < cfg.max_rounds)
 
             def outer_body(c):
-                s, a, m_tot, sw_tot, rounds, _ls, _first = c
+                s, a, tally, m_tot, sw_tot, rounds, _ls, _first = c
 
                 # The refresh cadence must count ROUNDS SINCE THE LAST FULL
                 # RECOMPUTE, which spans move/swap segments — each inner
@@ -864,16 +889,16 @@ def chain_optimize_full(state: ClusterTensors, goals: tuple[Goal, ...],
                 # the goal's cumulative round count (else a pass of many
                 # short segments would never refresh).
                 def move_body(carry, rounds_done):
-                    st, ag = carry
+                    st, ag, tl = carry
                     ag = maybe_refresh(ag, st, num_topics,
                                        rounds + rounds_done)
-                    ns, nag, applied, _stat = _chain_round_body(
+                    ns, nag, applied, stat = _chain_round_body(
                         st, ag, g, prior, goals, constraint, cfg, num_topics,
-                        masks)
-                    return (ns, nag), applied
+                        masks, stats="tally")
+                    return (ns, nag, tl + stat), applied
 
-                (s, a), m, r = run_carry_loop(move_body, (s, a),
-                                              cfg.max_rounds)
+                (s, a, tally), m, r = run_carry_loop(
+                    move_body, (s, a, tally), cfg.max_rounds)
 
                 def do_swap(st_ag):
                     def swap_body(carry, rounds_done):
@@ -895,19 +920,21 @@ def chain_optimize_full(state: ClusterTensors, goals: tuple[Goal, ...],
 
                 s, a, sw, sr = jax.lax.cond(supports_swap[g], do_swap,
                                             no_swap, (s, a))
-                return (s, a, m_tot + m, sw_tot + sw, rounds + r + sr, sw,
-                        jnp.bool_(False))
+                return (s, a, tally, m_tot + m, sw_tot + sw,
+                        rounds + r + sr, sw, jnp.bool_(False))
 
-            s, a, m, sw, rounds, _, _ = jax.lax.while_loop(
+            s, a, tally, m, sw, rounds, _, _ = jax.lax.while_loop(
                 outer_cond, outer_body,
-                (s, compute_agg(s, num_topics), jnp.int32(0), jnp.int32(0),
-                 jnp.int32(0), jnp.int32(0), jnp.bool_(True)))
-            return s, m, sw, rounds
+                (s, compute_agg(s, num_topics), jnp.zeros(2, jnp.float32),
+                 jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0),
+                 jnp.bool_(True)))
+            return s, m, sw, rounds, tally
 
         def skip(s):
-            return s, jnp.int32(0), jnp.int32(0), jnp.int32(0)
+            return (s, jnp.int32(0), jnp.int32(0), jnp.int32(0),
+                    jnp.zeros(2, jnp.float32))
 
-        new_state, moves, swaps, rounds = jax.lax.cond(
+        new_state, moves, swaps, rounds, tally = jax.lax.cond(
             (viol0 > 0) | (offline0 > 0) | drain_pending(carry_state),
             run, skip, carry_state)
         viol1, obj1, offline1 = _chain_goal_stats_body(
@@ -915,7 +942,8 @@ def chain_optimize_full(state: ClusterTensors, goals: tuple[Goal, ...],
         ys = {"viol_before": viol0, "obj_before": obj0,
               "offline_before": offline0, "viol_after": viol1,
               "obj_after": obj1, "offline_after": offline1,
-              "moves": moves, "swaps": swaps, "rounds": rounds}
+              "moves": moves, "swaps": swaps, "rounds": rounds,
+              "cand_valid": tally[0], "cand_accepted": tally[1]}
         return new_state, ys
 
     final_state, stats = jax.lax.scan(
@@ -1009,6 +1037,11 @@ def _chain_infos_from_stats(goals: tuple[Goal, ...], stats: dict,
             # ccsa: ok[CCSA001] decode of already-fetched host stats scalars
             "offline_remaining": int(stats["offline_after"][i]),
         })
+        if "cand_valid" in stats:
+            # ccsa: ok[CCSA001] decode of already-fetched host stats scalars
+            infos[-1]["candidates_valid"] = float(stats["cand_valid"][i])
+            # ccsa: ok[CCSA001] decode of already-fetched host stats scalars
+            infos[-1]["candidates_accepted"] = float(stats["cand_accepted"][i])
     return infos
 
 
@@ -1447,7 +1480,7 @@ def _megabatch_rounds_driver(states: ClusterTensors, active0: jax.Array,
         a = maybe_refresh(a, s, num_topics, gr)
         ns, na, applied, stat = _chain_round_body(
             s, a, active_idx, prior_mask, goals, constraint, cfg,
-            num_topics, m, collect=collect)
+            num_topics, m, stats="row" if collect else None)
         if collect:
             ring = ring.at[gr % ring_rounds].set(stat)
         return ns, na, ring, applied

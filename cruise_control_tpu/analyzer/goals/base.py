@@ -187,6 +187,16 @@ class Goal:
         override this."""
         return self.dest_score(state, derived, constraint, aux)
 
+    def swap_light_weight(self, state, derived, constraint, aux,
+                          ) -> jax.Array:
+        """[P, S] — what a replica WEIGHS in a swap: the counterparty gives
+        its lightest by this, and a swap is offered only where the
+        overloaded broker's replica outweighs it (maxSourceReplicaLoad).
+        Default: ``replica_weight``. A goal whose ``replica_weight`` ranks
+        replicas for the MOVE grid by something other than their size (the
+        resource goals' fit priority) says here what the size is."""
+        return self.replica_weight(state, derived, constraint, aux)
+
     def swap_acceptance(self, state, derived, constraint, aux,
                         fwd: CandidateDeltas, rev: CandidateDeltas,
                         net: CandidateDeltas) -> jax.Array:
@@ -214,6 +224,19 @@ class Goal:
     def replica_weight(self, state, derived, constraint, aux) -> jax.Array:
         """[P, S] — which replicas to move first (SortedReplicas analogue)."""
         return replica_load_total(state)
+
+    def card_dest_ok(self, state, cand_p: jax.Array, cand_s: jax.Array,
+                     ) -> "jax.Array | None":
+        """Optional [k, B] bool: the brokers this goal's ``acceptance``
+        lets the source card ``(cand_p, cand_s)[i]`` move to, as far as
+        that depends on the card alone and not on loads or counts (a rack
+        rule: the racks of the partition's other replicas). None when the
+        goal has no such rule. Once the goal is a PRIOR goal, what pairs a
+        card with destinations it could not otherwise reach reads the
+        conjunction of these (``search.prior_card_dest_ok``; today the swap
+        grid's counterparties): a hint that saves vetoes, never a bypass,
+        ``acceptance`` still judges every candidate."""
+        return None
 
     def target_dests(self, state, derived, constraint, aux,
                      cand_p: jax.Array, cand_s: jax.Array,

@@ -179,6 +179,16 @@ class ResourceDistributionGoal(Goal):
         fits = (size <= max_gap) & (size <= src_room) & (size > 0)
         return jnp.where(fits, peak + size, size)
 
+    def swap_light_weight(self, state, derived, constraint, aux):
+        # The replica's load of this resource. ``replica_weight`` lifts
+        # the replicas that FIT a move above the rest (each against its
+        # OWN broker's surplus over the lower band), so on a counterparty
+        # near its lower band the "lightest" by it were its smallest
+        # NON-fitting replicas, which can be larger than what the
+        # overloaded broker offers: such a grid's swaps all carry load
+        # TOWARDS the overloaded side (PERF.md, PR 34).
+        return replica_load_column(state, int(self.resource))
+
     def target_dests(self, state, derived, constraint, aux,
                      cand_p, cand_s, src_valid, rank_stride=1,
                      rank_offset=0):

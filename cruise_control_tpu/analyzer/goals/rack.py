@@ -45,21 +45,27 @@ def _duplicate_mask(state):
     return (same & earlier).any(axis=2) & exists
 
 
-def _other_replica_racks(state, deltas: CandidateDeltas):
-    """[N, S] — rack of every OTHER live replica of the candidate's
-    partition (the moving slot and empty slots read a rack no broker
-    has). Built once per grid row, where partition and moving slot are
-    fixed, then broadcast."""
+def other_replica_racks(state, partition, src_slot):
+    """[rows, S] — rack of every OTHER live replica of each row's
+    partition (the moving slot ``src_slot`` and empty slots read a rack no
+    broker has). A row is a grid row or a source card: partition and
+    moving slot are fixed there."""
     b = state.num_brokers
-    assign_p = state.assignment[deltas.row_partition]  # [rows, S]
+    assign_p = state.assignment[partition]  # [rows, S]
     rack_pad = jnp.concatenate([state.rack, state.rack[:1]])
     slot_racks = rack_pad[jnp.clip(assign_p, 0, b - 1)]
     s = state.max_replication_factor
     not_moving = jnp.arange(s, dtype=jnp.int32)[None, :] \
-        != deltas.row_src_slot[:, None]
+        != src_slot[:, None]
     no_rack = jnp.iinfo(slot_racks.dtype).min
-    return deltas.from_rows(
-        jnp.where(not_moving & (assign_p >= 0), slot_racks, no_rack))
+    return jnp.where(not_moving & (assign_p >= 0), slot_racks, no_rack)
+
+
+def _other_replica_racks(state, deltas: CandidateDeltas):
+    """[N, S] — ``other_replica_racks`` per candidate: built once per grid
+    row, then broadcast."""
+    return deltas.from_rows(other_replica_racks(
+        state, deltas.row_partition, deltas.row_src_slot))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -92,6 +98,13 @@ class RackAwareGoal(Goal):
     def acceptance(self, state, derived, constraint, aux, deltas: CandidateDeltas):
         is_move = deltas.replica_delta > 0
         return jnp.where(is_move, ~self._dst_rack_conflict(state, deltas), True)
+
+    def card_dest_ok(self, state, cand_p, cand_s):
+        # ``acceptance`` as far as it depends on the card alone: the
+        # brokers whose rack hosts no OTHER replica of the card's partition.
+        others = other_replica_racks(state, cand_p, cand_s)     # [k, S]
+        return ~(state.rack[None, None, :] == others[:, :, None]) \
+            .any(axis=1)
 
     def improvement(self, state, derived, constraint, aux, deltas):
         dup = _duplicate_mask(state)
@@ -140,6 +153,12 @@ class RackAwareDistributionGoal(RackAwareGoal):
         dst_count = self._rack_counts_at(state, deltas, dst_rack)
         is_move = deltas.replica_delta > 0
         return jnp.where(is_move, dst_count + 1 <= limit, True)
+
+    def card_dest_ok(self, state, cand_p, cand_s):
+        others = other_replica_racks(state, cand_p, cand_s)     # [k, S]
+        in_rack = (state.rack[None, None, :] == others[:, :, None]) \
+            .sum(axis=1)
+        return in_rack + 1 <= self._limits(state)[cand_p][:, None]
 
     def improvement(self, state, derived, constraint, aux, deltas):
         limit = deltas.at_partition(self._limits(state))

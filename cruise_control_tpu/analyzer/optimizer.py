@@ -199,6 +199,41 @@ def ensure_evacuated(goal_chain: Sequence[Goal], infos: Sequence[dict],
         "the hard goals")
 
 
+def record_goal_outcomes(goal_chain: Sequence[Goal], infos: Sequence[dict],
+                         meta: ClusterMeta, span=None) -> None:
+    """What the search left behind and what its acceptance stack cost,
+    counted once a pass from the host scalars the chain's stats already
+    fetched: ``solver_goals_violated_after_total{goal=}`` (1 for each goal
+    still violated after its turn, what ``goalSummary`` renders as
+    ``VIOLATED``; 0 for the others, so that the series exists), and, where
+    the route tallies them (the whole-chain dispatch, which keeps no
+    flight ring; the bounded route's ring feeds ``solver_flight_killed_*``
+    instead), ``solver_round_candidates_total{goal=,stage="valid"|
+    "accepted"}``: the move rounds' valid candidates, and those of them
+    that every EARLIER goal's acceptance let through. On the pass's
+    ``solver.dispatch`` spans under ``span``: ``racks`` (the racks the
+    model holds) and ``prior_veto_share`` (vetoed over valid, the pass's
+    total)."""
+    from ..utils.sensors import SENSORS
+    valid = accepted = 0.0
+    for goal, info in zip(goal_chain, infos):
+        SENSORS.count("solver_goals_violated_after",
+                      0.0 if info["succeeded"] else 1.0,
+                      labels={"goal": goal.name})
+        if "candidates_valid" not in info:
+            continue
+        for stage in ("valid", "accepted"):
+            SENSORS.count("solver_round_candidates",
+                          info["candidates_" + stage],
+                          labels={"goal": goal.name, "stage": stage})
+        valid += info["candidates_valid"]
+        accepted += info["candidates_accepted"]
+    for dispatch in _dispatch_spans(span):
+        dispatch.set(racks=len(meta.rack_names))
+        if valid:
+            dispatch.set(prior_veto_share=round(1.0 - accepted / valid, 4))
+
+
 def ensure_only_new_brokers_receive(fetched: FetchedDiff,
                                     infos: Sequence[dict], meta: ClusterMeta,
                                     span=None) -> None:
@@ -941,6 +976,7 @@ class GoalOptimizer:
 
         ensure_evacuated(goal_chain, infos, lambda: state, meta, options,
                          _opt_span)
+        record_goal_outcomes(goal_chain, infos, meta, _opt_span)
         if stats.goals_skipped:
             from ..utils.sensors import SENSORS as _S
             _S.count("solver_goals_skipped", stats.goals_skipped)

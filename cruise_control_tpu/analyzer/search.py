@@ -388,8 +388,46 @@ def swap_brokers(derived: DerivedState, src_score: jax.Array,
             dst_brokers, jnp.isfinite(dst_vals))
 
 
+def prior_card_dest_ok(goals: Sequence[Goal], prior_mask: jax.Array,
+                       state: ClusterTensors, cand_p: jax.Array,
+                       cand_s: jax.Array) -> "jax.Array | None":
+    """[k, B] bool: the brokers each source card may enter under the PRIOR
+    goals, as far as their acceptance depends on the card alone
+    (``Goal.card_dest_ok``: a rack rule reads the racks of the partition's
+    other replicas), or None where no goal of the chain has such a rule
+    (trace-time). The ONE place destination construction learns of it
+    (docs/DESIGN.md "Destinations under a rack rule"): whatever builds a
+    destination for a card reads this, and acceptance still judges every
+    candidate. A goal's rule counts once the goal is prior (traced)."""
+    ok = None
+    for i, g in enumerate(goals):
+        rule = g.card_dest_ok(state, cand_p, cand_s)
+        if rule is not None:
+            rule = rule | ~prior_mask[i]
+            ok = rule if ok is None else ok & rule
+    return ok
+
+
+def swap_counterparties(derived: DerivedState, dst_score: jax.Array,
+                        src_may: jax.Array, k: int):
+    """The counterparties of a swap round where the prior goals rule
+    brokers out card by card (``src_may`` [K, B]: the brokers SOME heavy
+    replica of each overloaded broker may enter,
+    ``search.prior_card_dest_ok``): each overloaded broker's OWN ``k``
+    best-scored brokers among those it may reach, where ``swap_brokers``
+    takes the ``k`` best-scored overall for all of them, which may all lie
+    where this broker's replicas cannot go (three zones at RF 3: in
+    another zone). With nothing ruled out every row is ``swap_brokers``'
+    counterparties. Returns (dst_brokers [K, k], dst_ok [K, k])."""
+    key = jnp.where(derived.replica_dest_ok[None, :] & src_may,
+                    dst_score[None, :], -jnp.inf)
+    vals, brokers = jax.lax.top_k(key, k)
+    return brokers, jnp.isfinite(vals)
+
+
 def swap_grid(state: ClusterTensors, derived: DerivedState,
               src_score: jax.Array, dst_score: jax.Array, weight: jax.Array,
+              light_weight: jax.Array, card_dest_ok,
               k_brokers: int = 8, j_replicas: int = 4):
     """The swap candidate grid (AbstractGoal.maybeApplySwapAction:287 + the
     swap search of ResourceDistributionGoal.java:599-687), batched:
@@ -397,7 +435,16 @@ def swap_grid(state: ClusterTensors, derived: DerivedState,
     top-k overloaded brokers × top-k donors × (j heaviest source replicas ×
     j lightest destination replicas) → K·K·j·j swap candidates. The source
     replica must outweigh the destination replica (maxSourceReplicaLoad: a
-    swap always decreases the overloaded side, :599-687).
+    swap always decreases the overloaded side, :599-687): the heaviest are
+    the first by ``weight`` (the move grid's order), the lightest and the
+    comparison are by ``light_weight`` (``Goal.swap_light_weight``).
+
+    ``card_dest_ok`` (``(partition [n], slot [n]) -> [n, B] bool | None``,
+    the chain's ``prior_card_dest_ok``): where it rules a broker out for
+    an overloaded broker's heavy replicas, every overloaded broker meets
+    its OWN K donors (``swap_counterparties``), the same grid shape over
+    K x K donor slots; where it rules nothing out, or answers None,
+    ``swap_brokers``' one list.
 
     Returns (fwd, rev, net, p1, s1, p2, s2, src_b, dst_b, base_valid) where
     fwd/rev are the directional move legs and net the net transfer."""
@@ -409,10 +456,43 @@ def swap_grid(state: ClusterTensors, derived: DerivedState,
 
     heavy_idx, heavy_ok = _per_broker_top_replicas(
         state, weight, src_brokers, j_replicas, largest=True)    # [K, j]
-    light_idx, light_ok = _per_broker_top_replicas(
-        state, weight, dst_brokers, j_replicas, largest=False)
-
     s_dim = state.max_replication_factor
+
+    def lightest(brokers):
+        return _per_broker_top_replicas(state, light_weight, brokers,
+                                        j_replicas, largest=False)
+
+    def one_list(_):
+        """``swap_brokers``' K donors for every overloaded broker."""
+        light_idx, light_ok = lightest(dst_brokers)              # [K, j]
+        return tuple(jnp.broadcast_to(x[None], (k,) + x.shape) for x in
+                     (dst_brokers, dst_b_ok, light_idx, light_ok))
+
+    may_enter = card_dest_ok(
+        *slot_coords(heavy_idx.reshape(-1), state.num_partitions, s_dim))
+    if may_enter is None:
+        dst_brokers, dst_b_ok, light_idx, light_ok = one_list(None)
+    else:
+        # [K, B]: the brokers some heavy replica of each overloaded broker
+        # may enter; a row that offers no swap rules nothing out
+        src_may = (may_enter.reshape(k, j_replicas, -1)
+                   & heavy_ok[:, :, None]).any(axis=1) \
+            | ~(src_b_ok & heavy_ok.any(axis=1))[:, None]
+
+        def own_lists(_):
+            brokers, ok = swap_counterparties(derived, dst_score, src_may, k)
+            light_idx, light_ok = lightest(brokers.reshape(-1))
+            return (brokers, ok, light_idx.reshape(k, k, j_replicas),
+                    light_ok.reshape(k, k, j_replicas))
+
+        # With nothing ruled out every own list IS the one list. The cond
+        # is for the cells where the rule seldom bites: seeking the K x K
+        # donors' lightest replicas on every swap round read +2.04 % on
+        # ``round.ms_per_round`` at 250 brokers / 8 racks, the cond +0.64 %
+        # (PERF.md section 6, PR 34).
+        dst_brokers, dst_b_ok, light_idx, light_ok = jax.lax.cond(
+            src_may.all(), one_list, own_lists, None)
+
     # Grid: [K_src, K_dst, j, j] flattened.
     n = k * k * j_replicas * j_replicas
     si, di, ai, bi = jnp.meshgrid(jnp.arange(k), jnp.arange(k),
@@ -420,22 +500,22 @@ def swap_grid(state: ClusterTensors, derived: DerivedState,
                                   jnp.arange(j_replicas), indexing="ij")
     si, di, ai, bi = (x.reshape(-1) for x in (si, di, ai, bi))
     src_b = src_brokers[si]
-    dst_b = dst_brokers[di]
+    dst_b = dst_brokers[si, di]
     a_flat = heavy_idx[si, ai]
-    b_flat = light_idx[di, bi]
+    b_flat = light_idx[si, di, bi]
     p1, s1 = slot_coords(a_flat, state.num_partitions, s_dim)
     p2, s2 = slot_coords(b_flat, state.num_partitions, s_dim)
 
-    base_valid = src_b_ok[si] & dst_b_ok[di] & heavy_ok[si, ai] \
-        & light_ok[di, bi] & (src_b != dst_b) \
+    base_valid = src_b_ok[si] & dst_b_ok[si, di] & heavy_ok[si, ai] \
+        & light_ok[si, di, bi] & (src_b != dst_b) \
         & derived.movable_partition[p1] & derived.movable_partition[p2]
     # Distinct partitions, cross-hosting checks.
     base_valid &= p1 != p2
     base_valid &= ~(state.assignment[p1] == dst_b[:, None]).any(axis=1)
     base_valid &= ~(state.assignment[p2] == src_b[:, None]).any(axis=1)
     # The swap must shrink the overloaded side.
-    w_a = weight[p1, s1]
-    w_b = weight[p2, s2]
+    w_a = light_weight[p1, s1]
+    w_b = light_weight[p2, s2]
     base_valid &= w_a > w_b
 
     # Load vectors travel with the replicas (leadership keeps its replica).
@@ -496,7 +576,11 @@ def swap_round_candidates(state: ClusterTensors, masks: ExclusionMasks,
     weight = goal.replica_weight(state, derived, constraint, aux)
 
     fwd, rev, net, p1, s1, p2, s2, src_b, dst_b, base_valid = swap_grid(
-        state, derived, src_score, dst_score, weight, k_brokers, j_replicas)
+        state, derived, src_score, dst_score, weight,
+        goal.swap_light_weight(state, derived, constraint, aux),
+        partial(prior_card_dest_ok, optimized,
+                jnp.ones(len(optimized), dtype=bool), state),
+        k_brokers, j_replicas)
     accept = base_valid
     for g in optimized:
         accept &= g.swap_acceptance(state, derived, constraint,

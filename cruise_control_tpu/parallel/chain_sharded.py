@@ -38,7 +38,8 @@ from ..analyzer.agg import (
 )
 from ..analyzer.chain import (
     _chain_infos_from_stats, _chain_scores, _gated_aux, _scored_candidates,
-    _switch_swap_dest_score, excluded_hosting_replicas, set_dispatch_rounds,
+    _switch_swap_dest_score, _switch_swap_light_weight,
+    excluded_hosting_replicas, set_dispatch_rounds,
 )
 from ..analyzer.constraint import BalancingConstraint
 from ..analyzer.derived import compute_derived
@@ -219,10 +220,14 @@ def _chain_swap_local(state: ClusterTensors, agg, masks: ExclusionMasks,
     src_brokers, src_b_ok, dst_brokers, dst_b_ok = swap_brokers(
         derived, src_score, dst_score, k)
 
+    # The heaviest by the move grid's order, the lightest and the
+    # comparison by what a replica weighs in a swap, as search.swap_grid.
+    light_weight = _switch_swap_light_weight(active_idx, goals, aux_list,
+                                             state, derived, constraint)
     heavy_idx, heavy_ok = _per_broker_top_replicas(
         state, weight, src_brokers, j, largest=True)
     light_idx, light_ok = _per_broker_top_replicas(
-        state, weight, dst_brokers, j, largest=False)
+        state, light_weight, dst_brokers, j, largest=False)
 
     p1, s1 = slot_coords(heavy_idx, state.num_partitions, s_dim)
     p2, s2 = slot_coords(light_idx, state.num_partitions, s_dim)
@@ -248,7 +253,8 @@ def _chain_swap_local(state: ClusterTensors, agg, masks: ExclusionMasks,
     leg_r = leg_masks(p2, s2, light_ok, src_brokers)
 
     w_a = jnp.where(heavy_ok, weight[p1, s1], -jnp.inf)
-    w_b = jnp.where(light_ok, weight[p2, s2], jnp.inf)
+    w_b = jnp.where(light_ok, light_weight[p2, s2], jnp.inf)
+    size_a = light_weight[p1, s1]
     lead1 = state.leader_slot[p1] == s1
     lead2 = state.leader_slot[p2] == s2
     load_a = jnp.where(lead1[..., None], state.leader_load[p1],
@@ -296,7 +302,7 @@ def _chain_swap_local(state: ClusterTensors, agg, masks: ExclusionMasks,
     l_nwin = pick(gather_cards(nwin2), lsel)
     h_legs = pick(gather_cards(leg_f), hsel)
     l_legs = pick(gather_cards(leg_r), lsel)
-    h_w = hv
+    h_w = pick(gather_cards(size_a), hsel)
     l_w = -lv
 
     n = k * k * j * j

@@ -50,7 +50,7 @@ import time
 from .sensors import SENSORS, current_cluster_label
 
 # Columns of the on-device per-round stats row (chain._chain_round_body
-# collect=True). ``violation`` is the active goal's broker-violation total
+# stats="row"). ``violation`` is the active goal's broker-violation total
 # at round ENTRY (the tensors the row reduces over are the pre-apply
 # state): trajectory[N] equals exit-of-round N-1, and the goal's recorded
 # exit stats carry the final post-pass value — recomputing violations

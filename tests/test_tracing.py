@@ -941,8 +941,8 @@ def test_a_nested_trace_is_counted_once():
 
 _ROUND_SCOPES = ("round.agg_refresh", "round.score", "round.source_topk",
                  "round.candidates", "round.deltas", "round.accept",
-                 "round.select", "round.apply", "swap.round", "goal.stats",
-                 "goal.agg")
+                 "round.select", "round.apply", "round.flight_stats",
+                 "swap.round", "goal.stats", "goal.agg")
 
 
 def test_lowered_chain_names_every_scope_and_solves_as_the_parent_did():
