@@ -57,6 +57,36 @@ def _span(x: jax.Array, start: int, stop: int, stride: int = 1) -> jax.Array:
     return jax.lax.slice_in_dim(x, start, stop, stride, axis=0)
 
 
+def _grid_from_rows(layout, rows: jax.Array) -> jax.Array:
+    """[k_src + k_l, ...] values, one per row of the two-block grid
+    ``layout`` -> [N, ...] candidates (a broadcast, no lookup)."""
+    (k_src, k_cols), (k_l, s) = layout
+    tail = rows.shape[1:]
+    move = jnp.broadcast_to(jnp.expand_dims(_span(rows, 0, k_src), 1),
+                            (k_src, k_cols) + tail)
+    lead = jnp.broadcast_to(
+        jnp.expand_dims(_span(rows, k_src, k_src + k_l), 1),
+        (k_l, s) + tail)
+    return jnp.concatenate([move.reshape((k_src * k_cols,) + tail),
+                            lead.reshape((k_l * s,) + tail)])
+
+
+def _grid_from_dst(layout, vals: jax.Array) -> jax.Array:
+    """[k_cols - 1 | k_src | k_l * S, ...] values, one per entry of the
+    grid's destination margin (shared columns, targeted column, leadership
+    slots) -> [N, ...] candidates."""
+    (k_src, k_cols), _ = layout
+    k_shared = k_cols - 1
+    tail = vals.shape[1:]
+    move = jnp.concatenate(
+        [jnp.broadcast_to(_span(vals, 0, k_shared)[None],
+                          (k_src, k_shared) + tail),
+         jnp.expand_dims(_span(vals, k_shared, k_shared + k_src), 1)],
+        axis=1)
+    return jnp.concatenate([move.reshape((k_src * k_cols,) + tail),
+                            _span(vals, k_shared + k_src, len(vals))])
+
+
 @partial(jax.tree_util.register_dataclass,
          data_fields=["row_src", "dst_margin", "row_partition", "row_topic",
                       "row_src_slot"],
@@ -87,29 +117,12 @@ class CandidateGrid:
 
     def from_rows(self, rows: jax.Array) -> jax.Array:
         """[k_src + k_l, ...] row values -> [N, ...] candidates."""
-        (k_src, k_cols), (k_l, s) = self.layout
-        tail = rows.shape[1:]
-        move = jnp.broadcast_to(jnp.expand_dims(_span(rows, 0, k_src), 1),
-                                (k_src, k_cols) + tail)
-        lead = jnp.broadcast_to(
-            jnp.expand_dims(_span(rows, k_src, k_src + k_l), 1),
-            (k_l, s) + tail)
-        return jnp.concatenate([move.reshape((k_src * k_cols,) + tail),
-                                lead.reshape((k_l * s,) + tail)])
+        return _grid_from_rows(self.layout, rows)
 
     def from_dst(self, vals: jax.Array) -> jax.Array:
         """[len(dst_margin), ...] values read at ``dst_margin`` -> [N, ...]
         candidates."""
-        (k_src, k_cols), _ = self.layout
-        k_shared = k_cols - 1
-        tail = vals.shape[1:]
-        move = jnp.concatenate(
-            [jnp.broadcast_to(_span(vals, 0, k_shared)[None],
-                              (k_src, k_shared) + tail),
-             jnp.expand_dims(_span(vals, k_shared, k_shared + k_src), 1)],
-            axis=1)
-        return jnp.concatenate([move.reshape((k_src * k_cols,) + tail),
-                                _span(vals, k_shared + k_src, len(vals))])
+        return _grid_from_dst(self.layout, vals)
 
     def at_dst_topic(self, x: jax.Array) -> jax.Array:
         """``x[topic, dst_broker]`` of a [T, B] table per candidate: the
@@ -157,7 +170,9 @@ class CandidateDeltas:
     ``at_*`` methods below, never by indexing with the [N] fields: with a
     ``grid`` attached (compute_deltas given the layout) the lookup runs on
     the grid's margins and is broadcast; with none it is the plain
-    per-candidate gather. Same values on every valid candidate."""
+    per-candidate gather. Same values on every valid candidate. The [N]
+    fields themselves are built the same way (compute_deltas) and are
+    equal on EVERY candidate."""
 
     src_broker: jax.Array    # [N] int32
     dst_broker: jax.Array    # [N] int32
@@ -255,57 +270,123 @@ class CandidateDeltas:
         return 0.0 if value is None else value[:, r]
 
 
+def _at_slot(assign: jax.Array, slot: jax.Array) -> jax.Array:
+    """``assign[i, slot[i]]`` of [n, S] assignment rows, a negative slot
+    read as slot 0."""
+    return jnp.take_along_axis(
+        assign, jnp.maximum(slot, 0)[:, None], axis=1)[:, 0]
+
+
+def _delta_frame(cand: Candidates, layout):
+    """How ``compute_deltas`` lays the candidates out: ``(rows, from_rows,
+    from_dst, destinations)``.
+
+    - ``rows(flat)``: an [N] field of ``cand`` that a row fixes -> one
+      value per row;
+    - ``from_rows(v)``, ``from_dst(v)``: per-row / per-destination values
+      -> [N, ...] candidates;
+    - ``destinations(assign, is_move)``: the raw destination brokers, one
+      per destination, given the rows' assignment [rows, S].
+
+    With no layout every candidate is its own row and its own destination
+    (the three maps are the identity, a leadership destination is looked
+    up behind ``cand.dst_slot``). With ``generate_candidates``' two-block
+    layout the rows are the grid's (each row's first candidate, static
+    strided slices) and the destinations its margin ``[shared columns |
+    targeted column | leadership slots]``: column j of the leadership
+    block is slot j, so its destinations are the leader rows' assignment
+    itself, reshaped, with no lookup."""
+    if layout is None:
+        def same(x):
+            return x
+
+        def destinations(assign, is_move):
+            return jnp.where(is_move, cand.dst_broker,
+                             _at_slot(assign, cand.dst_slot))
+        return same, same, same, destinations
+
+    if len(layout) != 2:
+        raise ValueError(
+            f"grid lookups need the move + leadership layout, got {layout!r}")
+    (k_src, k_cols), (k_l, s_dim) = layout
+    n_move, n = k_src * k_cols, cand.n
+
+    def rows(flat):
+        return jnp.concatenate([_span(flat, 0, n_move, k_cols),
+                                _span(flat, n_move, n, s_dim)])
+
+    def destinations(assign, _is_move):
+        return jnp.concatenate(
+            [_span(cand.dst_broker, 0, k_cols - 1),
+             _span(cand.dst_broker, k_cols - 1, n_move, k_cols),
+             _span(assign, k_src, k_src + k_l).reshape(-1)])
+    return (rows, partial(_grid_from_rows, layout),
+            partial(_grid_from_dst, layout), destinations)
+
+
 @jax.named_scope("round.deltas")
 def compute_deltas(state: ClusterTensors, derived: DerivedState,
                    cand: Candidates,
                    layout: "tuple[tuple[int, int], ...] | None" = None,
                    ) -> CandidateDeltas:
-    """Gather the (src, dst, Δload) tuple for every candidate; also folds the
+    """The (src, dst, Δload) tuple of every candidate; also folds the
     structural legitimacy checks (GoalUtils.legitMove: destination must not
     already host the partition, source must exist, destination must be an
     alive allowed broker, leadership destination must be a live replica).
 
     ``layout``: ``generate_candidates``' layout for ``cand`` when it made
-    both blocks (moves, then leadership). The deltas then carry the grid's
-    margins (CandidateGrid) and the goals' table lookups run there."""
-    p = cand.partition
+    both blocks (moves, then leadership). Whatever a ROW of that grid fixes
+    (the partition's assignment, leader slot, loads and topic, the moving
+    slot, the source broker and its state, the deltas) is then looked up
+    once per row, whatever a DESTINATION fixes (the broker's masks) once
+    per entry of the destination margin, and both are broadcast; what
+    mixes the two is a compare of the broadcasts. The deltas carry the
+    margins (CandidateGrid) and the goals' table lookups run there too.
+    Without it the same body runs with every candidate its own row and
+    destination: the per-candidate gathers. Same fields either way, on
+    every candidate (docs/DESIGN.md "Grid lookups")."""
     b = state.num_brokers
-    assign_p = state.assignment[p]              # [N, S]
-    leader_slot_p = state.leader_slot[p]        # [N]
+    rows, from_rows, from_dst, destinations = _delta_frame(cand, layout)
 
-    is_move = cand.kind == KIND_MOVE
+    # Per row ---------------------------------------------------------------
+    p = rows(cand.partition)
+    is_move = rows(cand.kind) == KIND_MOVE
+    assign = state.assignment[p]                # [rows, S]
+    leader_slot = state.leader_slot[p]          # [rows]
     # src broker: replica's broker for moves; current leader's broker for leadership.
-    src_slot = jnp.where(is_move, cand.src_slot, leader_slot_p)
-    src_broker = jnp.take_along_axis(
-        assign_p, jnp.maximum(src_slot, 0)[:, None], axis=1)[:, 0]
-    dst_broker = jnp.where(
-        is_move, cand.dst_broker,
-        jnp.take_along_axis(assign_p, jnp.maximum(cand.dst_slot, 0)[:, None], axis=1)[:, 0])
+    src_slot = jnp.where(is_move, rows(cand.src_slot), leader_slot)
+    src_broker = _at_slot(assign, src_slot)
 
-    moving_is_leader = src_slot == leader_slot_p
-    lead = state.leader_load[p]      # [N, R]
-    foll = state.follower_load[p]    # [N, R]
+    moving_is_leader = src_slot == leader_slot
+    lead = state.leader_load[p]      # [rows, R]
+    foll = state.follower_load[p]    # [rows, R]
     move_vec = jnp.where(moving_is_leader[:, None], lead, foll)
     leadership_vec = lead - foll
     load_delta = jnp.where(is_move[:, None], move_vec, leadership_vec)
 
     replica_delta = is_move.astype(jnp.int32)
     leader_delta = (jnp.where(is_move, moving_is_leader, True)).astype(jnp.int32)
+    topic = state.topic[p]
 
-    # Structural legitimacy -------------------------------------------------
-    src_exists = (src_slot >= 0) & (jnp.take_along_axis(
-        assign_p, jnp.maximum(src_slot, 0)[:, None], axis=1)[:, 0] >= 0)
-    dst_in_range = (dst_broker >= 0) & (dst_broker < b)
-    dst_safe = jnp.clip(dst_broker, 0, b - 1)
+    src_exists = (src_slot >= 0) & (src_broker >= 0)
     src_safe = jnp.clip(src_broker, 0, b - 1)
-    src_offline = ~derived.alive[src_safe]
-    dst_alive, dst_may_lead, dst_may_receive = broker_masks_at(
-        derived, dst_safe, src_offline)
-    dst_alive &= dst_in_range
+    src_offline = from_rows(~derived.alive[src_safe])
+    row_ok = from_rows(derived.movable_partition[p] & src_exists)
+    leader_slot_n = from_rows(leader_slot)
+    moving_is_leader_n = from_rows(moving_is_leader)
 
+    # Per destination -------------------------------------------------------
+    dst_broker = destinations(assign, is_move)
+    dst_safe = jnp.clip(dst_broker, 0, b - 1)
+    dst_alive, dst_may_lead, dst_may_receive = broker_masks_at(
+        derived, dst_safe, src_offline, from_dst)
+    dst_alive &= from_dst((dst_broker >= 0) & (dst_broker < b))
+    dst_n = from_dst(dst_broker)
+
+    # Structural legitimacy: a row against a destination, [N] ----------------
     # Destination must not already host the partition (moves only);
     # comparing against all S slots of the partition.
-    already_hosts = (assign_p == dst_broker[:, None]).any(axis=1)
+    already_hosts = (from_rows(assign) == dst_n[:, None]).any(axis=1)
     # Moving a LEADER replica transfers leadership with it, so destinations
     # excluded for leadership are ineligible for leader-replica moves
     # (GoalUtils.filterOutBrokersExcludedForLeadership:120-137: excluded
@@ -313,53 +394,36 @@ def compute_deltas(state: ClusterTensors, derived: DerivedState,
     # replica.isLeader()). Offline replicas are exempt — self-healing
     # placement must proceed even onto leadership-excluded brokers
     # (eligibleReplicasForSwap's !isOriginalOffline carve-out).
-    lead_dst_ok = (~moving_is_leader) | src_offline | dst_may_lead
+    lead_dst_ok = (~moving_is_leader_n) | src_offline | dst_may_lead
     move_ok = (~already_hosts) & dst_may_receive \
-        & (src_broker != dst_broker) & lead_dst_ok
-    # Leadership: destination slot must hold a live replica on an
-    # allowed-for-leadership broker, and differ from the current leader.
-    dst_slot_live = jnp.take_along_axis(
-        assign_p, jnp.maximum(cand.dst_slot, 0)[:, None], axis=1)[:, 0] >= 0
-    lead_ok = dst_slot_live & (cand.dst_slot != leader_slot_p) & (cand.dst_slot >= 0) \
-        & dst_may_lead & (leader_slot_p >= 0)
+        & (from_rows(src_broker) != dst_n) & lead_dst_ok
+    # Leadership: destination slot must hold a live replica (its broker IS
+    # the destination) on an allowed-for-leadership broker, and differ from
+    # the current leader.
+    lead_ok = (dst_n >= 0) & (cand.dst_slot != leader_slot_n) \
+        & (cand.dst_slot >= 0) & dst_may_lead & (leader_slot_n >= 0)
 
-    valid = cand.valid & derived.movable_partition[p] & src_exists & dst_alive \
-        & jnp.where(is_move, move_ok, lead_ok)
+    is_move_n = cand.kind == KIND_MOVE
+    valid = cand.valid & row_ok & dst_alive \
+        & jnp.where(is_move_n, move_ok, lead_ok)
 
     grid = None
     if layout is not None:
-        if len(layout) != 2:
-            raise ValueError(
-                "grid lookups need the move + leadership layout, got "
-                f"{layout!r}")
-        (k_src, k_cols), (_k_l, s_dim) = layout
-        n_move, n = k_src * k_cols, cand.n
-
-        def rows(flat):
-            # constant along a row: each row's first candidate
-            return jnp.concatenate([_span(flat, 0, n_move, k_cols),
-                                    _span(flat, n_move, n, s_dim)])
-
-        row_p = rows(p)
         grid = CandidateGrid(
-            layout=tuple(layout), row_src=rows(src_safe),
-            dst_margin=jnp.concatenate(
-                [_span(dst_safe, 0, k_cols - 1),
-                 _span(dst_safe, k_cols - 1, n_move, k_cols),
-                 _span(dst_safe, n_move, n)]),
-            row_partition=row_p, row_topic=state.topic[row_p],
-            row_src_slot=rows(jnp.maximum(src_slot, 0)))
+            layout=tuple(layout), row_src=src_safe, dst_margin=dst_safe,
+            row_partition=p, row_topic=topic,
+            row_src_slot=jnp.maximum(src_slot, 0))
 
     return CandidateDeltas(
-        src_broker=jnp.where(valid, src_broker, 0),
-        dst_broker=jnp.where(valid, dst_safe, 0),
-        load_delta=jnp.where(valid[:, None], load_delta, 0.0),
-        replica_delta=jnp.where(valid, replica_delta, 0),
-        leader_delta=jnp.where(valid, leader_delta, 0),
-        partition=p,
-        topic=state.topic[p],
-        src_slot=jnp.where(valid, src_slot, 0),
-        dst_slot=jnp.where(valid & ~is_move, cand.dst_slot, 0),
+        src_broker=jnp.where(valid, from_rows(src_broker), 0),
+        dst_broker=jnp.where(valid, from_dst(dst_safe), 0),
+        load_delta=jnp.where(valid[:, None], from_rows(load_delta), 0.0),
+        replica_delta=jnp.where(valid, from_rows(replica_delta), 0),
+        leader_delta=jnp.where(valid, from_rows(leader_delta), 0),
+        partition=cand.partition,
+        topic=from_rows(topic),
+        src_slot=jnp.where(valid, from_rows(src_slot), 0),
+        dst_slot=jnp.where(valid & ~is_move_n, cand.dst_slot, 0),
         valid=valid,
         grid=grid,
     )

@@ -234,7 +234,10 @@ _accept_lookup_traced: str | None = None
 def accept_lookup() -> str | None:
     """The table-lookup form of the last traced move-round body (None
     before any trace): the ``accept_lookup`` attribute of the
-    ``solver.dispatch`` spans."""
+    ``solver.dispatch`` spans. One observable, ``deltas.grid``, decides
+    both halves: with ``"grid"`` the goals' lookups (``round.accept``)
+    AND the deltas themselves (``round.deltas``, PR 35) are built on the
+    grid's margins; with ``"flat"`` both gather once a candidate."""
     return _accept_lookup_traced
 
 

@@ -124,15 +124,18 @@ def dest_columns_ok(derived: DerivedState) -> jax.Array:
 
 
 def broker_masks_at(derived: DerivedState, dst: jax.Array,
-                    src_offline: jax.Array,
+                    src_offline: jax.Array, from_dst=lambda x: x,
                     ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """The per-candidate lookup of the per-broker masks, for ``dst`` [N]
-    (in-range destination broker indices): ([N] alive, [N] allowed
-    leadership, [N] may RECEIVE the candidate's replica). ONE lookup a
-    candidate: the four per-broker masks ride one table of bits (a gather
-    on the chip costs by the element it produces, and with no broker
-    excluded XLA folded the separate lookups into one anyway; PERF.md,
-    PR 32).
+    """The lookup of the per-broker masks at the destinations ``dst``
+    (in-range broker indices), per candidate: ([N] alive, [N] allowed
+    leadership, [N] may RECEIVE the candidate's replica). ``dst`` holds
+    one broker per candidate, or one per entry of the candidate grid's
+    destination margin with ``from_dst`` the margin's broadcast to the
+    candidates (``candidates.compute_deltas``); ``src_offline`` is [N].
+    ONE lookup a destination: the four per-broker masks ride one table of
+    bits (a gather on the chip costs by the element it produces, and with
+    no broker excluded XLA folded the separate lookups into one anyway;
+    PERF.md, PR 32).
 
     An online replica is received only where ``replica_dest_ok``; an
     OFFLINE one (``src_offline``: its broker is dead) on any broker
@@ -143,8 +146,9 @@ def broker_masks_at(derived: DerivedState, dst: jax.Array,
     bits = (derived.alive, derived.allowed_leadership,
             derived.replica_dest_ok, derived.allowed_replica_move)
     table = sum(mask.astype(jnp.int8) << i for i, mask in enumerate(bits))
+    at_dst = from_dst(table[dst])
     alive, may_lead, online_ok, offline_ok = (
-        (table[dst] >> i) & 1 == 1 for i in range(len(bits)))
+        (at_dst >> i) & 1 == 1 for i in range(len(bits)))
     return alive, may_lead, jnp.where(src_offline, offline_ok, online_ok)
 
 

@@ -16,6 +16,7 @@ report ``(t2k - tk) / k`` — the fixed per-dispatch cost cancels.
 
 from __future__ import annotations
 
+import dataclasses
 import time
 from functools import partial
 
@@ -51,14 +52,19 @@ from functools import partial
 # as ``select_sources`` runs them; broker_count): the solver's own
 # ``segment`` and ``dense`` forms, and for the best a sort-based
 # alternative (one three-key ``lax.sort``, the first two of each run).
-# ``topk128`` beside them is the selection's global block alone.
+# ``topk128`` beside them is the selection's global block alone. The
+# ``deltas_*`` classes (PR 35) price ``compute_deltas`` itself on the same
+# grid over a random cluster of (brokers, partitions): every field gathered
+# once per candidate (``deltas_flat``, no layout passed) and built on the
+# grid's margins (``deltas_margin``, the layout passed: the round's form).
 CASE_NAMES = ("topk128", "topk1024", "approx1024", "segsum", "segmax",
               "gather_grid", "scatter_m", "elemwise", "pairwise_m",
               "segsort", "rankfill", "scatter_apply",
               "cell_segsum", "frac_round", "stride_sort",
               "stride_sort_fused", "accept_flat", "accept_margin",
               "accept_packed", "bbest_segment", "bbest_dense", "bbest_sort",
-              "bcount_segment", "bcount_dense")
+              "bcount_segment", "bcount_dense", "deltas_flat",
+              "deltas_margin")
 
 _ACCEPT_TERMS = 100
 
@@ -142,6 +148,62 @@ def _build_cases(brokers: int, partitions: int):
                           -jnp.inf)
             out += [w, jnp.where(jnp.isfinite(w), si[at], n_flat)]
         return tuple(out)
+
+    # deltas_*: a cluster of (brokers, partitions) at RF 3 with three
+    # distinct brokers a partition and leaders on every slot, and the grid
+    # above with random partitions on its rows. The carry shifts every
+    # row's partition, so no lookup is loop-invariant.
+    from ..analyzer.candidates import Candidates, compute_deltas
+    from ..analyzer.derived import compute_derived
+    from ..model.tensors import ClusterTensors
+    dkeys = jax.random.split(akeys[2], 5)
+    hop = jax.random.randint(dkeys[0], (partitions, s), 1,
+                             max(2, brokers // 3))
+    first = jax.random.randint(dkeys[1], (partitions, 1), 0, brokers)
+    lload = jax.random.uniform(dkeys[2], (partitions, 4))
+    dstate = ClusterTensors(
+        assignment=(first + jnp.cumsum(hop, axis=1) - hop) % brokers,
+        leader_slot=jax.random.randint(dkeys[3], (partitions,), 0, s),
+        leader_load=lload, follower_load=0.5 * lload,
+        capacity=jnp.ones((brokers, 4)),
+        rack=jnp.arange(brokers, dtype=jnp.int32) % 8,
+        broker_state=jnp.zeros(brokers, jnp.int8),
+        topic=jax.random.randint(dkeys[3], (partitions,), 0,
+                                 max(1, brokers // 10)),
+        partition_mask=jnp.ones(partitions, bool),
+        broker_mask=jnp.ones(brokers, bool))
+    dderived = compute_derived(dstate)
+    n_move = k_src * (k_dst + 1)
+    row_p = jax.random.randint(dkeys[4], (n_rows,), 0, partitions)
+    row_slot = jax.random.randint(dkeys[0], (k_src,), 0, s)
+    zeros_n = jnp.zeros(src_n.shape, jnp.int32)
+    dcand = Candidates(
+        kind=(jnp.arange(len(src_n)) >= n_move).astype(jnp.int8),
+        partition=grid.from_rows(row_p),
+        src_slot=zeros_n.at[:n_move].set(jnp.repeat(row_slot, k_dst + 1)),
+        dst_broker=zeros_n.at[:n_move].set(dst_n[:n_move]),
+        dst_slot=zeros_n.at[n_move:].set(
+            jnp.tile(jnp.arange(s, dtype=jnp.int32), k_l)),
+        valid=jnp.ones(src_n.shape, bool))
+
+    def deltas_step(v, layout):
+        """``compute_deltas`` with every row's partition shifted by the
+        carry; every field's bits summed into the next carry (exact in
+        any order), so none is dead and the forms can be compared."""
+        cand = dataclasses.replace(
+            dcand, partition=(dcand.partition + v[0]) % partitions)
+        d = compute_deltas(dstate, dderived, cand, layout)
+        total = jnp.int32(0)
+        for f in dataclasses.fields(d):
+            a = getattr(d, f.name)
+            if f.name == "grid" or a is None:
+                continue
+            if jnp.issubdtype(a.dtype, jnp.floating):
+                a = jax.lax.bitcast_convert_type(a, jnp.int32)
+            total += a.astype(jnp.int32).sum()
+        return v + 1 + total % 2
+
+    shift0 = jnp.zeros((1,), jnp.int32)
 
     def fold(arrays):
         """Every array into the carry's next value, so none is dead."""
@@ -308,6 +370,10 @@ def _build_cases(brokers: int, partitions: int):
             form = which.split("_")[1]
             return loop(lambda v: v + fold(
                 [broker_count(v > 0.0, bseg, brokers, form)]), x, iters)
+        if which == "deltas_flat":
+            return loop(lambda v: deltas_step(v, None), x, iters)
+        if which == "deltas_margin":
+            return loop(lambda v: deltas_step(v, grid.layout), x, iters)
         if which == "scatter_apply":
             # one-shot scatter apply of a full mover batch onto [P, S].
             plane = jnp.zeros((partitions, s), jnp.int32)
@@ -330,7 +396,8 @@ def _build_cases(brokers: int, partitions: int):
               "frac_round": w, "stride_sort": w, "stride_sort_fused": w,
               "accept_flat": tables, "accept_margin": tables,
               "accept_packed": tables, "bbest_segment": w, "bbest_dense": w,
-              "bbest_sort": w, "bcount_segment": w, "bcount_dense": w}
+              "bbest_sort": w, "bcount_segment": w, "bcount_dense": w,
+              "deltas_flat": shift0, "deltas_margin": shift0}
     return run, inputs
 
 
