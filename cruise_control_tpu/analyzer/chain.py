@@ -290,34 +290,38 @@ def _scored_candidates(state: ClusterTensors, agg: "AggCarry | None",
     the mesh's total. Every collective it adds runs unconditionally
     (a ``cond`` whose branches disagree on collectives would deadlock).
 
-    The phases carry stable ``jax.named_scope`` names (``round.score``,
-    ``round.source_topk``, ``round.candidates``, ``round.deltas``,
-    ``round.accept``), some here and some inside the helpers, so a device
-    profile names them on every route. Metadata only: the lowered
-    computation is the same."""
+    The phases carry stable ``jax.named_scope`` names (``round.score``
+    and inside it ``round.score_derived``, ``round.score_goals``,
+    ``round.score_offline``; ``round.source_topk``, ``round.candidates``,
+    ``round.deltas``, ``round.accept``), some here and some inside the
+    helpers, so a device profile names them on every route. Metadata
+    only: the lowered computation is the same."""
     lead_only_f, incl_lead_f, indep_f = _goal_flags(goals)
     is_lead_only = lead_only_f[active_idx]
     has_leadership = incl_lead_f[active_idx]
 
     with jax.named_scope("round.score"):
-        derived = compute_derived(state, masks.excluded_topics,
-                                  masks.excluded_replica_move_brokers,
-                                  masks.excluded_leadership_brokers,
-                                  psum=psum, agg=agg)
-        is_active, aux_list, src_score, dst_score, weight = _chain_scores(
-            state, derived, active_idx, prior_mask, goals, constraint,
-            num_topics, agg, psum=psum)
+        with jax.named_scope("round.score_derived"):
+            derived = compute_derived(state, masks.excluded_topics,
+                                      masks.excluded_replica_move_brokers,
+                                      masks.excluded_leadership_brokers,
+                                      psum=psum, agg=agg)
+        with jax.named_scope("round.score_goals"):
+            is_active, aux_list, src_score, dst_score, weight = \
+                _chain_scores(state, derived, active_idx, prior_mask, goals,
+                              constraint, num_topics, agg, psum=psum)
 
         # Self-healing priority: replicas stranded on dead brokers are
         # always sources with maximal weight for non-leadership goals, and
         # moving one scores a large bonus below so it wins over pure
         # balance refinements (ClusterModel.selfHealingEligibleReplicas).
-        off = offline_replicas(state)  # [P, S]
-        offline_pb = offline_per_broker(state, off)
-        if psum is not None:
-            offline_pb = psum(offline_pb)
-        src_score = src_score + jnp.where(is_lead_only, 0.0, offline_pb)
-        weight = jnp.where(off & ~is_lead_only, 1e30, weight)
+        with jax.named_scope("round.score_offline"):
+            off = offline_replicas(state)  # [P, S]
+            offline_pb = offline_per_broker(state, off)
+            if psum is not None:
+                offline_pb = psum(offline_pb)
+            src_score = src_score + jnp.where(is_lead_only, 0.0, offline_pb)
+            weight = jnp.where(off & ~is_lead_only, 1e30, weight)
 
     # UNIFORM grid layout: both the move and the leadership block always
     # exist (static shapes shared by every goal); the active goal's traced

@@ -161,13 +161,14 @@ class CruiseControl:
         if configure_observability:
             from .utils import xla_telemetry
             from .utils.flight_recorder import FLIGHT
-            from .utils.tracing import TRACER
+            from .utils.tracing import TRACER, watch_collector
             TRACER.configure(
                 enabled=config.get_boolean("tracing.enabled"),
                 max_traces=config.get_int("tracing.max.traces"),
                 jsonl_path=config.get("tracing.jsonl.path") or None,
                 jsonl_max_bytes=config.get_long("tracing.jsonl.max.bytes"),
                 jsonl_max_files=config.get_int("tracing.jsonl.max.files"))
+            watch_collector(config.get_boolean("tracing.enabled"))
             FLIGHT.configure(
                 enabled=config.get_boolean("solver.flight.recorder.enabled"),
                 max_passes=config.get_int("solver.flight.recorder.max.passes"),

@@ -43,7 +43,7 @@ import time
 from typing import Callable
 
 from ..utils.sensors import SENSORS
-from ..utils.tracing import annotation
+from ..utils.tracing import annotation, gc_pause_ns
 
 _AMBIENT: contextvars.ContextVar["Journey | None"] = \
     contextvars.ContextVar("journey_current", default=None)
@@ -96,9 +96,13 @@ class _SegmentScope:
     attaches attrs before close (cache hit, verdict, pass ids). The
     block is also a ``cc.<segment>`` event of a running profiler capture
     (the spans' helper), so the segment IS the measurement of its
-    boundary on the device trace's clock too."""
+    boundary on the device trace's clock too. The collector's pauses that
+    overlapped the block are kept beside the segment (``Journey.paused``),
+    not in it: a pause is real time, a journey's clock may be the
+    twin's."""
 
-    __slots__ = ("_journey", "_name", "_attrs", "_t0", "_annotation")
+    __slots__ = ("_journey", "_name", "_attrs", "_t0", "_annotation",
+                 "_gc_ns0")
 
     def __init__(self, journey: "Journey", name: str, attrs: dict):
         self._journey = journey
@@ -107,6 +111,7 @@ class _SegmentScope:
 
     def __enter__(self) -> "_SegmentScope":
         self._t0 = self._journey.now()
+        self._gc_ns0 = gc_pause_ns()
         self._annotation = annotation(self._name)
         self._annotation.__enter__()
         return self
@@ -115,9 +120,12 @@ class _SegmentScope:
         self._annotation.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self._attrs.setdefault("error", exc_type.__name__)
+        paused_ns = gc_pause_ns() - self._gc_ns0
         self._journey.add(self._name,
                           max(0.0, self._journey.now() - self._t0),
                           **self._attrs)
+        if paused_ns > 0:
+            self._journey.paused(self._name, paused_ns)
         return False
 
     def set(self, **attrs) -> None:
@@ -134,7 +142,7 @@ class Journey:
     recording = True
 
     __slots__ = ("endpoint", "cluster", "opened_unix_s", "status",
-                 "attrs", "segments", "total_s", "unattributed_s",
+                 "attrs", "segments", "gc_ns", "total_s", "unattributed_s",
                  "_t0", "_monotonic", "_lock", "_closed")
 
     def __init__(self, endpoint: str, cluster: str | None,
@@ -146,6 +154,9 @@ class Journey:
         self.status = "open"
         self.attrs: dict = {}
         self.segments: list[tuple[str, float, dict]] = []
+        # segment -> nanoseconds of the collector's pauses that overlapped
+        # its blocks; feeds a counter at close and is not exported.
+        self.gc_ns: dict[str, int] = {}
         self.total_s = 0.0
         self.unattributed_s = 0.0
         self._monotonic = monotonic
@@ -164,6 +175,11 @@ class Journey:
                 return
             self.segments.append((name, max(0.0, float(duration_s)),
                                   attrs))
+
+    def paused(self, name: str, pause_ns: int) -> None:
+        with self._lock:
+            if not self._closed:
+                self.gc_ns[name] = self.gc_ns.get(name, 0) + pause_ns
 
     def seg(self, name: str, **attrs) -> _SegmentScope:
         """Context manager timing a block into one segment."""
@@ -209,7 +225,9 @@ class JourneyLog:
     every downstream stamp no-ops. ``close()`` finalizes the record,
     appends it to the ring, and mirrors each segment into the
     ``journey_segment_seconds{endpoint,segment}`` histogram (ambient
-    cluster label applies, exactly like every other sensor)."""
+    cluster label applies, exactly like every other sensor) and the
+    collector's pauses inside it into
+    ``journey_segment_gc_seconds_total`` under the same labels."""
 
     def __init__(self, enabled: bool = True, max_entries: int = 256,
                  monotonic: Callable[[], float] = time.monotonic,
@@ -249,6 +267,10 @@ class JourneyLog:
             SENSORS.observe("journey_segment_seconds", duration_s,
                             labels={"endpoint": journey.endpoint,
                                     "segment": name})
+        for name, pause_ns in journey.gc_ns.items():
+            SENSORS.count("journey_segment_gc_seconds", pause_ns / 1e9,
+                          labels={"endpoint": journey.endpoint,
+                                  "segment": name})
 
     # -- export ------------------------------------------------------------
     def entries(self, endpoint: str | None = None,

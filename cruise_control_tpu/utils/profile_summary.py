@@ -1,7 +1,7 @@
 """From a profiler capture to a summary an operator can read in the
 response of ``GET /profile``: device busy and idle time, device seconds by
-XLA program and by named scope of the round body, and what the program was
-doing in the device's idle gaps.
+XLA program, by named scope of the round body and of the largest single
+operations, and what the program was doing in the device's idle gaps.
 
 Two steps, so that the arithmetic is tested on a small recorded fixture
 without a chip: ``load_events`` turns an ``.xplane.pb`` into plain lists,
@@ -36,6 +36,8 @@ SCOPE_STAT = "tf_op"
 SCOPE = re.compile(r"(?:^|/)((?:round|swap|goal)\.[a-z_]+)(?=/|:|$)")
 UNSCOPED = "(unscoped)"
 NO_SPAN = "(no span)"
+NO_PROGRAM = "(no program)"
+LARGEST_OPERATIONS = 10
 
 
 # -- step 1: the file -> plain lists ------------------------------------------
@@ -290,8 +292,9 @@ def _rounded(seconds: dict) -> dict:
 def reduce(events: dict) -> dict | None:
     """The capture's numbers, or None where no operation ran on a device.
     The window runs from the first to the last event the capture holds
-    (device operations and ``cc.*`` spans); seconds by program and by
-    scope are means over the devices; idle gaps are the first device's."""
+    (device operations and ``cc.*`` spans); seconds by program, by scope
+    and by operation (its own, as by scope; the ten largest) are means
+    over the devices; idle gaps are the first device's."""
     devices = {k: v for k, v in sorted(events["devices"].items())
                if v["ops"]}
     if not devices:
@@ -305,6 +308,7 @@ def reduce(events: dict) -> dict | None:
     scopes: dict[str, float] = {}
     programs: dict[str, float] = {}
     unscoped: dict[str, float] = {}
+    operations: dict[tuple[str, str], list] = {}   # -> [s, events, scope]
     share = 1.0 / len(devices)
     for dev in devices.values():
         busy = _union([(e[-2], e[-2] + e[-1]) for e in dev["ops"]])
@@ -316,14 +320,19 @@ def reduce(events: dict) -> dict | None:
         for name, _s, d in modules:
             programs[name] = programs.get(name, 0.0) + share * d / 1e9
         ops = sorted(dev["ops"], key=lambda e: (e[-2], -e[-1]))
-        for (_name, scope, start, _dur), own in zip(ops, _self_ns(ops)):
+        for (name, scope, start, _dur), own in zip(ops, _self_ns(ops)):
             scopes[scope] = scopes.get(scope, 0.0) + share * own / 1e9
+            at = bisect.bisect_right(module_starts, start) - 1
+            inside = at >= 0 and start < modules[at][1] + modules[at][2]
+            program = modules[at][0] if inside else NO_PROGRAM
             if scope == UNSCOPED:
-                at = bisect.bisect_right(module_starts, start) - 1
-                inside = at >= 0 and start < modules[at][1] + modules[at][2]
-                program = modules[at][0] if inside else "(no program)"
                 unscoped[program] = unscoped.get(program, 0.0) \
                     + share * own / 1e9
+            # an instruction's name is its program's own: fusion.7 of one
+            # program is not fusion.7 of another
+            entry = operations.setdefault((program, name), [0.0, 0.0, scope])
+            entry[0] += share * own / 1e9
+            entry[1] += share
     gaps, cursor = [], lo
     for s, e in first_busy:
         if s > cursor:
@@ -342,6 +351,12 @@ def reduce(events: dict) -> dict | None:
         "deviceSecondsByProgram": _rounded(programs),
         "deviceSecondsByScope": _rounded(scopes),
         "unscopedSecondsByProgram": _rounded(unscoped),
+        "deviceSecondsByOperation": [
+            {"operation": name, "program": program, "scope": scope,
+             "seconds": round(seconds, 9), "events": round(events, 3)}
+            for (program, name), (seconds, events, scope) in sorted(
+                operations.items(), key=lambda kv: -kv[1][0]
+            )[:LARGEST_OPERATIONS]],
         "idleSecondsBySpan": _rounded(_idle_by_span(gaps, spans)),
     }
 

@@ -25,8 +25,11 @@ from __future__ import annotations
 
 import bisect
 import contextvars
+import logging
 import threading
 from contextlib import contextmanager
+
+LOG = logging.getLogger(__name__)
 
 _PREFIX = "kafka_cruisecontrol"
 
@@ -129,6 +132,8 @@ class SensorRegistry:
         # name -> (count, total_seconds, last_seconds, max_seconds)
         self._timers: dict[tuple[str, tuple], tuple[int, float, float, float]] = {}
         self._histograms: dict[tuple[str, tuple], _Histogram] = {}
+        # Run at the top of render(): what publishes totals kept elsewhere.
+        self._refreshes: list = []
 
     @staticmethod
     def _key(name: str, labels: dict | None) -> tuple[str, tuple]:
@@ -146,6 +151,34 @@ class SensorRegistry:
     def gauge(self, name: str, value: float, labels: dict | None = None) -> None:
         with self._lock:
             self._gauges[self._key(name, labels)] = float(value)
+
+    def set_counter(self, name: str, total: float,
+                    labels: dict | None = None) -> None:
+        """A counter whose running total is kept elsewhere (by code that
+        may not take this lock: ``tracing._on_collection``) and published
+        from a refresh; the total only ever grows."""
+        with self._lock:
+            self._counters[self._key(name, labels)] = float(total)
+
+    def set_timer(self, name: str, count: int, total: float, last: float,
+                  mx: float, labels: dict | None = None) -> None:
+        """``set_counter``'s like for the timer shape."""
+        with self._lock:
+            self._timers[self._key(name, labels)] = (
+                int(count), float(total), float(last), float(mx))
+
+    def add_refresh(self, refresh) -> None:
+        """``refresh()`` runs at the top of every ``render()``, before
+        the snapshot is taken, so that a scrape and a direct ``render()``
+        both see what it publishes. Adding one twice keeps one."""
+        with self._lock:
+            if refresh not in self._refreshes:
+                self._refreshes.append(refresh)
+
+    def remove_refresh(self, refresh) -> None:
+        with self._lock:
+            if refresh in self._refreshes:
+                self._refreshes.remove(refresh)
 
     def record_timer(self, name: str, seconds: float,
                      labels: dict | None = None) -> None:
@@ -250,6 +283,14 @@ class SensorRegistry:
     def render(self, extra_gauges: dict | None = None) -> str:
         """Prometheus text format. ``extra_gauges`` lets the scrape handler
         mix in live values (name -> value or (value, labels))."""
+        with self._lock:
+            refreshes = list(self._refreshes)
+        for refresh in refreshes:
+            try:
+                refresh()
+            except Exception:  # noqa: BLE001 — a scrape must not fail
+                LOG.warning("sensor refresh %r failed", refresh,
+                            exc_info=True)
         lines: list[str] = []
         typed: set[str] = set()
         with self._lock:

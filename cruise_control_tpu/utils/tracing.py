@@ -40,18 +40,25 @@ Design points:
 - **Zero-cost when disabled**: ``span()`` returns a shared no-op context
   manager — no allocation, no contextvar write, no clock read — so the
   config flag removes tracing from the solver hot path entirely.
+- **The collector's pauses where they fall** (``watch_collector``): one
+  ``gc.callbacks`` entry times every collection of Python's collector
+  into plain integers; a span that a pause overlapped carries ``gcMs``
+  and feeds ``trace_span_gc_seconds_total`` under its own labels, and a
+  full collection is a ``cc.gc.gen2`` event of a running capture.
 """
 
 from __future__ import annotations
 
 import collections
+import gc
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 
-from .sensors import SENSORS, current_cluster_label
+from .sensors import SENSORS, cluster_label, current_cluster_label
 
 import contextvars
 
@@ -82,13 +89,122 @@ _IDS = itertools.count(1)
 
 SPAN_HISTOGRAM = "trace_span_seconds"
 
+# -- the collector -------------------------------------------------------------
+#
+# What ``_on_collection`` writes. Plain integers and lists of them, and no
+# lock: CPython runs one collection at a time and calls ``gc.callbacks`` on
+# the collecting thread with the GIL held, and a collection can start at
+# any allocation, one made inside ``SENSORS``' or ``TRACER``'s own
+# ``with self._lock:`` block included. ``threading.Lock`` is not
+# re-entrant, so a callback that took either lock (``SENSORS.count``)
+# would stop its thread against itself. The registry reads these at
+# render time (``_publish_collector``).
+GENERATIONS = 3
+_gc_pause_ns = 0                        # every generation, the process's life
+_gc_started_ns = 0
+_gc_collections = [0] * GENERATIONS     # by generation
+_gc_ns = [0] * GENERATIONS
+_gc_last_ns = [0] * GENERATIONS
+_gc_max_ns = [0] * GENERATIONS
+# sys.getallocatedblocks() at the install and at the end of every full
+# collection, and at no other time: it is NOT O(1), it walks every pool of
+# every arena (tens of milliseconds once the heap has passed some millions
+# of blocks; read at every render it cost 5-7 % of a 0.5 s loop, PERF.md).
+_gc_blocks = 0
+_gc_full_annotation = None
+_watch_lock = threading.Lock()          # install and removal only
+
+
+def gc_pause_ns() -> int:
+    """Nanoseconds the collector has paused the process for since
+    ``watch_collector(True)``; 0 without the hook. A span or a journey
+    segment reads it when it opens and when it closes: the difference is
+    the pauses that overlapped it, on whichever thread they ran (a pause
+    holds the GIL, so it stops every Python thread; for a span that waits
+    in C with the GIL released, ``solver.wait``, it is overlap and not
+    stall)."""
+    return _gc_pause_ns
+
+
+def _on_collection(phase: str, info: dict) -> None:
+    """The ``gc.callbacks`` entry. Takes no lock and calls nothing that
+    does (above). A full collection is also a ``cc.gc.gen2`` event of a
+    running capture; the younger ones (hundreds a request, under a
+    millisecond each) are counted only."""
+    global _gc_pause_ns, _gc_started_ns, _gc_blocks
+    global _gc_full_annotation
+    generation = info["generation"]
+    if phase == "start":
+        if generation == 2:
+            _gc_full_annotation = annotation("gc.gen2")
+            _gc_full_annotation.__enter__()
+        _gc_started_ns = time.monotonic_ns()
+        return
+    started = _gc_started_ns
+    if not started:     # installed between a collection's start and stop
+        return
+    pause = time.monotonic_ns() - started
+    _gc_started_ns = 0
+    _gc_pause_ns += pause
+    _gc_collections[generation] += 1
+    _gc_ns[generation] += pause
+    _gc_last_ns[generation] = pause
+    if pause > _gc_max_ns[generation]:
+        _gc_max_ns[generation] = pause
+    if generation == 2:
+        _gc_blocks = sys.getallocatedblocks()
+        if _gc_full_annotation is not None:
+            _gc_full_annotation.__exit__(None, None, None)
+            _gc_full_annotation = None
+
+
+def _publish_collector() -> None:
+    """The callback's integers into ``SENSORS``: run by the registry at
+    the top of ``render()``, outside any collection. The series carry no
+    cluster label: the collector is the process's."""
+    with cluster_label(None):
+        for generation in range(GENERATIONS):
+            labels = {"generation": str(generation)}
+            SENSORS.set_counter("python_gc_collections",
+                                _gc_collections[generation], labels=labels)
+            SENSORS.set_timer("python_gc_pause",
+                              _gc_collections[generation],
+                              _gc_ns[generation] / 1e9,
+                              _gc_last_ns[generation] / 1e9,
+                              _gc_max_ns[generation] / 1e9, labels=labels)
+        SENSORS.gauge("python_allocated_blocks", _gc_blocks)
+
+
+def watch_collector(enabled: bool) -> None:
+    """Install (once a process; a second call leaves one entry) or remove
+    the ``gc.callbacks`` entry and its publication. Called where
+    ``TRACER.configure(enabled=...)`` is, with the same flag: with
+    ``tracing.enabled=false`` the program has no entry in
+    ``gc.callbacks``. The ``python_gc_*`` series exist, at 0, from the
+    install on, so a reader tells "no pause" from "no hook"; after a
+    removal they stand where they were."""
+    global _gc_blocks
+    with _watch_lock:
+        installed = _on_collection in gc.callbacks
+        if not enabled:
+            if installed:
+                gc.callbacks.remove(_on_collection)
+            SENSORS.remove_refresh(_publish_collector)
+        elif not installed:
+            annotation("gc.gen2")   # the import, outside any collection
+            _gc_blocks = sys.getallocatedblocks()
+            gc.callbacks.append(_on_collection)
+            SENSORS.add_refresh(_publish_collector)
+            _publish_collector()
+
 
 class Span:
     """One timed, attributed node of a trace tree."""
 
     __slots__ = ("name", "span_id", "parent", "root", "trace_id",
                  "start_ns", "end_ns", "attributes", "children",
-                 "label_keys", "transient", "pending", "published")
+                 "label_keys", "transient", "pending", "published",
+                 "gc_ns0")
 
     def __init__(self, name: str, parent: "Span | None"):
         self.name = name
@@ -98,6 +214,9 @@ class Span:
         self.trace_id = parent.trace_id if parent is not None \
             else f"{next(_IDS):032x}"
         self.start_ns = time.monotonic_ns()
+        # gc_pause_ns() when the span opened: read after the start and,
+        # in Tracer._close, before the end, so what is counted fell inside.
+        self.gc_ns0 = _gc_pause_ns
         self.end_ns = 0
         self.attributes: dict = {}
         self.children: list[Span] = []
@@ -365,11 +484,16 @@ class Tracer:
         return _CURRENT.get()
 
     def _close(self, span: Span) -> None:
+        paused_ns = _gc_pause_ns - span.gc_ns0
         span.end_ns = time.monotonic_ns()
         labels = {"span": span.name}
         for key in span.label_keys:
             labels[key] = str(span.attributes.get(key, ""))
         SENSORS.observe(SPAN_HISTOGRAM, span.duration_s, labels=labels)
+        if paused_ns > 0:
+            span.attributes["gcMs"] = round(paused_ns / 1e6, 3)
+            SENSORS.count("trace_span_gc_seconds", paused_ns / 1e9,
+                          labels=labels)
         with self._lock:
             self.spans_closed += 1
         parent = span.parent

@@ -497,3 +497,49 @@ def test_microbench_accept_forms_compute_the_same(case):
     assert (want != np.asarray(inputs[flat])).any()
     np.testing.assert_array_equal(
         np.asarray(run(inputs[case], 2, case)), want)
+
+
+INNER_SCORE_SCOPES = ("round.score_derived", "round.score_goals",
+                      "round.score_offline")
+
+
+def test_the_names_inside_round_score_are_metadata_only(monkeypatch):
+    """``round.score`` carries three names inside it (PR 36) so that a
+    profile says what inside it costs. The lowered text of the scoring
+    half is the same with and without them once locations are left out:
+    the compile cache's key ignores them, so no deployment pays a cold
+    compile for the names."""
+    import contextlib
+
+    state, meta = _cluster()
+    goals = _default_chain()
+    cfg = SearchConfig(num_sources=32, num_dests=6, moves_per_round=32,
+                       max_rounds=1)
+
+    def lowered():
+        # a function of its own each time: jit keeps the traced jaxpr,
+        # names and all, by the function it was given
+        def half(state, active_idx, prior_mask):
+            sc = chain_mod._scored_candidates(
+                state, None, active_idx, prior_mask, goals,
+                BalancingConstraint(), cfg, meta.num_topics, _masks(),
+                global_partitions=state.num_partitions)
+            return sc.score, sc.accept
+
+        return jax.jit(half).lower(state, jnp.int32(0),
+                                   jnp.zeros(len(goals), bool))
+
+    named = lowered()
+    real = jax.named_scope
+    monkeypatch.setattr(
+        jax, "named_scope",
+        lambda name: contextlib.nullcontext() if name in INNER_SCORE_SCOPES
+        else real(name))
+    plain = lowered()
+    monkeypatch.undo()
+    with_names = named.as_text(debug_info=True)
+    for scope in INNER_SCORE_SCOPES:
+        assert f"round.score/{scope}/" in with_names
+        assert scope not in plain.as_text(debug_info=True)
+    assert "round.score/" in plain.as_text(debug_info=True)
+    assert named.as_text() == plain.as_text()

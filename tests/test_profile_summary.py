@@ -53,6 +53,76 @@ def test_reduce_by_hand_idle_by_span_and_seconds_by_scope():
         "diff.fetch": pytest.approx(0.6e-06)}
 
 
+def test_reduce_by_hand_names_the_largest_operations():
+    # Two requests of one program: the while's own time is what its body
+    # does not cover, an operation's events are counted, and fusion.1 of
+    # another program is another operation.
+    ops = [("while.1", "(unscoped)", 1000.0, 6000.0),
+           ("fusion.1", "round.accept", 1500.0, 2000.0),
+           ("fusion.2", "round.score_goals", 4000.0, 1000.0),
+           ("fusion.1", "(unscoped)", 8000.0, 1000.0),
+           ("while.1", "(unscoped)", 11000.0, 6000.0),
+           ("fusion.1", "round.accept", 11500.0, 2500.0),
+           ("copy.9", "(unscoped)", 19000.0, 100.0)]
+    modules = [("jit_chain_optimize_full", 1000.0, 6000.0),
+               ("jit_cluster_stats", 8000.0, 1000.0),
+               ("jit_chain_optimize_full", 11000.0, 6000.0)]
+    got = ps.reduce(_events(ops, modules))
+    chain, stats = "jit_chain_optimize_full", "jit_cluster_stats"
+    assert got["deviceSecondsByOperation"] == [
+        {"operation": "while.1", "program": chain, "scope": "(unscoped)",
+         "seconds": 6.5e-06, "events": 2.0},
+        {"operation": "fusion.1", "program": chain, "scope": "round.accept",
+         "seconds": 4.5e-06, "events": 2.0},
+        {"operation": "fusion.2", "program": chain,
+         "scope": "round.score_goals", "seconds": 1e-06, "events": 1.0},
+        {"operation": "fusion.1", "program": stats, "scope": "(unscoped)",
+         "seconds": 1e-06, "events": 1.0},
+        {"operation": "copy.9", "program": "(no program)",
+         "scope": "(unscoped)", "seconds": 1e-07, "events": 1.0}]
+    assert sum(o["seconds"] for o in got["deviceSecondsByOperation"]) == \
+        pytest.approx(got["busyS"])
+
+
+def test_reduce_keeps_the_ten_largest_operations_as_means_over_devices():
+    ops = [(f"fusion.{i}", "round.accept", 100.0 * i, 10.0 + i)
+           for i in range(12)]
+    events = _events(ops, [("jit_f", 0.0, 2000.0)])
+    events["devices"]["/device:TPU:1"] = {
+        "ops": [list(o) for o in ops[6:]], "modules": [["jit_f", 0.0, 2000.0]]}
+    got = ps.reduce(events)["deviceSecondsByOperation"]
+    assert len(got) == ps.LARGEST_OPERATIONS == 10
+    assert [o["operation"] for o in got[:6]] == \
+        [f"fusion.{i}" for i in range(11, 5, -1)]
+    assert got[0] == {"operation": "fusion.11", "program": "jit_f",
+                      "scope": "round.accept", "seconds": 2.1e-08,
+                      "events": 1.0}
+    # on one device of two: half an event, half its seconds
+    assert got[-1] == {"operation": "fusion.2", "program": "jit_f",
+                       "scope": "round.accept", "seconds": 6e-09,
+                       "events": 0.5}
+
+
+def test_reduce_on_the_recorded_excerpt_names_its_operations():
+    with open(EXCERPT) as f:
+        got = ps.reduce(json.load(f))
+    largest = got["deviceSecondsByOperation"]
+    assert len(largest) == 10
+    assert largest[0] == {
+        "operation": "select_select_fusion.345",
+        "program": "jit_chain_optimize_full", "scope": "round.accept",
+        "seconds": pytest.approx(0.000112672, abs=1e-9), "events": 2.0}
+    assert [o["seconds"] for o in largest] == \
+        sorted((o["seconds"] for o in largest), reverse=True)
+    assert all(o["program"] == "jit_chain_optimize_full" for o in largest)
+    # the ten are a part of the scopes' seconds, not more
+    by_scope: dict = {}
+    for o in largest:
+        by_scope[o["scope"]] = by_scope.get(o["scope"], 0.0) + o["seconds"]
+    assert all(seconds <= got["deviceSecondsByScope"][scope] + 1e-9
+               for scope, seconds in by_scope.items())
+
+
 def test_reduce_labels_uncovered_idle_no_span():
     ops = [("fusion.1", "round.accept", 0.0, 1000.0),
            ("fusion.2", "round.accept", 5000.0, 1000.0)]
@@ -81,6 +151,23 @@ def test_reduce_innermost_span_is_the_latest_started_across_threads():
         "render": pytest.approx(40e-9)}
 
 
+def test_a_full_collection_is_the_innermost_span_of_its_idle():
+    """``cc.gc.gen2`` starts after every span that is open when the
+    collection runs, so the timeline picks it: idle under a pause reads
+    ``gc.gen2``, on whichever thread the pause ran."""
+    ops = [("fusion.1", "round.accept", 0.0, 100.0),
+           ("fusion.2", "round.accept", 900.0, 100.0)]
+    host = [("cc.http.request", 50.0, 900.0),
+            ("cc.render", 200.0, 600.0),
+            ("cc.gc.gen2", 300.0, 250.0),             # inside the render
+            ("cc.bench.client", 0.0, 1000.0)]         # another thread
+    got = ps.reduce(_events(ops, host=host))
+    assert got["idleSecondsBySpan"] == {
+        "render": pytest.approx(350e-9),
+        "gc.gen2": pytest.approx(250e-9),
+        "http.request": pytest.approx(200e-9)}
+
+
 def test_reduce_without_device_ops_is_none():
     assert ps.reduce({"devices": {}, "host": [["cc.http.request", 0, 5]]}) \
         is None
@@ -94,6 +181,14 @@ def test_reduce_without_device_ops_is_none():
      "round.source_topk"),
     ("jit(f)/cond/branch_1_fun/while/body/swap.round/gather:", "swap.round"),
     ("jit(f)/while/body/goal.stats/jit(_where)/select_n:", "goal.stats"),
+    ("jit(f)/while/body/round.score/round.score_derived/reduce_sum:",
+     "round.score_derived"),
+    ("jit(f)/while/body/round.score/round.score_goals/goal.agg/add:",
+     "goal.agg"),
+    ("jit(f)/while/body/round.score/round.score_goals/select_n:",
+     "round.score_goals"),
+    ("jit(f)/while/body/round.score/round.score_offline/scatter-add:",
+     "round.score_offline"),
     ("jit(cluster_stats)/reduce_sum:", "(unscoped)"),
     ("jit(f)/my_round.accepted/add:", "(unscoped)"),
     ("", "(unscoped)"),

@@ -164,3 +164,46 @@ def test_registry_quantile_none_only_for_absent_series():
     assert r.quantile("lat", 0.5) is not None
     # Same name, different labels = a different (absent) series.
     assert r.quantile("lat", 0.5, labels={"cluster": "x"}) is None
+
+
+def test_a_registered_refresh_runs_before_the_render_takes_its_snapshot():
+    r = SensorRegistry()
+    calls = []
+
+    def refresh():
+        calls.append(len(calls))
+        r.set_counter("kept_elsewhere", 7 + len(calls),
+                      labels={"generation": "2"})
+        r.set_timer("pause", 3, 0.5, 0.1, 0.3, labels={"generation": "2"})
+
+    r.add_refresh(refresh)
+    r.add_refresh(refresh)      # adding one twice keeps one
+    text = r.render()
+    assert calls == [0]
+    assert 'kafka_cruisecontrol_kept_elsewhere_total{generation="2"} 8.0' \
+        in text
+    assert 'kafka_cruisecontrol_pause_seconds_count{generation="2"} 3' in text
+    assert 'kafka_cruisecontrol_pause_seconds_sum{generation="2"} 0.5' in text
+    assert 'kafka_cruisecontrol_pause_seconds_max{generation="2"} 0.3' in text
+    # the total is set, not added to
+    assert 'kept_elsewhere_total{generation="2"} 9.0' in r.render()
+    r.remove_refresh(refresh)
+    r.render()
+    assert calls == [0, 1]
+
+
+def test_a_refresh_that_fails_does_not_break_the_render(caplog):
+    r = SensorRegistry()
+    r.count("requests")
+    seen = []
+
+    def broken():
+        raise RuntimeError("no backend")
+
+    r.add_refresh(broken)
+    r.add_refresh(lambda: seen.append(True))
+    with caplog.at_level("WARNING"):
+        text = r.render()
+    assert "kafka_cruisecontrol_requests_total 1.0" in text
+    assert seen == [True]       # the refreshes after it still ran
+    assert "no backend" in caplog.text

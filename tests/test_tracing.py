@@ -939,7 +939,9 @@ def test_a_nested_trace_is_counted_once():
     assert not getattr(xla_telemetry._OPEN, "stack", None)
 
 
-_ROUND_SCOPES = ("round.agg_refresh", "round.score", "round.source_topk",
+_ROUND_SCOPES = ("round.agg_refresh", "round.score", "round.score_derived",
+                 "round.score_goals", "round.score_offline",
+                 "round.source_topk",
                  "round.candidates", "round.deltas", "round.accept",
                  "round.select", "round.apply", "round.flight_stats",
                  "swap.round", "goal.stats", "goal.agg")
@@ -971,3 +973,243 @@ def test_lowered_chain_names_every_scope_and_solves_as_the_parent_did():
         [289, 0, 0, 0, 0, 64, 91, 0, 24, 0, 37, 0, 70, 2, 0]
     assert len(result.proposals) == 373
     assert result.balancedness_after == 89.57958658572481
+
+
+# -- the collector: every pause counted where it falls (PR 36) ---------------
+
+import gc  # noqa: E402
+
+from cruise_control_tpu.utils import tracing  # noqa: E402
+from cruise_control_tpu.utils.sensors import SENSORS, SensorRegistry  # noqa: E402
+
+
+@pytest.fixture
+def collector(monkeypatch):
+    """The hook installed over integers of the test's own and a registry
+    of its own, with the automatic collections off: what is counted is
+    what the test forces."""
+    registry = SensorRegistry()
+    monkeypatch.setattr(tracing, "SENSORS", registry)
+    for name in ("_gc_collections", "_gc_ns", "_gc_last_ns", "_gc_max_ns"):
+        monkeypatch.setattr(tracing, name, [0] * tracing.GENERATIONS)
+    monkeypatch.setattr(tracing, "_gc_pause_ns", 0)
+    was_installed = tracing._on_collection in gc.callbacks
+    tracing.watch_collector(False)
+    gc.collect()
+    gc.disable()
+    try:
+        tracing.watch_collector(True)
+        yield registry
+    finally:
+        tracing.watch_collector(False)
+        gc.enable()
+        monkeypatch.undo()
+        tracing.watch_collector(was_installed)
+
+
+def _sample(registry, series: str) -> float | None:
+    for line in registry.render().splitlines():
+        if line.startswith("kafka_cruisecontrol_" + series + " "):
+            return float(line.rsplit(" ", 1)[1])
+    return None
+
+
+def test_the_three_series_exist_at_zero_once_the_hook_is_installed(collector):
+    for generation in "012":
+        label = f'{{generation="{generation}"}}'
+        assert _sample(collector, "python_gc_collections_total" + label) == 0
+        assert _sample(collector, "python_gc_pause_seconds_sum" + label) == 0
+        assert _sample(collector, "python_gc_pause_seconds_count" + label) == 0
+    assert _sample(collector, "python_allocated_blocks") > 0
+    assert tracing.gc_pause_ns() == 0
+
+
+@pytest.mark.parametrize("generation", [0, 1, 2])
+def test_the_hook_counts_a_forced_collection_by_generation(collector,
+                                                           generation):
+    blocks_at_install = _sample(collector, "python_allocated_blocks")
+    gc.collect(generation)
+    assert tracing._gc_collections == [int(g == generation)
+                                       for g in range(3)]
+    pause_ns = tracing._gc_ns[generation]
+    assert pause_ns > 0 and tracing.gc_pause_ns() == pause_ns
+    label = f'{{generation="{generation}"}}'
+    assert _sample(collector, "python_gc_collections_total" + label) == 1
+    assert _sample(collector, "python_gc_pause_seconds_count" + label) == 1
+    assert _sample(collector, "python_gc_pause_seconds_sum" + label) == \
+        pytest.approx(pause_ns / 1e9)
+    assert _sample(collector, "python_gc_pause_seconds_max" + label) == \
+        pytest.approx(pause_ns / 1e9)
+    # the heap's blocks are read at the install and at the end of a FULL
+    # collection only: the reading walks every pool of the heap
+    keep = [[i] for i in range(5000)]
+    gc.collect(generation)
+    grew = _sample(collector, "python_allocated_blocks") - blocks_at_install
+    assert (grew >= len(keep)) == (generation == 2)
+
+
+def test_installing_twice_leaves_one_entry_and_removal_none(collector):
+    tracing.watch_collector(True)
+    assert gc.callbacks.count(tracing._on_collection) == 1
+    assert collector._refreshes.count(tracing._publish_collector) == 1
+    tracing.watch_collector(False)
+    assert tracing._on_collection not in gc.callbacks
+    assert tracing._publish_collector not in collector._refreshes
+    gc.collect()
+    assert tracing.gc_pause_ns() == 0
+
+
+def test_a_span_a_collection_falls_inside_gets_gcms_and_its_own_sample(
+        collector):
+    tracer = Tracer()
+    with tracer.span("http.request", label_keys=("endpoint",),
+                     endpoint="PROPOSALS") as outer:
+        with tracer.span("render") as inside:
+            gc.collect()
+        with tracer.span("http.write") as beside:
+            pass
+    with tracer.span("http.request", label_keys=("endpoint",),
+                     endpoint="STATE") as after:
+        pass
+    pause_ms = tracing.gc_pause_ns() / 1e6
+    assert inside.attributes["gcMs"] == pytest.approx(pause_ms, abs=1e-3)
+    assert outer.attributes["gcMs"] == inside.attributes["gcMs"]
+    assert "gcMs" not in beside.attributes and "gcMs" not in after.attributes
+    # under the labels of the span's own trace_span_seconds series
+    counter = "trace_span_gc_seconds_total"
+    assert _sample(collector, counter + '{span="render"}') == \
+        pytest.approx(pause_ms / 1e3)
+    assert _sample(
+        collector, counter + '{endpoint="PROPOSALS",span="http.request"}') \
+        == pytest.approx(pause_ms / 1e3)
+    assert _sample(collector, "trace_span_seconds_count"
+                   '{endpoint="PROPOSALS",span="http.request"}') == 1
+    assert _sample(collector, counter + '{span="http.write"}') is None
+    assert _sample(
+        collector, counter + '{endpoint="STATE",span="http.request"}') is None
+    # GET /trace's shape carries the attribute
+    exported = tracer.traces()[1]["root"]
+    assert {"key": "gcMs", "value": {"doubleValue": outer.attributes["gcMs"]}} \
+        in exported["attributes"]
+
+
+def test_a_journey_segment_a_collection_falls_inside_feeds_its_counter(
+        collector, monkeypatch):
+    from cruise_control_tpu.serving import journey as journey_mod
+    monkeypatch.setattr(journey_mod, "SENSORS", collector)
+    log = journey_mod.JourneyLog()
+    journey = log.open("PROPOSALS")
+    with journey.seg("render"):
+        gc.collect()
+    with journey.seg("proposal_diff"):
+        pass
+    log.close(journey)
+    pause_s = tracing.gc_pause_ns() / 1e9
+    counter = "journey_segment_gc_seconds_total"
+    assert _sample(collector, counter
+                   + '{endpoint="PROPOSALS",segment="render"}') == \
+        pytest.approx(pause_s)
+    assert _sample(collector, counter
+                   + '{endpoint="PROPOSALS",segment="proposal_diff"}') is None
+    assert _sample(collector, "journey_segment_seconds_count"
+                   '{endpoint="PROPOSALS",segment="render"}') == 1
+    # a pause is real time and a journey's clock may be the twin's: the
+    # exported record stays what the injected clock made it
+    assert "gc" not in json.dumps(log.entries()).lower()
+
+
+@pytest.mark.parametrize("holder", ["SENSORS", "TRACER"])
+def test_a_collection_under_a_held_lock_returns(collector, holder):
+    """The callback takes no lock: a collection that starts inside the
+    registry's or the tracer's own ``with self._lock:`` block (an
+    allocation there is enough) must not stop its thread against itself."""
+    lock = collector._lock if holder == "SENSORS" else TRACER._lock
+    done = []
+
+    def collect_under_the_lock():
+        with lock:
+            gc.collect()
+        done.append(True)
+
+    thread = threading.Thread(target=collect_under_the_lock, daemon=True)
+    thread.start()
+    thread.join(5)
+    assert not thread.is_alive() and done == [True]
+    assert tracing._gc_collections[2] == 1
+
+
+def test_a_full_collection_is_one_gc_gen2_annotation(collector, monkeypatch):
+    events = []
+
+    class Recorder:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            events.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            events.append(("exit", self.name))
+
+    monkeypatch.setattr(tracing, "_TRACE_ANNOTATION", Recorder)
+    gc.collect(0)
+    gc.collect(1)
+    assert events == []      # the young generations are counted only
+    gc.collect()
+    assert events == [("enter", "cc.gc.gen2"), ("exit", "cc.gc.gen2")]
+
+
+def test_the_hook_follows_tracing_enabled(collector):
+    """``facade.py`` installs the hook where it configures the tracer: with
+    ``tracing.enabled=false`` the program has no entry in ``gc.callbacks``
+    and no ``python_gc_*`` series."""
+    tracing.watch_collector(False)
+    collector.clear()
+    backend = InMemoryAdminBackend(_partitions().values())
+
+    def facade(enabled):
+        cc = CruiseControl(CruiseControlConfig({
+            "tracing.enabled": enabled, "failed.brokers.file.path": ""}),
+            backend)
+        cc.shutdown()
+
+    try:
+        facade("false")
+        assert not TRACER.enabled
+        assert tracing._on_collection not in gc.callbacks
+        assert "python_gc" not in collector.render()
+        facade("true")
+        assert gc.callbacks.count(tracing._on_collection) == 1
+        assert _sample(collector, "python_gc_collections_total"
+                       '{generation="2"}') is not None
+        facade("false")
+        assert tracing._on_collection not in gc.callbacks
+    finally:
+        TRACER.configure(enabled=True)
+
+
+def test_get_trace_shows_gcms_on_the_request_that_paid_the_pause(
+        traced_api, collector, monkeypatch):
+    monitor = traced_api._cc._load_monitor
+    real = monitor.cluster_model
+
+    def collect_then_build(*args, **kwargs):
+        gc.collect()
+        return real(*args, **kwargs)
+
+    def rebalance_trace():
+        status, body, _ = traced_api.handle(
+            "POST", "/kafkacruisecontrol/rebalance", "dryrun=true")
+        assert status == 200, body
+        status, body, _ = traced_api.handle(
+            "GET", "/kafkacruisecontrol/trace",
+            "operation=rebalance&entries=1")
+        return list(_walk(body["traces"][0]["root"]))
+
+    monkeypatch.setattr(monitor, "cluster_model", collect_then_build)
+    paid = {node["name"]: _attrs(node) for node in rebalance_trace()}
+    assert paid["rebalance"]["gcMs"] > 0
+    # the pause fell before the model build opened its span
+    assert "gcMs" not in paid["monitor.cluster_model"]
+    monkeypatch.setattr(monitor, "cluster_model", real)
+    assert all("gcMs" not in _attrs(node) for node in rebalance_trace())

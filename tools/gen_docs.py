@@ -60,7 +60,7 @@ def gen_sensors() -> str:
     SENSORS.count/gauge/record_timer call sites, plus the /metrics extra
     gauges assembled in api/server.py."""
     pattern = re.compile(
-        r"SENSORS\.(count|gauge|record_timer|observe)\("
+        r"SENSORS\.(count|gauge|record_timer|observe|set_counter|set_timer)\("
         r"\s*[\"']([a-z0-9_]+)[\"']")
     extra = re.compile(r"extra\[[\"']([a-z0-9_]+)[\"']\]")
     found: dict[str, tuple[str, str]] = {}
@@ -90,7 +90,8 @@ def gen_sensors() -> str:
     for name in sorted(found):
         kind, where = found[name]
         kind = {"count": "counter", "gauge": "gauge",
-                "record_timer": "timer", "observe": "histogram"}[kind]
+                "record_timer": "timer", "observe": "histogram",
+                "set_counter": "counter", "set_timer": "timer"}[kind]
         lines.append(f"| `kafka_cruisecontrol_{name}` | {kind} | `{where}` |")
     lines.append("")
     return "\n".join(lines)
