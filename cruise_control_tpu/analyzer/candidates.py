@@ -147,11 +147,12 @@ class CandidateGrid:
 @partial(jax.tree_util.register_dataclass,
          data_fields=["src_broker", "dst_broker", "load_delta", "replica_delta",
                       "leader_delta", "partition", "topic", "src_slot",
-                      "dst_slot", "valid", "pre_src_load", "pre_dst_load",
-                      "pre_src_count", "pre_dst_count", "pre_src_leaders",
-                      "pre_dst_leaders", "pre_src_topic_count",
-                      "pre_dst_topic_count", "pre_src_topic_leaders",
-                      "pre_dst_pot", "pre_dst_lbi", "grid"],
+                      "dst_slot", "valid", "src_offline", "pre_src_load",
+                      "pre_dst_load", "pre_src_count", "pre_dst_count",
+                      "pre_src_leaders", "pre_dst_leaders",
+                      "pre_src_topic_count", "pre_dst_topic_count",
+                      "pre_src_topic_leaders", "pre_dst_pot", "pre_dst_lbi",
+                      "grid"],
          meta_fields=[])
 @dataclasses.dataclass(frozen=True)
 class CandidateDeltas:
@@ -184,6 +185,9 @@ class CandidateDeltas:
     src_slot: jax.Array      # [N] int32
     dst_slot: jax.Array      # [N] int32 (leadership target slot; 0 for moves)
     valid: jax.Array         # [N] bool
+    # [N] bool: the moving replica is OFFLINE (its broker is dead), False
+    # on invalid candidates; compute_deltas sets it, a swap's net leaves it
+    src_offline: jax.Array | None = None
     pre_src_load: jax.Array | None = None        # [N, R]
     pre_dst_load: jax.Array | None = None        # [N, R]
     pre_src_count: jax.Array | None = None       # [N] f32
@@ -425,6 +429,7 @@ def compute_deltas(state: ClusterTensors, derived: DerivedState,
         src_slot=jnp.where(valid, from_rows(src_slot), 0),
         dst_slot=jnp.where(valid & ~is_move_n, cand.dst_slot, 0),
         valid=valid,
+        src_offline=valid & src_offline,
         grid=grid,
     )
 

@@ -34,9 +34,7 @@ from typing import Callable, NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 
-from ..model.tensors import (
-    ClusterTensors, offline_per_broker, offline_replicas,
-)
+from ..model.tensors import ClusterTensors, offline_replicas
 from .agg import (
     AggCarry, apply_deltas_to_agg, compute_agg, maybe_refresh, pot_lbi_deltas,
 )
@@ -46,7 +44,7 @@ from .candidates import (
 )
 from .fill import targets_enabled
 from .constraint import BalancingConstraint
-from .derived import DerivedState, compute_derived
+from .derived import DerivedState, compute_derived, healing
 from .goals.base import Goal
 from .search import (
     _EPS_IMPROVEMENT, _OFFLINE_BONUS, ExclusionMasks,
@@ -265,6 +263,37 @@ class ScoredCandidates(NamedTuple):
     is_active: jax.Array      # [G]
     independent: jax.Array    # the active goal lifts the per-round move cap
     targets: bool             # static: the move block's last column is targeted
+    heals: jax.Array          # scalar: the round took the healing branch
+
+
+def _self_healing(state: ClusterTensors, derived: DerivedState,
+                  src_score: jax.Array, weight: jax.Array,
+                  is_lead_only: jax.Array,
+                  ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The self-healing priority (ClusterModel.selfHealingEligibleReplicas):
+    replicas stranded on dead brokers are always sources with maximal
+    weight for non-leadership goals; moving one scores a bonus in
+    ``_scored_candidates`` so it wins over pure balance refinements.
+    Returns (src_score, weight, offline, heals): ``offline`` is
+    ``derived.healing`` (a replica is offline), ``heals`` whether this
+    round took the branch that builds the per-slot mask.
+
+    A replica is offline exactly when its broker is DEAD, so the source
+    term per broker is the dead brokers' replica counts, which the carry
+    already holds (global on a mesh: no collective here). The [P, S] mask
+    is built only while a replica is offline (docs/DESIGN.md "The move
+    round"); neither branch of the ``cond`` holds a collective, and its
+    predicate is replicated on the mesh, so every device takes the same
+    branch."""
+    offline = healing(derived)
+    heals = offline & ~is_lead_only
+    offline_pb = jnp.where(derived.alive, 0.0,
+                           derived.broker_replicas.astype(jnp.float32))
+    src_score = src_score + jnp.where(is_lead_only, 0.0, offline_pb)
+    weight = jax.lax.cond(
+        heals, lambda w: jnp.where(offline_replicas(state), 1e30, w),
+        lambda w: w, weight)
+    return src_score, weight, offline, heals
 
 
 def _scored_candidates(state: ClusterTensors, agg: "AggCarry | None",
@@ -311,17 +340,9 @@ def _scored_candidates(state: ClusterTensors, agg: "AggCarry | None",
                 _chain_scores(state, derived, active_idx, prior_mask, goals,
                               constraint, num_topics, agg, psum=psum)
 
-        # Self-healing priority: replicas stranded on dead brokers are
-        # always sources with maximal weight for non-leadership goals, and
-        # moving one scores a large bonus below so it wins over pure
-        # balance refinements (ClusterModel.selfHealingEligibleReplicas).
         with jax.named_scope("round.score_offline"):
-            off = offline_replicas(state)  # [P, S]
-            offline_pb = offline_per_broker(state, off)
-            if psum is not None:
-                offline_pb = psum(offline_pb)
-            src_score = src_score + jnp.where(is_lead_only, 0.0, offline_pb)
-            weight = jnp.where(off & ~is_lead_only, 1e30, weight)
+            src_score, weight, offline, heals = _self_healing(
+                state, derived, src_score, weight, is_lead_only)
 
     # UNIFORM grid layout: both the move and the leadership block always
     # exist (static shapes shared by every goal); the active goal's traced
@@ -347,8 +368,7 @@ def _scored_candidates(state: ClusterTensors, agg: "AggCarry | None",
         t_dst, t_ok = _switch_target_dests(active_idx, goals, aux_list,
                                            state, derived, constraint,
                                            cand_p, cand_s, src_valid)
-        any_offline = off.any() if psum is None else psum(off.sum()) > 0
-        extra = (t_dst, t_ok & ~any_offline)
+        extra = (t_dst, t_ok & ~offline)
     cand, layout = generate_candidates(state, derived, src_score, dst_score,
                                        weight, cfg.num_sources, cfg.num_dests,
                                        include_leadership=True,
@@ -378,8 +398,7 @@ def _scored_candidates(state: ClusterTensors, agg: "AggCarry | None",
             accept &= (~prior_mask[i]) | g.acceptance(
                 state, derived, constraint, aux_list[i], deltas)
 
-        moving_offline = deltas.at_src_slot(off) \
-            & (deltas.replica_delta > 0)
+        moving_offline = deltas.src_offline & (deltas.replica_delta > 0)
 
         imp = jax.lax.switch(active_idx,
                              [imp_branch(i) for i in range(len(goals))], 0)
@@ -389,7 +408,7 @@ def _scored_candidates(state: ClusterTensors, agg: "AggCarry | None",
 
     independent = indep_f[active_idx] & ~prior_mask.any()
     return ScoredCandidates(derived, aux_list, cand, layout, deltas, accept,
-                            score, is_active, independent, targets)
+                            score, is_active, independent, targets, heals)
 
 
 def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
@@ -416,8 +435,10 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
     never a new selection input: the trajectory is byte-identical with
     collection on or off (pinned in tests/test_flight_recorder.py).
     ``"tally"`` (the whole-chain dispatch, which keeps no ring) returns in
-    its place the row's two sums that price the acceptance stack,
-    ``[valid, accepted]``: two reductions over the candidate axis.
+    its place the row's two sums that price the acceptance stack and
+    whether the round took the self-healing branch,
+    ``[valid, accepted, heals]``: two reductions over the candidate axis
+    and a scalar.
 
     The selection's phases carry the scopes ``round.select``,
     ``round.apply`` and ``round.flight_stats`` (``round.agg_refresh`` in
@@ -477,7 +498,8 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
     elif stats == "tally":
         with jax.named_scope("round.flight_stats"):
             stat = jnp.stack([deltas.valid.sum().astype(jnp.float32),
-                              sc.accept.sum().astype(jnp.float32)])
+                              sc.accept.sum().astype(jnp.float32),
+                              sc.heals.astype(jnp.float32)])
     return new_state, agg, applied, stat
 
 
@@ -857,9 +879,11 @@ def chain_optimize_full(state: ClusterTensors, goals: tuple[Goal, ...],
 
     Returns (final_state, per_goal_stats) where per_goal_stats is a dict of
     [G]-arrays: viol_before/after, obj_before/after, offline_before,
-    moves, swaps, rounds, and cand_valid / cand_accepted (f32: each goal's
-    move rounds' valid candidates, and those of them that every earlier
-    goal's acceptance let through; ``_chain_round_body`` ``stats="tally"``).
+    moves, swaps, rounds, and cand_valid / cand_accepted / healing_rounds
+    (f32: each goal's move rounds' valid candidates, those of them that
+    every earlier goal's acceptance let through, and the move rounds that
+    took the self-healing branch; ``_chain_round_body``
+    ``stats="tally"``).
     """
     g_count = len(goals)
     supports_swap = jnp.asarray([g.supports_swap for g in goals])
@@ -932,14 +956,14 @@ def chain_optimize_full(state: ClusterTensors, goals: tuple[Goal, ...],
 
             s, a, tally, m, sw, rounds, _, _ = jax.lax.while_loop(
                 outer_cond, outer_body,
-                (s, compute_agg(s, num_topics), jnp.zeros(2, jnp.float32),
+                (s, compute_agg(s, num_topics), jnp.zeros(3, jnp.float32),
                  jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0),
                  jnp.bool_(True)))
             return s, m, sw, rounds, tally
 
         def skip(s):
             return (s, jnp.int32(0), jnp.int32(0), jnp.int32(0),
-                    jnp.zeros(2, jnp.float32))
+                    jnp.zeros(3, jnp.float32))
 
         new_state, moves, swaps, rounds, tally = jax.lax.cond(
             (viol0 > 0) | (offline0 > 0) | drain_pending(carry_state),
@@ -950,7 +974,8 @@ def chain_optimize_full(state: ClusterTensors, goals: tuple[Goal, ...],
               "offline_before": offline0, "viol_after": viol1,
               "obj_after": obj1, "offline_after": offline1,
               "moves": moves, "swaps": swaps, "rounds": rounds,
-              "cand_valid": tally[0], "cand_accepted": tally[1]}
+              "cand_valid": tally[0], "cand_accepted": tally[1],
+              "healing_rounds": tally[2]}
         return new_state, ys
 
     final_state, stats = jax.lax.scan(
@@ -1049,6 +1074,8 @@ def _chain_infos_from_stats(goals: tuple[Goal, ...], stats: dict,
             infos[-1]["candidates_valid"] = float(stats["cand_valid"][i])
             # ccsa: ok[CCSA001] decode of already-fetched host stats scalars
             infos[-1]["candidates_accepted"] = float(stats["cand_accepted"][i])
+            # ccsa: ok[CCSA001] decode of already-fetched host stats scalars
+            infos[-1]["healing_rounds"] = int(stats["healing_rounds"][i])
     return infos
 
 

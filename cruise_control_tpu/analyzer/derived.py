@@ -113,14 +113,21 @@ def compute_derived(state: ClusterTensors,
     )
 
 
+def healing(derived: DerivedState) -> jax.Array:
+    """Scalar bool: a replica is offline, that is a DEAD broker still hosts
+    one (``offline_replicas(state).any()``, read from the per-broker counts
+    instead of every slot). Global on a mesh, as the counts are."""
+    return ((derived.broker_replicas > 0) & ~derived.alive).any()
+
+
 def dest_columns_ok(derived: DerivedState) -> jax.Array:
     """[B] bool: the brokers a round may offer as destination COLUMNS:
     ``replica_dest_ok``, widened to every broker allowed replica moves
-    while a replica is offline (a dead broker still hosts one), so that
-    self-healing is not held up by a scale-out. Which candidates of a
-    widened column are legitimate is still ``broker_masks_at``'s to say."""
-    healing = ((derived.broker_replicas > 0) & ~derived.alive).any()
-    return derived.replica_dest_ok | (healing & derived.allowed_replica_move)
+    while a replica is offline (``healing``), so that self-healing is not
+    held up by a scale-out. Which candidates of a widened column are
+    legitimate is still ``broker_masks_at``'s to say."""
+    return derived.replica_dest_ok \
+        | (healing(derived) & derived.allowed_replica_move)
 
 
 def broker_masks_at(derived: DerivedState, dst: jax.Array,

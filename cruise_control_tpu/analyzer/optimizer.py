@@ -171,7 +171,10 @@ def ensure_evacuated(goal_chain: Sequence[Goal], infos: Sequence[dict],
     goal's exit count), ``solver_evacuation_rounds_total`` (rounds of the
     goals entered with replicas offline), and ``offline_before``,
     ``offline_remaining``, ``excluded_brokers`` on the pass's
-    ``solver.dispatch`` spans under ``span``."""
+    ``solver.dispatch`` spans under ``span``. Where the route tallies them
+    (the whole-chain dispatch), the move rounds that built the per-slot
+    offline mask (``chain._self_healing``): ``solver_healing_rounds_total``
+    and ``healing_rounds`` on the same spans."""
     if not infos:
         return
     from ..utils.sensors import SENSORS
@@ -183,10 +186,16 @@ def ensure_evacuated(goal_chain: Sequence[Goal], infos: Sequence[dict],
                   labels={"when": "remaining"})
     SENSORS.count("solver_evacuation_rounds", sum(
         info["rounds"] for info in infos if info["offline_before"] > 0))
+    healing = None
+    if "healing_rounds" in infos[0]:
+        healing = sum(info["healing_rounds"] for info in infos)
+        SENSORS.count("solver_healing_rounds", healing)
     for dispatch in _dispatch_spans(span):
         dispatch.set(
             offline_before=before, offline_remaining=remaining,
             excluded_brokers=len(options.excluded_brokers_for_replica_move))
+        if healing is not None:
+            dispatch.set(healing_rounds=healing)
     if remaining == 0 or all(g.leadership_only for g in goal_chain):
         return
     from ..model.tensors import offline_replicas

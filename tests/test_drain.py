@@ -129,7 +129,8 @@ def test_drain_agrees_with_the_plain_reference(route, removed):
 
     before = (counter("solver_offline_replicas", when="before"),
               counter("solver_offline_replicas", when="remaining"),
-              counter("solver_evacuation_rounds"))
+              counter("solver_evacuation_rounds"),
+              counter("solver_healing_rounds"))
     cc = facade(dep, route)
     try:
         result = cc.remove_brokers(removed, dryrun=True)
@@ -163,6 +164,13 @@ def test_drain_agrees_with_the_plain_reference(route, removed):
         == len(forced)
     assert counter("solver_offline_replicas", when="remaining") == before[1]
     assert counter("solver_evacuation_rounds") > before[2]
+    # the whole-chain dispatch tallies the rounds that built the offline
+    # mask: some, and no more than the evacuation rounds
+    healed = counter("solver_healing_rounds") - before[3]
+    if route == "fused":
+        assert 0 < healed <= counter("solver_evacuation_rounds") - before[2]
+    else:
+        assert healed == 0
     dispatches = [s for s in spans(traces[0]["root"])
                   if s["name"] == "solver.dispatch"]
     # (the unbounded per-goal route opens goal.solve spans and no dispatch)
@@ -172,6 +180,8 @@ def test_drain_agrees_with_the_plain_reference(route, removed):
         assert attrs["offline_before"] == {"intValue": str(len(forced))}
         assert attrs["offline_remaining"] == {"intValue": "0"}
         assert attrs["excluded_brokers"] == {"intValue": str(len(removed))}
+        if route == "fused":
+            assert attrs["healing_rounds"] == {"intValue": str(int(healed))}
 
 
 def spans(node):
