@@ -503,14 +503,49 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
     return new_state, agg, applied, stat
 
 
+@partial(jax.tree_util.register_dataclass,
+         data_fields=["agg", "rounds", "last"], meta_fields=[])
+@dataclasses.dataclass(frozen=True)
+class PassCarry:
+    """What a bounded dispatch hands the next dispatch of the SAME pass, so
+    that a pass split at any dispatch boundaries walks the unsplit loop's
+    trajectory: the incrementally maintained aggregates (a fresh
+    recompute at each dispatch's entry would drop the f32 drift the
+    unsplit loop carries, and which rounds read fresh aggregates would
+    then follow the boundaries, which the adaptive controller sets by
+    wall-clock), the rounds the pass has run (the refresh cadence counts
+    them) and the applied count of its last round (a pass at its fixed
+    point runs no further round, so the pump's speculative successor
+    runs none). Device scalars, chained like the state: no readback."""
+
+    agg: AggCarry
+    rounds: jax.Array       # i32: rounds the pass ran before this dispatch
+    last: jax.Array         # i32: the last round's applied count
+
+
+def _pass_start(state: ClusterTensors, num_topics: int) -> PassCarry:
+    """The carry a pass starts from: aggregates computed afresh, no
+    round run yet."""
+    return PassCarry(compute_agg(state, num_topics), jnp.int32(0),
+                     jnp.int32(1))
+
+
+@partial(jax.jit, static_argnames=("num_topics",))
+def start_pass(state: ClusterTensors, num_topics: int) -> PassCarry:
+    """``_pass_start`` as its own program: the bounded route's pump makes
+    one a pass and chains it through the pass's dispatches."""
+    return _pass_start(state, num_topics)
+
+
 def _chain_rounds_driver(state: ClusterTensors, active_idx: jax.Array,
                          prior_mask: jax.Array, goals: tuple[Goal, ...],
                          constraint: BalancingConstraint, cfg: SearchConfig,
                          num_topics: int, masks: ExclusionMasks,
                          budget: jax.Array | None = None,
                          ring_rounds: int = 0,
+                         resume: PassCarry | None = None,
                          ) -> tuple[ClusterTensors, jax.Array, jax.Array,
-                                    "jax.Array | None"]:
+                                    "jax.Array | None", PassCarry]:
     """Traced body of the fused move driver — the MEGASTEP: up to
     ``budget`` round-bodies under one ``lax.while_loop`` whose carry is
     ``((state, agg), moves, rounds, last_applied)`` with ``last_applied``
@@ -523,15 +558,23 @@ def _chain_rounds_driver(state: ClusterTensors, active_idx: jax.Array,
     writes its flight-stats row at ``round % ring_rounds``, and the ring
     rides the dispatch's existing async readback (one more output
     tensor, ~3 KB at the default length — no extra host round-trip).
-    Returns (final_state, total_moves, rounds_run, ring-or-None)."""
+
+    ``resume`` (the bounded route) continues a pass from the previous
+    dispatch's ``PassCarry`` instead of starting one: the aggregates are
+    taken over, the refresh cadence counts the pass's rounds, and a pass
+    already at its fixed point runs no round.
+    Returns (final_state, total_moves, rounds_run, ring-or-None,
+    PassCarry for the pass's next dispatch)."""
     collect = ring_rounds > 0
+    if resume is None:
+        resume = _pass_start(state, num_topics)
 
     def body(carry, rounds_done):
         if collect:
             s, a, ring = carry
         else:
             s, a = carry
-        a = maybe_refresh(a, s, num_topics, rounds_done)
+        a = maybe_refresh(a, s, num_topics, resume.rounds + rounds_done)
         ns, na, applied, stat = _chain_round_body(
             s, a, active_idx, prior_mask, goals, constraint, cfg,
             num_topics, masks, stats="row" if collect else None)
@@ -540,17 +583,15 @@ def _chain_rounds_driver(state: ClusterTensors, active_idx: jax.Array,
             return (ns, na, ring), applied
         return (ns, na), applied
 
-    carry0 = (state, compute_agg(state, num_topics))
+    carry0 = (state, resume.agg)
     if collect:
         carry0 = carry0 + (jnp.zeros((ring_rounds, _FLIGHT_STATS),
                                      jnp.float32),)
-    final_carry, total, rounds = run_carry_loop(
-        body, carry0, cfg.max_rounds, budget=budget)
-    if collect:
-        final, _agg, ring = final_carry
-        return final, total, rounds, ring
-    final, _agg = final_carry
-    return final, total, rounds, None
+    final_carry, total, rounds, last = run_carry_loop(
+        body, carry0, cfg.max_rounds, budget=budget, last0=resume.last)
+    ring = final_carry[2] if collect else None
+    return (final_carry[0], total, rounds, ring,
+            PassCarry(final_carry[1], resume.rounds + rounds, last))
 
 
 @partial(jax.jit, static_argnames=("goals", "constraint", "cfg", "num_topics",
@@ -560,7 +601,8 @@ def chain_optimize_rounds(state: ClusterTensors, active_idx: jax.Array,
                           constraint: BalancingConstraint, cfg: SearchConfig,
                           num_topics: int, masks: ExclusionMasks,
                           budget: jax.Array | None = None,
-                          ring_rounds: int = 0):
+                          ring_rounds: int = 0,
+                          resume: PassCarry | None = None):
     """Fused multi-round driver for ANY goal in the chain: one compilation
     serves all G (active_idx, prior_mask) combinations. Returns
     (final_state, total_moves, rounds_run). ``budget`` (traced) further
@@ -569,16 +611,17 @@ def chain_optimize_rounds(state: ClusterTensors, active_idx: jax.Array,
     ``ring_rounds`` > 0 (static — the flight recorder's ON switch, one
     extra compilation per process when enabled) appends the per-round
     flight-stats ring as a FOURTH output; 0 keeps the 3-tuple contract.
+    ``resume`` (the bounded route's ``PassCarry``) appends the carry for
+    the pass's next dispatch as the LAST output.
 
     Aggregates are computed once at entry and maintained incrementally
     through the loop (analyzer.agg), with a periodic fresh recompute to
     bound f32 drift."""
-    final, total, rounds, ring = _chain_rounds_driver(
+    final, total, rounds, ring, carry = _chain_rounds_driver(
         state, active_idx, prior_mask, goals, constraint, cfg, num_topics,
-        masks, budget, ring_rounds=ring_rounds)
-    if ring_rounds > 0:
-        return final, total, rounds, ring
-    return final, total, rounds
+        masks, budget, ring_rounds=ring_rounds, resume=resume)
+    out = (final, total, rounds) + ((ring,) if ring_rounds > 0 else ())
+    return out + ((carry,) if resume is not None else ())
 
 
 def strip_mutable(state: ClusterTensors) -> ClusterTensors:
@@ -598,9 +641,10 @@ def strip_mutable(state: ClusterTensors) -> ClusterTensors:
 
 @partial(jax.jit, static_argnames=("goals", "constraint", "cfg",
                                    "num_topics", "ring_rounds"),
-         donate_argnums=(0, 1))
+         donate_argnums=(0, 1, 2))
 def chain_optimize_rounds_donated(assignment: jax.Array,
                                   leader_slot: jax.Array,
+                                  resume: PassCarry,
                                   rest: ClusterTensors,
                                   active_idx: jax.Array,
                                   prior_mask: jax.Array,
@@ -609,22 +653,23 @@ def chain_optimize_rounds_donated(assignment: jax.Array,
                                   cfg: SearchConfig, num_topics: int,
                                   masks: ExclusionMasks, budget: jax.Array,
                                   ring_rounds: int = 0):
-    """The donated megastep: identical trace to ``chain_optimize_rounds``
-    with the two mutable tensors donated, so XLA writes the new assignment
-    into the old buffers instead of allocating a fresh generation per
-    dispatch. Callers pass ``strip_mutable(state)`` as ``rest`` and must
-    not touch the donated arrays afterwards. Returns (assignment,
-    leader_slot, moves, rounds) — plus the flight-stats ring when
-    ``ring_rounds`` > 0 (chain_optimize_rounds; the ring is loop-created,
-    never part of the donation set)."""
+    """The donated megastep of the bounded route: the trace of
+    ``chain_optimize_rounds`` resuming ``resume``, with the two mutable
+    tensors and the pass's carry donated, so XLA writes the new
+    assignment into the old buffers instead of allocating a fresh
+    generation per dispatch. Callers pass ``strip_mutable(state)`` as
+    ``rest`` and must not touch the donated arrays afterwards. Returns
+    (assignment, leader_slot, moves, rounds, PassCarry) — with the
+    flight-stats ring before the carry when ``ring_rounds`` > 0
+    (chain_optimize_rounds; the ring is loop-created, never part of the
+    donation set)."""
     state = dataclasses.replace(rest, assignment=assignment,
                                 leader_slot=leader_slot)
-    final, total, rounds, ring = _chain_rounds_driver(
+    final, total, rounds, ring, carry = _chain_rounds_driver(
         state, active_idx, prior_mask, goals, constraint, cfg, num_topics,
-        masks, budget, ring_rounds=ring_rounds)
-    if ring_rounds > 0:
-        return final.assignment, final.leader_slot, total, rounds, ring
-    return final.assignment, final.leader_slot, total, rounds
+        masks, budget, ring_rounds=ring_rounds, resume=resume)
+    out = (final.assignment, final.leader_slot, total, rounds)
+    return out + ((ring,) if ring_rounds > 0 else ()) + (carry,)
 
 
 @jax.named_scope("swap.round")
@@ -685,19 +730,26 @@ def _chain_swap_driver(state: ClusterTensors, active_idx: jax.Array,
                        masks: ExclusionMasks, moves: int = 8,
                        max_rounds: int = 64,
                        budget: jax.Array | None = None,
-                       ) -> tuple[ClusterTensors, jax.Array, jax.Array]:
+                       resume: PassCarry | None = None,
+                       ) -> tuple[ClusterTensors, jax.Array, jax.Array,
+                                  PassCarry]:
+    """The swap twin of ``_chain_rounds_driver`` (``resume`` likewise)."""
+    if resume is None:
+        resume = _pass_start(state, num_topics)
+
     def body(carry, rounds_done):
         s, a = carry
-        a = maybe_refresh(a, s, num_topics, rounds_done)
+        a = maybe_refresh(a, s, num_topics, resume.rounds + rounds_done)
         ns, na, applied = _chain_swap_body(s, a, active_idx, prior_mask,
                                            goals, constraint, num_topics,
                                            masks, moves)
         return (ns, na), applied
 
-    (final, _agg), total, rounds = run_carry_loop(
-        body, (state, compute_agg(state, num_topics)), max_rounds,
-        budget=budget)
-    return final, total, rounds
+    (final, agg), total, rounds, last = run_carry_loop(
+        body, (state, resume.agg), max_rounds, budget=budget,
+        last0=resume.last)
+    return final, total, rounds, PassCarry(agg, resume.rounds + rounds,
+                                           last)
 
 
 @partial(jax.jit, static_argnames=("goals", "constraint", "num_topics",
@@ -708,18 +760,22 @@ def chain_swap_rounds(state: ClusterTensors, active_idx: jax.Array,
                       masks: ExclusionMasks, moves: int = 8,
                       max_rounds: int = 64,
                       budget: jax.Array | None = None,
-                      ) -> tuple[ClusterTensors, jax.Array, jax.Array]:
+                      resume: PassCarry | None = None):
     """Fused swap-phase driver, chain-parameterized (incremental-aggregate
-    carry, as chain_optimize_rounds)."""
-    return _chain_swap_driver(state, active_idx, prior_mask, goals,
-                              constraint, num_topics, masks, moves,
-                              max_rounds, budget)
+    carry, as chain_optimize_rounds): (final_state, swaps, rounds), and
+    the pass's next ``PassCarry`` last with ``resume``."""
+    final, total, rounds, carry = _chain_swap_driver(
+        state, active_idx, prior_mask, goals, constraint, num_topics, masks,
+        moves, max_rounds, budget, resume)
+    out = (final, total, rounds)
+    return out + ((carry,) if resume is not None else ())
 
 
 @partial(jax.jit, static_argnames=("goals", "constraint", "num_topics",
                                    "moves", "max_rounds"),
-         donate_argnums=(0, 1))
+         donate_argnums=(0, 1, 2))
 def chain_swap_rounds_donated(assignment: jax.Array, leader_slot: jax.Array,
+                              resume: PassCarry,
                               rest: ClusterTensors, active_idx: jax.Array,
                               prior_mask: jax.Array, goals: tuple[Goal, ...],
                               constraint: BalancingConstraint,
@@ -727,14 +783,14 @@ def chain_swap_rounds_donated(assignment: jax.Array, leader_slot: jax.Array,
                               moves: int, max_rounds: int,
                               budget: jax.Array,
                               ) -> tuple[jax.Array, jax.Array, jax.Array,
-                                         jax.Array]:
+                                         jax.Array, PassCarry]:
     """Donated swap megastep (see chain_optimize_rounds_donated)."""
     state = dataclasses.replace(rest, assignment=assignment,
                                 leader_slot=leader_slot)
-    final, total, rounds = _chain_swap_driver(
+    final, total, rounds, carry = _chain_swap_driver(
         state, active_idx, prior_mask, goals, constraint, num_topics, masks,
-        moves, max_rounds, budget)
-    return final.assignment, final.leader_slot, total, rounds
+        moves, max_rounds, budget, resume)
+    return final.assignment, final.leader_slot, total, rounds, carry
 
 
 @jax.named_scope("goal.stats")
@@ -1212,7 +1268,7 @@ class DispatchStats:
     without threading state through every driver."""
 
     def __init__(self):
-        self.rounds_per_dispatch: list[int] = []
+        self.rounds_per_dispatch: list[int] = []     # speculative left out
         self.donated = 0
         self.speculative = 0
         self.by_kind: dict[str, int] = {}
@@ -1225,26 +1281,31 @@ class DispatchStats:
         self.fingerprint = None
 
     def record(self, kind: str, rounds: int, donated: bool = False,
-               speculative: bool = False, telemetry: bool = True) -> None:
+               speculative: bool = False, telemetry: bool = True,
+               grid: str | None = None) -> None:
         """``telemetry=False`` keeps the tally local: the megabatch pump
         splits ONE physical dispatch into per-cluster accounting records,
         and only the physical record may hit the solver_dispatches
-        sensors (a 4-cluster dispatch is one XLA execution, not four)."""
-        self.rounds_per_dispatch.append(int(rounds))
+        sensors (a 4-cluster dispatch is one XLA execution, not four).
+        ``grid`` labels the sensors (``utils.xla_telemetry.record_dispatch``).
+        A speculative dispatch counts as a dispatch and stays out of
+        ``rounds_per_dispatch``: it starts on the pass's fixed point."""
+        if speculative:
+            self.speculative += 1
+        else:
+            self.rounds_per_dispatch.append(int(rounds))
         self.by_kind[kind] = self.by_kind.get(kind, 0) + 1
         if donated:
             self.donated += 1
-        if speculative:
-            self.speculative += 1
         if not telemetry:
             return
         from ..utils.xla_telemetry import record_dispatch
         record_dispatch(kind, int(rounds), donated=donated,
-                        speculative=speculative)
+                        speculative=speculative, grid=grid)
 
     @property
     def dispatch_count(self) -> int:
-        return len(self.rounds_per_dispatch)
+        return len(self.rounds_per_dispatch) + self.speculative
 
     def rounds_p50(self) -> float:
         if not self.rounds_per_dispatch:
@@ -1305,7 +1366,7 @@ def run_bounded_pass(enqueue: Callable, st, pass_cap: int,
                      async_readback: bool = True,
                      stats: DispatchStats | None = None,
                      kind: str = "move",
-                     flight=NO_FLIGHT):
+                     flight=NO_FLIGHT, grid: str | None = None):
     """Drive one logical pass (a fixed-point loop of at most ``pass_cap``
     search rounds) as a sequence of bounded megastep dispatches.
 
@@ -1334,13 +1395,21 @@ def run_bounded_pass(enqueue: Callable, st, pass_cap: int,
     pinning the budget at its floor.
 
     A dispatch that reports fewer rounds than its budget hit the pass's
-    fixed point; the speculatively-enqueued successor (if any) re-runs a
-    single zero-apply round that leaves the state byte-identical and
-    applies nothing — it is recorded in ``stats`` (speculative=True) but
+    fixed point; the speculatively-enqueued successor (if any) starts on
+    that fixed point — resuming the pass (``PassCarry``, the
+    single-device route) it runs no round; the mesh's kernels, which
+    start a pass afresh, re-run the zero-apply round — and applies
+    nothing: it is recorded in ``stats`` (speculative=True) but
     contributes neither moves nor rounds to the pass totals, so the
     round budget matches the synchronous path's exactly. Trajectory is
     invariant to all of it: same round sequence, only dispatch boundaries
     and readback timing differ.
+
+    ``grid`` labels the dispatches in ``stats`` and the pass's
+    ``solver.dispatch`` span, which also gets ``pass_rounds``,
+    ``speculative`` (the pass's speculative dispatches) and
+    ``budget_max`` (its largest round budget): values the pump holds
+    anyway, no readback added.
 
     Returns (st, applied_total, pass_rounds)."""
     applied_total = 0
@@ -1349,6 +1418,8 @@ def run_bounded_pass(enqueue: Callable, st, pass_cap: int,
     prev = None    # (applied, rounds, budget, t0, donated, ring) — unread
     last_read_t = None
     converged = False
+    speculative = 0
+    budget_max = 0
     with TRACER.span("solver.dispatch", route="bounded",
                      kind=kind) as dispatch:
         while True:
@@ -1357,6 +1428,7 @@ def run_bounded_pass(enqueue: Callable, st, pass_cap: int,
             if may_enqueue and not converged and est_rounds < pass_cap \
                     and not (out_of_time is not None and out_of_time()):
                 budget = controller.budget(pass_cap - est_rounds)
+                budget_max = max(budget_max, budget)
                 t0 = _time.monotonic()
                 with TRACER.span("solver.enqueue"):
                     st, applied, r, donated, ring = enqueue(st, budget)
@@ -1379,7 +1451,7 @@ def run_bounded_pass(enqueue: Callable, st, pass_cap: int,
                 controller.observe(r_read, budget_p, now - start)
                 last_read_t = now
                 if stats is not None:
-                    stats.record(kind, r_read, donated=donated_p)
+                    stats.record(kind, r_read, donated=donated_p, grid=grid)
                 # ccsa: ok[CCSA001] same readback point, applied_p already read
                 flight.dispatch(kind, budget_p, r_read, int(applied_p),
                                 donated=donated_p, elapsed_s=now - start,
@@ -1389,19 +1461,17 @@ def run_bounded_pass(enqueue: Callable, st, pass_cap: int,
                 if r_read < budget_p:
                     converged = True
             if converged and cur is not None:
-                # Speculative post-convergence dispatch: one re-run of the
-                # terminal zero-apply round (state frozen on device, applies
-                # nothing). Its rounds are NOT added to pass_rounds — they
-                # make no search progress, and counting them would consume
-                # cfg.max_rounds budget the synchronous per-round path does
-                # not pay, diverging the paths at the round-cap boundary.
-                # Its ring rows repeat the terminal round — dropped for the
-                # same reason.
+                # Speculative post-convergence dispatch: it started on
+                # the pass's fixed point and applied nothing (state
+                # untouched on device; a resumed pass runs no round).
+                # Nothing of it is added to the pass totals; its ring
+                # rows, if any, repeat the terminal round.
+                speculative += 1
                 if stats is not None:
                     # ccsa: ok[CCSA001] post-convergence drain: nothing left to
                     # pipeline behind this readback — the pass is over
                     stats.record(kind, int(cur[1]), donated=cur[4],
-                                 speculative=True)
+                                 speculative=True, grid=grid)
                 # ccsa: ok[CCSA001] post-convergence drain, same as above
                 flight.dispatch(kind, cur[2], int(cur[1]), 0, donated=cur[4],
                                 speculative=True, controller_k=controller.k)
@@ -1410,7 +1480,10 @@ def run_bounded_pass(enqueue: Callable, st, pass_cap: int,
             if prev is None and (converged or est_rounds >= pass_cap
                                  or (out_of_time is not None and out_of_time())):
                 break
-        dispatch.set(rounds=pass_rounds)
+        dispatch.set(rounds=pass_rounds, pass_rounds=pass_rounds,
+                     speculative=speculative, budget_max=budget_max)
+        if grid is not None:
+            dispatch.set(grid=grid)
         _set_traced_forms(dispatch, kind)
     return st, applied_total, pass_rounds
 
@@ -2179,6 +2252,7 @@ def optimize_goal_in_chain(state: ClusterTensors, chain: Sequence[Goal],
                            flight=NO_FLIGHT,
                            entry_stats: tuple | None = None,
                            drain_hint: bool | None = None,
+                           grid: str = "narrow",
                            ) -> tuple[ClusterTensors, dict]:
     """Run goal ``chain[index]`` to convergence under the acceptance of
     ``chain[:index]``, using the chain-shared kernels (same semantics and
@@ -2232,6 +2306,10 @@ def optimize_goal_in_chain(state: ClusterTensors, chain: Sequence[Goal],
     the hint holds the exact values that dispatch would have returned.
     ``drain_hint`` is the matching precomputed drain-pending bool (drain
     is goal-independent, a function of state + masks only).
+
+    ``grid`` names the grid ``cfg`` is, ``"narrow"`` or ``"wide"`` (the
+    optimizer's widened grid): the label of the goal's dispatches in the
+    ``solver_dispatch*`` series and on their ``solver.dispatch`` spans.
     """
     goal_t0 = _time.monotonic()
 
@@ -2332,12 +2410,13 @@ def optimize_goal_in_chain(state: ClusterTensors, chain: Sequence[Goal],
                 st, applied, r = chain_swap_rounds(
                     st, idx, prior, goals, constraint, num_topics, masks)
             if stats is not None:
-                stats.record(phase, int(r))
+                stats.record(phase, int(r), grid=grid)
             flight.dispatch(phase, pass_cap, int(r), int(applied),
                             ring=ring)
             return st, int(applied), int(r)
 
-        def enqueue(st, budget: int):
+        def enqueue(sc, budget: int):
+            st, carry = sc
             b = jnp.int32(budget)
             ring = None
             if donate:
@@ -2350,34 +2429,40 @@ def optimize_goal_in_chain(state: ClusterTensors, chain: Sequence[Goal],
                 rest = strip_mutable(st)
                 if phase == "move":
                     out = chain_optimize_rounds_donated(
-                        st.assignment, st.leader_slot, rest, idx, prior,
-                        goals, constraint, cfg, num_topics, masks, b,
+                        st.assignment, st.leader_slot, carry, rest, idx,
+                        prior, goals, constraint, cfg, num_topics, masks, b,
                         ring_rounds=ring_n)
                     a, l, applied, r = out[:4]
-                    ring = out[4] if ring_n > 0 else None
                 else:
-                    a, l, applied, r = chain_swap_rounds_donated(
-                        st.assignment, st.leader_slot, rest, idx, prior,
-                        goals, constraint, num_topics, masks, 8, 64, b)
+                    out = chain_swap_rounds_donated(
+                        st.assignment, st.leader_slot, carry, rest, idx,
+                        prior, goals, constraint, num_topics, masks, 8, 64,
+                        b)
+                    a, l, applied, r = out[:4]
                 st = dataclasses.replace(st, assignment=a, leader_slot=l)
             elif phase == "move":
                 out = chain_optimize_rounds(
                     st, idx, prior, goals, constraint, cfg, num_topics,
-                    masks, budget=b, ring_rounds=ring_n)
+                    masks, budget=b, ring_rounds=ring_n, resume=carry)
                 st, applied, r = out[:3]
-                ring = out[3] if ring_n > 0 else None
             else:
-                st, applied, r = chain_swap_rounds(
+                out = chain_swap_rounds(
                     st, idx, prior, goals, constraint, num_topics, masks,
-                    budget=b)
+                    budget=b, resume=carry)
+                st, applied, r = out[:3]
+            if phase == "move" and ring_n > 0:
+                ring = out[-2]
             can_donate[0] = True
-            return st, applied, r, donate, ring
+            return (st, out[-1]), applied, r, donate, ring
 
-        return run_bounded_pass(
-            enqueue, st, pass_cap, dispatch,
+        # One carry a pass: the pass's dispatches chain it on device, so
+        # any split of the pass walks the same rounds (PassCarry).
+        (st, _carry), applied, rounds_run = run_bounded_pass(
+            enqueue, (st, start_pass(st, num_topics)), pass_cap, dispatch,
             out_of_time=out_of_time if wall_budget_s > 0 else None,
             async_readback=async_rb, stats=stats, kind=phase,
-            flight=flight)
+            flight=flight, grid=grid)
+        return st, applied, rounds_run
 
     # Fast path (parity with chain_optimize_full's per-goal lax.cond skip
     # and the sharded bounded driver): nothing violated, nothing offline,

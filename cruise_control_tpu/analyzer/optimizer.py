@@ -850,7 +850,8 @@ class GoalOptimizer:
                 megastep=megastep, stats=stats, donate_input=False,
                 flight=flight_pass)
             if not bounded:
-                stats.record("chain", sum(i["rounds"] for i in infos))
+                stats.record("chain", sum(i["rounds"] for i in infos),
+                             grid="fused")
                 flight_pass.record_goal_infos(infos)
             goal_results = _apportioned_goal_results(
                 goal_chain, infos, time.time() - t0)
@@ -864,7 +865,8 @@ class GoalOptimizer:
             state, infos = optimize_chain(
                 state, goal_chain, self._constraint, search_cfg,
                 meta.num_topics, masks)
-            stats.record("chain", sum(i["rounds"] for i in infos))
+            stats.record("chain", sum(i["rounds"] for i in infos),
+                         grid="fused")
             flight_pass.record_goal_infos(infos)
             goal_results = _apportioned_goal_results(
                 goal_chain, infos, time.time() - t0)
@@ -966,7 +968,8 @@ class GoalOptimizer:
                         flight=flight_pass.goal(g.name),
                         entry_stats=entry,
                         drain_hint=hint_drain if entry is not None
-                        else None)
+                        else None,
+                        grid="wide" if use_wide or fast else "narrow")
                     chain_owns_state |= info["rounds"] > 0 \
                         or info.get("direct_sweeps", 0) > 0
                     gsp.set(rounds=info["rounds"],
@@ -1461,7 +1464,7 @@ class GoalOptimizer:
             megabatch_all_goal_stats, megabatch_goal_stats,
             megabatch_optimize_rounds, megabatch_optimize_rounds_donated,
             megabatch_swap_rounds, megabatch_swap_rounds_donated,
-            stack_states, strip_mutable,
+            stack_states, start_pass, strip_mutable,
         )
         from .goals import ALL_GOALS
         names = entry.get("goals") or []
@@ -1566,13 +1569,15 @@ class GoalOptimizer:
             if donate and bounded:
                 wait(chain_optimize_rounds_donated(
                     jnp.copy(state.assignment), jnp.copy(state.leader_slot),
-                    strip_mutable(state), idx, prior, goals, constraint, c,
-                    num_topics, masks, zero, ring_rounds=ring_n))
+                    start_pass(state, num_topics), strip_mutable(state), idx,
+                    prior, goals, constraint, c, num_topics, masks, zero,
+                    ring_rounds=ring_n))
             elif bounded:
                 wait(chain_optimize_rounds(state, idx, prior, goals,
                                            constraint, c, num_topics, masks,
-                                           budget=zero,
-                                           ring_rounds=ring_n))
+                                           budget=zero, ring_rounds=ring_n,
+                                           resume=start_pass(state,
+                                                             num_topics)))
             else:
                 wait(chain_optimize_rounds(state, idx, prior, goals,
                                            constraint, c, num_topics, masks,
@@ -1580,11 +1585,12 @@ class GoalOptimizer:
         if donate and bounded:
             wait(chain_swap_rounds_donated(
                 jnp.copy(state.assignment), jnp.copy(state.leader_slot),
-                strip_mutable(state), idx, prior, goals, constraint,
-                num_topics, masks, 8, 64, zero))
+                start_pass(state, num_topics), strip_mutable(state), idx,
+                prior, goals, constraint, num_topics, masks, 8, 64, zero))
         elif bounded:
             wait(chain_swap_rounds(state, idx, prior, goals, constraint,
-                                   num_topics, masks, budget=zero))
+                                   num_topics, masks, budget=zero,
+                                   resume=start_pass(state, num_topics)))
         else:
             wait(chain_swap_rounds(state, idx, prior, goals, constraint,
                                    num_topics, masks))

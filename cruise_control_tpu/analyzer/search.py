@@ -199,7 +199,8 @@ def cumulative_select(state: ClusterTensors, deltas, score: jax.Array,
     return idx, sel, sub, pot, lbi
 
 
-def run_carry_loop(round_body, carry0, max_rounds: int, budget=None):
+def run_carry_loop(round_body, carry0, max_rounds: int, budget=None,
+                   last0=None):
     """Generic fused-driver scaffold: iterate ``round_body(carry, rounds)
     -> (carry, applied)`` under ``lax.while_loop`` until a round applies
     nothing (or ``max_rounds``) entirely on device — ONE host round-trip
@@ -220,7 +221,13 @@ def run_carry_loop(round_body, carry0, max_rounds: int, budget=None):
     round-trip; the host detects convergence purely from the returned
     ``rounds_run < budget``. That detectability is what the async
     readback pump and its speculative post-convergence dispatch rely on
-    (chain.run_bounded_pass)."""
+    (chain.run_bounded_pass).
+
+    ``last0`` (optional TRACED int) resumes a pass that an earlier call
+    left: the applied count of its last round, so a call that starts on
+    a fixed point (``last0 == 0``) runs no round at all. With it the loop
+    also returns its own last applied count, the next call's ``last0``:
+    (final_carry, total_applied, rounds_run, last_applied)."""
     cap = max_rounds if budget is None else jnp.minimum(
         jnp.int32(max_rounds), budget.astype(jnp.int32))
 
@@ -234,9 +241,13 @@ def run_carry_loop(round_body, carry0, max_rounds: int, budget=None):
         applied = applied.astype(jnp.int32)
         return carry, total + applied, rounds + 1, applied
 
-    final, total, rounds, _ = jax.lax.while_loop(
-        cond, body, (carry0, jnp.int32(0), jnp.int32(0), jnp.int32(1)))
-    return final, total, rounds
+    final, total, rounds, last = jax.lax.while_loop(
+        cond, body, (carry0, jnp.int32(0), jnp.int32(0),
+                     jnp.int32(1) if last0 is None
+                     else last0.astype(jnp.int32)))
+    if last0 is None:
+        return final, total, rounds
+    return final, total, rounds, last
 
 
 def run_rounds_loop(round_body, state: ClusterTensors, max_rounds: int,

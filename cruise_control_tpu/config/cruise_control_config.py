@@ -359,7 +359,8 @@ def _definition() -> ConfigDef:
              "host-device readback RTT overlaps device compute. The "
              "adaptive dispatch controller then learns from the completed "
              "dispatch one step behind. Trajectory-invariant; the only "
-             "cost is one speculative zero-apply round per pass.")
+             "cost is one speculative dispatch per pass, which runs no "
+             "round.")
     d.define("solver.deficit.moves.cap", T.INT, 2048, Range.at_least(0),
              I.LOW,
              "Deficit-aware batch sizing for count-distribution goals on "

@@ -11,10 +11,12 @@ Each of these encodes a contract a prior PR paid for:
   deltas — double-bills the predecessor's execution into the next
   observation (chain.py's staleness contract, PR 5).
 - CCSA002: the donated megastep kernels may donate ONLY the mutable set
-  ``{assignment, leader_slot}`` (``strip_mutable``): every other tensor
+  ``{assignment, leader_slot}`` (``strip_mutable``) and the bounded
+  pass's own carry ``resume`` (``chain.PassCarry``, made by
+  ``start_pass`` for the pass and owned by its pump): every other tensor
   is topology, shared across generations by the incremental model
   pipeline's cache — donating a shared buffer lets XLA delete it under
-  the cache's feet (model/refresh.py, PR 5).
+  the cache's feet (model/refresh.py).
 - CCSA003: functions traced by ``lax.while_loop``/``scan``/``cond``/
   ``switch`` run ONCE at trace time; Python mutation of enclosing state
   inside them happens once per compilation, not once per round — the
@@ -32,6 +34,9 @@ from .core import Finding, FileContext, Rule, register
 #: The exact mutable set of the split state (chain.strip_mutable): the two
 #: tensors the search rewrites. Everything else is topology.
 MUTABLE_SET = ("assignment", "leader_slot")
+#: What else a donated kernel may consume: the bounded pass's carry, which
+#: no model generation shares (chain.start_pass makes one a pass).
+PASS_OWNED = ("resume",)
 
 
 def _donate_argnums_of(call: ast.Call) -> ast.expr | None:
@@ -248,7 +253,7 @@ class DonationSetRule(Rule):
                     f"cannot resolve the function `{label}` donates into "
                     "— donation set unverifiable (donate via a local "
                     "`def` so ccsa can map argnums to parameter names)")]
-        bad = [d for d in donated if d not in MUTABLE_SET]
+        bad = [d for d in donated if d not in MUTABLE_SET + PASS_OWNED]
         if not bad:
             return []
         return [Finding(

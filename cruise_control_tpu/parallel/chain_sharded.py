@@ -850,7 +850,8 @@ def _optimize_chain_sharded_bounded(state, goals, constraint, cfg,
 
         return run_bounded_pass(enqueue, st, pass_cap, ctl,
                                 async_readback=async_rb, stats=stats,
-                                kind=phase, flight=goal_flight)
+                                kind=phase, flight=goal_flight,
+                                grid="narrow")
 
     def run_direct(st, g, goal_flight):
         """Direct-transport pre-pass for goal index ``g``: one sharded

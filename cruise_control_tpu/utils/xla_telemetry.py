@@ -183,13 +183,17 @@ DISPATCH_ROUND_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0,
 
 
 def record_dispatch(kind: str, rounds: int, donated: bool = False,
-                    speculative: bool = False) -> None:
+                    speculative: bool = False,
+                    grid: str | None = None) -> None:
     """Account one solver device dispatch (a bounded megastep or a fused
     whole-pass execution): counters by kind (move/swap/chain), donation
     and speculative (async post-convergence no-op) tallies, and the
-    rounds-per-dispatch histogram the bench reads its p50 from. The
-    ambient trace span (goal.solve) gets a dispatch tally so traces show
-    how many XLA executions a goal cost."""
+    rounds-per-dispatch histogram the bench reads its p50 from. ``grid``
+    adds a label of that name to all four: ``narrow`` or ``wide`` on the
+    bounded per-goal route (which grid the goal's rounds ran on),
+    ``fused`` on the whole-chain route; routes that pass none keep the
+    ``kind`` label alone. The ambient trace span (goal.solve) gets a
+    dispatch tally so traces show how many XLA executions a goal cost."""
     from .tracing import TRACER
     span = TRACER.current_span()
     if span is not None:
@@ -197,7 +201,7 @@ def record_dispatch(kind: str, rounds: int, donated: bool = False,
             int(span.attributes.get("dispatches", 0)) + 1
     if not _enabled:
         return
-    labels = {"kind": kind}
+    labels = {"kind": kind} if grid is None else {"kind": kind, "grid": grid}
     SENSORS.count("solver_dispatches", labels=labels)
     SENSORS.observe("solver_dispatch_rounds", float(rounds), labels=labels,
                     buckets=DISPATCH_ROUND_BUCKETS)

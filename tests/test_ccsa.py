@@ -92,7 +92,8 @@ def test_ccsa002_repo_donation_sites_resolve():
     """The real donated kernels (decorator form in analyzer/chain —
     including the round-14 batched megabatch twins — and the jit-call
     form wrapping shard_map bodies in parallel/chain_sharded) must
-    verify CLEAN — donation exactly {assignment, leader_slot}."""
+    verify CLEAN — donation exactly {assignment, leader_slot}, and the
+    bounded pass's own carry ``resume``."""
     for rel in ("cruise_control_tpu/analyzer/chain.py",
                 "cruise_control_tpu/analyzer/direct.py",
                 "cruise_control_tpu/parallel/chain_sharded.py",

@@ -269,7 +269,9 @@ def test_dispatch_stats_accounting():
     s.record("swap", 1, donated=True, speculative=True)
     d = s.as_dict()
     assert d["dispatch_count"] == 4
-    assert d["rounds_per_dispatch_p50"] == 2.0   # lower median of [1,2,8,16]
+    # a speculative dispatch searches no round: the median is of [2, 8, 16]
+    assert d["rounds_per_dispatch_p50"] == 8.0
+    assert s.rounds_per_dispatch == [16, 2, 8]
     assert d["donated_dispatches"] == 1
     assert d["speculative_dispatches"] == 1
 
