@@ -23,9 +23,9 @@ import jax
 import jax.numpy as jnp
 
 from ..model.tensors import (
-    ClusterTensors, broker_best, broker_flag_at, broker_reduce_form,
-    broker_segments, flatten_slots, is_leader_slot, replica_exists,
-    slot_coords,
+    ClusterTensors, broker_best, broker_best_rows, broker_flag_at,
+    broker_reduce_form, broker_segments, flatten_slots, is_leader_slot,
+    replica_exists, slot_coords,
 )
 from .derived import DerivedState, dest_columns_ok, broker_masks_at
 
@@ -434,9 +434,10 @@ def compute_deltas(state: ClusterTensors, derived: DerivedState,
     )
 
 
-# The form select_sources last took in this process ("dense" or "segment",
-# model.tensors.broker_reduce_form): fixed when a program is traced, so it
-# is written then, and the ``solver.dispatch`` spans report it.
+# The form select_sources last took in this process ("dense", "rows" or
+# "segment": model.tensors.broker_reduce_form, narrowed by source_rows):
+# fixed when a program is traced, so it is written then, and the
+# ``solver.dispatch`` spans report it.
 _source_select_traced: str | None = None
 
 
@@ -447,15 +448,88 @@ def source_select() -> str | None:
     return _source_select_traced
 
 
+def source_rows(quarter: int, b: int, form: str) -> int | None:
+    """How many candidate brokers the per-broker reductions of
+    ``select_sources`` run over, from static shapes: ``quarter`` (the
+    brokers the grid keeps) and a slack of a quarter of it, at least 16.
+    None where every broker's row is reduced as before: the ``segment``
+    form, whose cost is by the element and not by the broker, or fewer
+    than twice as many brokers as rows (docs/DESIGN.md "Per-broker
+    reductions of the flat replica axis")."""
+    if form != "dense":
+        return None
+    m = quarter + max(16, quarter // 4)
+    return m if 2 * m <= b else None
+
+
+def _keep_brokers(score: jax.Array, w1: jax.Array, best1: jax.Array,
+                  w2: jax.Array, best2: jax.Array, quarter: int, n_flat: int,
+                  ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The per-broker blocks' cards: the ``quarter`` rows of highest
+    ``score`` among those with a finite best (``lax.top_k``: the lower
+    position first among equals), each broker's best and second-best flat
+    index and their validity; ``n_flat`` where a row offers none."""
+    tb_score, top = jax.lax.top_k(
+        jnp.where(jnp.isfinite(w1), score, -jnp.inf), quarter)
+    ok_b1 = jnp.isfinite(tb_score)
+    rows_b1 = jnp.where(ok_b1, best1[top], n_flat)
+    ok_b2 = ok_b1 & jnp.isfinite(w2[top])
+    rows_b2 = jnp.where(ok_b2, best2[top], n_flat)
+    return rows_b1, rows_b2, ok_b1, ok_b2
+
+
+def broker_blocks(flat_weight: jax.Array, seg_flat: jax.Array,
+                  source_score: jax.Array, quarter: int, form: str,
+                  batched: bool = False) -> tuple[jax.Array, ...]:
+    """The source selection's per-broker blocks: of the ``quarter``
+    brokers of highest ``source_score`` that hold a replica of finite
+    ``flat_weight``, the best and the second best replica each. Returns
+    (rows_b1, rows_b2, ok_b1, ok_b2 [quarter], fallback): ``fallback``
+    (scalar bool) says the round reduced every broker's row.
+
+    Where ``source_rows`` gives M, the best and second best are reduced
+    over the rows of the M brokers of highest ``source_score`` alone
+    (``lax.top_k``: score descending, broker ascending, the order the
+    full top-k breaks ties in), and the quarter is kept among them. That
+    is the full selection bit for bit unless fewer than ``quarter`` of the
+    M rows hold a finite best AND the M-th score is above 0, so that a
+    source broker may lie outside them: only then does the ``cond`` run
+    the reduction over all B rows. Under ``vmap`` (``batched``) a
+    ``cond`` whose predicate is batched runs both branches, so there every
+    broker's row is reduced, once, as without rows."""
+    b = source_score.shape[0]
+    n_flat = flat_weight.shape[0]
+
+    def every_broker():
+        w1, best1 = broker_best(flat_weight, seg_flat, b, form)
+        w2, best2 = broker_best(flat_weight, seg_flat, b, form, skip=best1)
+        return _keep_brokers(source_score, w1, best1, w2, best2, quarter,
+                             n_flat)
+
+    m = None if batched else source_rows(quarter, b, form)
+    if m is None:
+        return every_broker() + (jnp.bool_(False),)
+    s_m, cand_b = jax.lax.top_k(source_score, m)
+    w1, best1 = broker_best_rows(flat_weight, seg_flat, cand_b)
+    w2, best2 = broker_best_rows(flat_weight, seg_flat, cand_b, skip=best1)
+    fallback = (jnp.isfinite(w1).sum() < quarter) & ~(s_m[m - 1] <= 0.0)
+    kept = jax.lax.cond(
+        fallback, every_broker,
+        lambda: _keep_brokers(s_m, w1, best1, w2, best2, quarter, n_flat))
+    return tuple(kept) + (fallback,)
+
+
 @jax.named_scope("round.source_topk")
 def select_sources(state: ClusterTensors, source_score: jax.Array,
                    replica_weight: jax.Array, num_sources: int,
-                   ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+                   batched: bool = False) -> tuple[jax.Array, ...]:
     """The move grid's source-replica selection (broker-diverse top-k; see
     generate_candidates). Returns (cand_p [k], cand_s [k], src_valid [k],
-    on_source [n_flat]): the cards, and which flat replicas sit on a
+    on_source [n_flat], fallback): the cards, which flat replicas sit on a
     source broker at all (the leadership block ranks the leaders among
-    them).
+    them), and whether the per-broker blocks reduced every broker's row
+    (``broker_blocks``; a scalar the counter
+    ``solver_source_fallback_rounds_total`` sums).
 
     A caller that needs the source list FIRST (to compute per-card
     targeted destinations, analyzer.fill) hands the whole result on as
@@ -463,15 +537,16 @@ def select_sources(state: ClusterTensors, source_score: jax.Array,
     run once a round.
 
     The per-broker reductions take the form ``broker_reduce_form`` gives
-    for these shapes (dense compare-and-reduce, or ``segment_*``); cards
-    and validity are the same under both."""
+    for these shapes (dense compare-and-reduce, or ``segment_*``), over
+    the rows ``source_rows`` gives (every row where ``batched``: under
+    ``vmap``, ``broker_blocks``); cards and validity are the same under
+    every form."""
     global _source_select_traced
     b = state.num_brokers
     s_dim = state.max_replication_factor
     seg_flat = broker_segments(state)
     n_flat = seg_flat.shape[0]
     form = broker_reduce_form(b, n_flat)
-    _source_select_traced = form
     on_source = broker_flag_at(source_score > 0.0, seg_flat, form) \
         & flatten_slots(replica_exists(state))
 
@@ -490,6 +565,8 @@ def select_sources(state: ClusterTensors, source_score: jax.Array,
     # the best (and second-best) replica of each of the top source brokers.
     quarter = min(k_src // 4, b)
     half = k_src - 2 * quarter            # exact: half + 2*quarter == k_src
+    _source_select_traced = form \
+        if batched or source_rows(quarter, b, form) is None else "rows"
 
     g_w, g_idx = jax.lax.top_k(flat_weight, half)
     # Mask the global block's rows out of the per-broker selection so the
@@ -500,14 +577,8 @@ def select_sources(state: ClusterTensors, source_score: jax.Array,
         jnp.where(jnp.isfinite(g_w), g_idx, n_flat)].set(True)[:n_flat]
     flat_weight_rest = jnp.where(in_global, -jnp.inf, flat_weight)
 
-    w1, best1 = broker_best(flat_weight_rest, seg_flat, b, form)
-    w2, best2 = broker_best(flat_weight_rest, seg_flat, b, form, skip=best1)
-    b_score = jnp.where(jnp.isfinite(w1), source_score, -jnp.inf)
-    tb_score, top_brokers = jax.lax.top_k(b_score, quarter)
-    broker_ok = jnp.isfinite(tb_score)
-    rows_b1 = jnp.where(broker_ok, best1[top_brokers], n_flat)
-    ok_b2 = broker_ok & jnp.isfinite(w2[top_brokers])
-    rows_b2 = jnp.where(ok_b2, best2[top_brokers], n_flat)
+    rows_b1, rows_b2, broker_ok, ok_b2, fallback = broker_blocks(
+        flat_weight_rest, seg_flat, source_score, quarter, form, batched)
 
     top_idx = jnp.concatenate([g_idx, rows_b1, rows_b2])[:k_src]
     src_valid = jnp.concatenate([jnp.isfinite(g_w), broker_ok, ok_b2])[:k_src]
@@ -515,7 +586,7 @@ def select_sources(state: ClusterTensors, source_score: jax.Array,
     top_idx = jnp.minimum(top_idx, n_flat - 1)
     cand_p, cand_s = slot_coords(top_idx, state.num_partitions, s_dim)
     return (cand_p.astype(jnp.int32), cand_s.astype(jnp.int32), src_valid,
-            on_source)
+            on_source, fallback)
 
 
 @jax.named_scope("round.candidates")
@@ -559,7 +630,7 @@ def generate_candidates(state: ClusterTensors, derived: DerivedState,
     if sources is None:
         sources = select_sources(state, source_score, replica_weight,
                                  num_sources)
-    cand_p, cand_s, src_valid, on_source = sources
+    cand_p, cand_s, src_valid, on_source, _fallback = sources
     k_src = cand_p.shape[0]
 
     layout: list[tuple[int, int]] = []

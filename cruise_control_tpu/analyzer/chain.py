@@ -264,6 +264,7 @@ class ScoredCandidates(NamedTuple):
     independent: jax.Array    # the active goal lifts the per-round move cap
     targets: bool             # static: the move block's last column is targeted
     heals: jax.Array          # scalar: the round took the healing branch
+    source_fallback: jax.Array  # scalar: select_sources reduced every row
 
 
 def _self_healing(state: ClusterTensors, derived: DerivedState,
@@ -302,6 +303,7 @@ def _scored_candidates(state: ClusterTensors, agg: "AggCarry | None",
                        constraint: BalancingConstraint, cfg: SearchConfig,
                        num_topics: int, masks: ExclusionMasks, *,
                        global_partitions: int, psum=None,
+                       batched: bool = False,
                        ) -> ScoredCandidates:
     """The scoring half of one move round, written ONCE for every route
     (docs/DESIGN.md "The move round"): derived state -> the goals' aux ->
@@ -318,6 +320,9 @@ def _scored_candidates(state: ClusterTensors, agg: "AggCarry | None",
     and ``global_partitions`` (``state.num_partitions`` on one chip) is
     the mesh's total. Every collective it adds runs unconditionally
     (a ``cond`` whose branches disagree on collectives would deadlock).
+    ``batched``: the body runs under ``vmap`` (the megabatch), where a
+    ``cond`` on a per-cluster predicate runs both branches, so the source
+    selection keeps its one full reduction (``select_sources``).
 
     The phases carry stable ``jax.named_scope`` names (``round.score``
     and inside it ``round.score_derived``, ``round.score_goals``,
@@ -355,10 +360,11 @@ def _scored_candidates(state: ClusterTensors, agg: "AggCarry | None",
     # "Known limits").
     targets = targets_enabled(global_partitions) \
         and global_partitions == state.num_partitions
-    extra = sources = None
+    extra = None
+    sources = select_sources(state, src_score, weight, cfg.num_sources,
+                             batched=batched)
     if targets:
-        sources = select_sources(state, src_score, weight, cfg.num_sources)
-        cand_p, cand_s, src_valid, _on_source = sources
+        cand_p, cand_s, src_valid, _on_source, _fallback = sources
         # Targets pause while ANY offline replica exists, anywhere on the
         # mesh (traced scalar): targeted steering during a drain locks in
         # placements later goals cannot repair (1k drain-50: balancedness
@@ -408,7 +414,8 @@ def _scored_candidates(state: ClusterTensors, agg: "AggCarry | None",
 
     independent = indep_f[active_idx] & ~prior_mask.any()
     return ScoredCandidates(derived, aux_list, cand, layout, deltas, accept,
-                            score, is_active, independent, targets, heals)
+                            score, is_active, independent, targets, heals,
+                            sources[4])
 
 
 def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
@@ -416,9 +423,9 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
                       prior_mask: jax.Array, goals: tuple[Goal, ...],
                       constraint: BalancingConstraint, cfg: SearchConfig,
                       num_topics: int, masks: ExclusionMasks,
-                      stats: "str | None" = None,
+                      stats: "str | None" = None, batched: bool = False,
                       ) -> tuple[ClusterTensors, "AggCarry | None",
-                                 jax.Array, "jax.Array | None"]:
+                                 jax.Array, "jax.Array | None", jax.Array]:
     """One search round, chain-parameterized (traced body): the scoring
     half (``_scored_candidates``), then the one-chip selection. ``agg`` is
     the incrementally-maintained aggregate carry (analyzer.agg): the round
@@ -435,17 +442,21 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
     never a new selection input: the trajectory is byte-identical with
     collection on or off (pinned in tests/test_flight_recorder.py).
     ``"tally"`` (the whole-chain dispatch, which keeps no ring) returns in
-    its place the row's two sums that price the acceptance stack and
-    whether the round took the self-healing branch,
-    ``[valid, accepted, heals]``: two reductions over the candidate axis
-    and a scalar.
+    its place the row's two sums that price the acceptance stack,
+    whether the round took the self-healing branch and whether its source
+    selection reduced every broker's row,
+    ``[valid, accepted, heals, source_fallback]``: two reductions over the
+    candidate axis and two scalars. The last output is that
+    ``source_fallback`` scalar (bool) under every ``stats``: the bounded
+    route sums it into its ``PassCarry``.
 
     The selection's phases carry the scopes ``round.select``,
     ``round.apply`` and ``round.flight_stats`` (``round.agg_refresh`` in
     the drivers), after the scoring half's."""
     sc = _scored_candidates(state, agg, active_idx, prior_mask, goals,
                             constraint, cfg, num_topics, masks,
-                            global_partitions=state.num_partitions)
+                            global_partitions=state.num_partitions,
+                            batched=batched)
     derived, aux_list, deltas, score = \
         sc.derived, sc.aux_list, sc.deltas, sc.score
     m = max(cfg.moves_per_round, cfg.num_sources)
@@ -499,12 +510,14 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
         with jax.named_scope("round.flight_stats"):
             stat = jnp.stack([deltas.valid.sum().astype(jnp.float32),
                               sc.accept.sum().astype(jnp.float32),
-                              sc.heals.astype(jnp.float32)])
-    return new_state, agg, applied, stat
+                              sc.heals.astype(jnp.float32),
+                              sc.source_fallback.astype(jnp.float32)])
+    return new_state, agg, applied, stat, sc.source_fallback
 
 
 @partial(jax.tree_util.register_dataclass,
-         data_fields=["agg", "rounds", "last"], meta_fields=[])
+         data_fields=["agg", "rounds", "last", "source_fallbacks"],
+         meta_fields=[])
 @dataclasses.dataclass(frozen=True)
 class PassCarry:
     """What a bounded dispatch hands the next dispatch of the SAME pass, so
@@ -516,18 +529,22 @@ class PassCarry:
     wall-clock), the rounds the pass has run (the refresh cadence counts
     them) and the applied count of its last round (a pass at its fixed
     point runs no further round, so the pump's speculative successor
-    runs none). Device scalars, chained like the state: no readback."""
+    runs none), and the pass's move rounds whose source selection reduced
+    every broker's row (``select_sources``' fallback; the pump reads it
+    once the pass's last dispatch is read). Device scalars, chained like
+    the state: no readback."""
 
     agg: AggCarry
     rounds: jax.Array       # i32: rounds the pass ran before this dispatch
     last: jax.Array         # i32: the last round's applied count
+    source_fallbacks: jax.Array  # i32: fallback rounds of the pass so far
 
 
 def _pass_start(state: ClusterTensors, num_topics: int) -> PassCarry:
     """The carry a pass starts from: aggregates computed afresh, no
     round run yet."""
     return PassCarry(compute_agg(state, num_topics), jnp.int32(0),
-                     jnp.int32(1))
+                     jnp.int32(1), jnp.int32(0))
 
 
 @partial(jax.jit, static_argnames=("num_topics",))
@@ -571,27 +588,29 @@ def _chain_rounds_driver(state: ClusterTensors, active_idx: jax.Array,
 
     def body(carry, rounds_done):
         if collect:
-            s, a, ring = carry
+            s, a, fb, ring = carry
         else:
-            s, a = carry
+            s, a, fb = carry
         a = maybe_refresh(a, s, num_topics, resume.rounds + rounds_done)
-        ns, na, applied, stat = _chain_round_body(
+        ns, na, applied, stat, fallback = _chain_round_body(
             s, a, active_idx, prior_mask, goals, constraint, cfg,
             num_topics, masks, stats="row" if collect else None)
+        fb = fb + fallback.astype(jnp.int32)
         if collect:
             ring = ring.at[rounds_done % ring_rounds].set(stat)
-            return (ns, na, ring), applied
-        return (ns, na), applied
+            return (ns, na, fb, ring), applied
+        return (ns, na, fb), applied
 
-    carry0 = (state, resume.agg)
+    carry0 = (state, resume.agg, resume.source_fallbacks)
     if collect:
         carry0 = carry0 + (jnp.zeros((ring_rounds, _FLIGHT_STATS),
                                      jnp.float32),)
     final_carry, total, rounds, last = run_carry_loop(
         body, carry0, cfg.max_rounds, budget=budget, last0=resume.last)
-    ring = final_carry[2] if collect else None
+    ring = final_carry[3] if collect else None
     return (final_carry[0], total, rounds, ring,
-            PassCarry(final_carry[1], resume.rounds + rounds, last))
+            PassCarry(final_carry[1], resume.rounds + rounds, last,
+                      final_carry[2]))
 
 
 @partial(jax.jit, static_argnames=("goals", "constraint", "cfg", "num_topics",
@@ -749,7 +768,7 @@ def _chain_swap_driver(state: ClusterTensors, active_idx: jax.Array,
         body, (state, resume.agg), max_rounds, budget=budget,
         last0=resume.last)
     return final, total, rounds, PassCarry(agg, resume.rounds + rounds,
-                                           last)
+                                           last, resume.source_fallbacks)
 
 
 @partial(jax.jit, static_argnames=("goals", "constraint", "num_topics",
@@ -935,11 +954,12 @@ def chain_optimize_full(state: ClusterTensors, goals: tuple[Goal, ...],
 
     Returns (final_state, per_goal_stats) where per_goal_stats is a dict of
     [G]-arrays: viol_before/after, obj_before/after, offline_before,
-    moves, swaps, rounds, and cand_valid / cand_accepted / healing_rounds
-    (f32: each goal's move rounds' valid candidates, those of them that
-    every earlier goal's acceptance let through, and the move rounds that
-    took the self-healing branch; ``_chain_round_body``
-    ``stats="tally"``).
+    moves, swaps, rounds, and cand_valid / cand_accepted / healing_rounds /
+    source_fallback_rounds (f32: each goal's move rounds' valid
+    candidates, those of them that every earlier goal's acceptance let
+    through, the move rounds that took the self-healing branch, and those
+    whose source selection reduced every broker's row;
+    ``_chain_round_body`` ``stats="tally"``).
     """
     g_count = len(goals)
     supports_swap = jnp.asarray([g.supports_swap for g in goals])
@@ -979,7 +999,7 @@ def chain_optimize_full(state: ClusterTensors, goals: tuple[Goal, ...],
                     st, ag, tl = carry
                     ag = maybe_refresh(ag, st, num_topics,
                                        rounds + rounds_done)
-                    ns, nag, applied, stat = _chain_round_body(
+                    ns, nag, applied, stat, _fb = _chain_round_body(
                         st, ag, g, prior, goals, constraint, cfg, num_topics,
                         masks, stats="tally")
                     return (ns, nag, tl + stat), applied
@@ -1012,14 +1032,14 @@ def chain_optimize_full(state: ClusterTensors, goals: tuple[Goal, ...],
 
             s, a, tally, m, sw, rounds, _, _ = jax.lax.while_loop(
                 outer_cond, outer_body,
-                (s, compute_agg(s, num_topics), jnp.zeros(3, jnp.float32),
+                (s, compute_agg(s, num_topics), jnp.zeros(4, jnp.float32),
                  jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0),
                  jnp.bool_(True)))
             return s, m, sw, rounds, tally
 
         def skip(s):
             return (s, jnp.int32(0), jnp.int32(0), jnp.int32(0),
-                    jnp.zeros(3, jnp.float32))
+                    jnp.zeros(4, jnp.float32))
 
         new_state, moves, swaps, rounds, tally = jax.lax.cond(
             (viol0 > 0) | (offline0 > 0) | drain_pending(carry_state),
@@ -1031,7 +1051,7 @@ def chain_optimize_full(state: ClusterTensors, goals: tuple[Goal, ...],
               "obj_after": obj1, "offline_after": offline1,
               "moves": moves, "swaps": swaps, "rounds": rounds,
               "cand_valid": tally[0], "cand_accepted": tally[1],
-              "healing_rounds": tally[2]}
+              "healing_rounds": tally[2], "source_fallback_rounds": tally[3]}
         return new_state, ys
 
     final_state, stats = jax.lax.scan(
@@ -1065,7 +1085,21 @@ def optimize_chain(state: ClusterTensors, chain: Sequence[Goal],
         infos = _chain_infos_from_stats(goals, stats)
         set_dispatch_rounds(dispatch, infos)
         _set_traced_forms(dispatch)
+        count_source_fallbacks(
+            dispatch, sum(i["source_fallback_rounds"] for i in infos),
+            "fused")
     return state, infos
+
+
+def count_source_fallbacks(dispatch, rounds: int, grid: str | None) -> None:
+    """A pass's move rounds whose source selection reduced every broker's
+    row (``candidates.broker_blocks``' fallback): the counter
+    ``solver_source_fallback_rounds_total{grid=}`` and the attribute
+    ``source_fallback_rounds`` on the pass's ``solver.dispatch`` span."""
+    from ..utils.sensors import SENSORS
+    SENSORS.count("solver_source_fallback_rounds", rounds,
+                  labels=None if grid is None else {"grid": grid})
+    dispatch.set(source_fallback_rounds=rounds)
 
 
 def set_dispatch_rounds(dispatch, infos: list[dict]) -> None:
@@ -1132,6 +1166,9 @@ def _chain_infos_from_stats(goals: tuple[Goal, ...], stats: dict,
             infos[-1]["candidates_accepted"] = float(stats["cand_accepted"][i])
             # ccsa: ok[CCSA001] decode of already-fetched host stats scalars
             infos[-1]["healing_rounds"] = int(stats["healing_rounds"][i])
+            # ccsa: ok[CCSA001] decode of already-fetched host stats scalars
+            fallbacks = int(stats["source_fallback_rounds"][i])
+            infos[-1]["source_fallback_rounds"] = fallbacks
     return infos
 
 
@@ -1366,7 +1403,8 @@ def run_bounded_pass(enqueue: Callable, st, pass_cap: int,
                      async_readback: bool = True,
                      stats: DispatchStats | None = None,
                      kind: str = "move",
-                     flight=NO_FLIGHT, grid: str | None = None):
+                     flight=NO_FLIGHT, grid: str | None = None,
+                     pass_counts: Callable | None = None):
     """Drive one logical pass (a fixed-point loop of at most ``pass_cap``
     search rounds) as a sequence of bounded megastep dispatches.
 
@@ -1409,7 +1447,10 @@ def run_bounded_pass(enqueue: Callable, st, pass_cap: int,
     ``solver.dispatch`` span, which also gets ``pass_rounds``,
     ``speculative`` (the pass's speculative dispatches) and
     ``budget_max`` (its largest round budget): values the pump holds
-    anyway, no readback added.
+    anyway, no readback added. ``pass_counts(st, dispatch)``, where given,
+    reads what the pass counted on the device from the final ``st`` once
+    the pass's last dispatch has been read (the device is done with it:
+    a transfer, no wait) and records it on the span.
 
     Returns (st, applied_total, pass_rounds)."""
     applied_total = 0
@@ -1485,6 +1526,8 @@ def run_bounded_pass(enqueue: Callable, st, pass_cap: int,
         if grid is not None:
             dispatch.set(grid=grid)
         _set_traced_forms(dispatch, kind)
+        if pass_counts is not None:
+            pass_counts(st, dispatch)
     return st, applied_total, pass_rounds
 
 
@@ -1585,9 +1628,9 @@ def _megabatch_rounds_driver(states: ClusterTensors, active0: jax.Array,
     def per_cluster(s, a, ring, tm, rm, lm, gr):
         m = ExclusionMasks(tm, rm, lm)
         a = maybe_refresh(a, s, num_topics, gr)
-        ns, na, applied, stat = _chain_round_body(
+        ns, na, applied, stat, _fb = _chain_round_body(
             s, a, active_idx, prior_mask, goals, constraint, cfg,
-            num_topics, m, stats="row" if collect else None)
+            num_topics, m, stats="row" if collect else None, batched=True)
         if collect:
             ring = ring.at[gr % ring_rounds].set(stat)
         return ns, na, ring, applied
@@ -2455,13 +2498,19 @@ def optimize_goal_in_chain(state: ClusterTensors, chain: Sequence[Goal],
             can_donate[0] = True
             return (st, out[-1]), applied, r, donate, ring
 
+        def source_fallbacks(sc, span):
+            # ccsa: ok[CCSA001] after the pass's last readback: the
+            # carry is that dispatch's output, so this waits on nothing
+            count_source_fallbacks(span, int(sc[1].source_fallbacks), grid)
+
         # One carry a pass: the pass's dispatches chain it on device, so
         # any split of the pass walks the same rounds (PassCarry).
         (st, _carry), applied, rounds_run = run_bounded_pass(
             enqueue, (st, start_pass(st, num_topics)), pass_cap, dispatch,
             out_of_time=out_of_time if wall_budget_s > 0 else None,
             async_readback=async_rb, stats=stats, kind=phase,
-            flight=flight, grid=grid)
+            flight=flight, grid=grid,
+            pass_counts=source_fallbacks if phase == "move" else None)
         return st, applied, rounds_run
 
     # Fast path (parity with chain_optimize_full's per-goal lax.cond skip

@@ -303,7 +303,7 @@ def score_round_candidates(state: ClusterTensors, masks: ExclusionMasks,
     extra = sources = None
     if targets_enabled(state.num_partitions) and not goal.leadership_only:
         sources = select_sources(state, src_score, weight, cfg.num_sources)
-        cand_p, cand_s, src_valid, _on_source = sources
+        cand_p, cand_s, src_valid, _on_source, _fallback = sources
         extra = goal.target_dests(state, derived, constraint, aux,
                                   cand_p, cand_s, src_valid)
         if extra is None:

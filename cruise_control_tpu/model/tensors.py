@@ -231,6 +231,25 @@ def _broker_mask(seg_flat: jax.Array, b: int) -> jax.Array:
     return jnp.arange(b, dtype=seg_flat.dtype)[:, None] == seg_flat[None, :]
 
 
+def broker_best_rows(fw: jax.Array, seg_flat: jax.Array, rows: jax.Array,
+                     skip: jax.Array | None = None,
+                     ) -> tuple[jax.Array, jax.Array]:
+    """``broker_best``'s dense form over the brokers ``rows [M]`` alone:
+    ``(w [M], idx [M])``, row i exactly what ``broker_best`` reads at broker
+    ``rows[i]`` (``skip [M]`` likewise). The masked reduce runs over
+    ``[M, n_flat]`` cells, so it costs M / B of the full one."""
+    n_flat = fw.shape[0]
+    idxs = jnp.arange(n_flat, dtype=jnp.int32)
+    mask = rows.astype(seg_flat.dtype)[:, None] == seg_flat[None, :]
+    if skip is not None:
+        mask &= idxs[None, :] != skip[:, None]
+    masked = jnp.where(mask, fw[None, :], -jnp.inf)
+    w = masked.max(axis=1)
+    # argmax takes the first of equals: the lowest flat index
+    first = jnp.argmax(masked, axis=1).astype(jnp.int32)
+    return w, jnp.where(jnp.isfinite(w), first, n_flat)
+
+
 def broker_best(fw: jax.Array, seg_flat: jax.Array, b: int, form: str,
                 skip: jax.Array | None = None,
                 ) -> tuple[jax.Array, jax.Array]:
@@ -244,14 +263,8 @@ def broker_best(fw: jax.Array, seg_flat: jax.Array, b: int, form: str,
     n_flat = fw.shape[0]
     idxs = jnp.arange(n_flat, dtype=jnp.int32)
     if form == "dense":
-        mask = _broker_mask(seg_flat, b)
-        if skip is not None:
-            mask &= idxs[None, :] != skip[:, None]
-        masked = jnp.where(mask, fw[None, :], -jnp.inf)
-        w = masked.max(axis=1)
-        # argmax takes the first of equals: the lowest flat index
-        first = jnp.argmax(masked, axis=1).astype(jnp.int32)
-        return w, jnp.where(jnp.isfinite(w), first, n_flat)
+        return broker_best_rows(fw, seg_flat,
+                                jnp.arange(b, dtype=seg_flat.dtype), skip)
     if skip is not None:
         dead = jnp.array([n_flat], jnp.int32)
         fw = jnp.where(idxs == jnp.concatenate([skip, dead])[seg_flat],

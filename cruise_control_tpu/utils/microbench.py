@@ -49,9 +49,12 @@ from functools import partial
 # (``accept_packed``). The ``bbest_*`` / ``bcount_*`` classes (PR 28) price
 # the source selection's per-broker reductions of the flat replica axis
 # (model.tensors.broker_best, best and second best with the source lookup,
-# as ``select_sources`` runs them; broker_count): the solver's own
-# ``segment`` and ``dense`` forms, and for the best a sort-based
-# alternative (one three-key ``lax.sort``, the first two of each run).
+# as ``select_sources`` runs them, and the pick of the ``quarter`` brokers
+# the grid keeps; broker_count): the solver's own ``segment`` and ``dense``
+# forms, and for the best a sort-based alternative (one three-key
+# ``lax.sort``, the first two of each run). ``bbest_rows`` is the solver's
+# selection itself (``candidates.broker_blocks``): the dense pair over the
+# rows of the quarter's candidate brokers alone (``candidates.source_rows``).
 # ``topk128`` beside them is the selection's global block alone. The
 # ``deltas_*`` classes (PR 35) price ``compute_deltas`` itself on the same
 # grid over a random cluster of (brokers, partitions): every field gathered
@@ -63,13 +66,13 @@ CASE_NAMES = ("topk128", "topk1024", "approx1024", "segsum", "segmax",
               "cell_segsum", "frac_round", "stride_sort",
               "stride_sort_fused", "accept_flat", "accept_margin",
               "accept_packed", "bbest_segment", "bbest_dense", "bbest_sort",
-              "bcount_segment", "bcount_dense", "deltas_flat",
+              "bbest_rows", "bcount_segment", "bcount_dense", "deltas_flat",
               "deltas_margin")
 
 _ACCEPT_TERMS = 100
 
 
-def _build_cases(brokers: int, partitions: int):
+def _build_cases(brokers: int, partitions: int, quarter: int = 64):
     import jax
     import jax.numpy as jnp
 
@@ -117,26 +120,34 @@ def _build_cases(brokers: int, partitions: int):
         return ok.sum().astype(jnp.float32)
 
     # bbest_* / bcount_*: every flat replica on one of ``brokers`` brokers
-    # or in the dead bucket, as ``broker_segments`` lays them out.
+    # or in the dead bucket, as ``broker_segments`` lays them out; the
+    # carry's first ``brokers`` entries are the brokers' source scores.
+    from ..analyzer.candidates import _keep_brokers, broker_blocks
     from ..model.tensors import broker_best, broker_count, broker_flag_at
     bseg = jax.random.randint(akeys[0], (n_flat,), 0, brokers + 1)
     b_ids = jnp.arange(brokers, dtype=jnp.int32)
+    quarter = min(quarter, brokers)
 
     def best_two(v, form):
         """What ``select_sources`` asks per broker, on the carry as the
-        weights: which replicas sit on a source broker, then each
-        broker's best and second best of them."""
-        on = broker_flag_at(_span(v, 0, brokers) > 0.0, bseg, form)
+        weights: which replicas sit on a source broker, each broker's best
+        and second best of them, and the ``quarter`` brokers kept."""
+        score = _span(v, 0, brokers)
+        on = broker_flag_at(score > 0.0, bseg,
+                            "dense" if form == "rows" else form)
         fw = jnp.where(on, v, -jnp.inf)
+        if form == "rows":
+            return broker_blocks(fw, bseg, score, quarter, "dense")[:4]
         w1, i1 = broker_best(fw, bseg, brokers, form)
         w2, i2 = broker_best(fw, bseg, brokers, form, skip=i1)
-        return w1, i1, w2, i2
+        return _keep_brokers(score, w1, i1, w2, i2, quarter, n_flat)
 
     def best_two_sorted(v):
-        """The same four arrays from ONE sort of the axis by (broker,
-        weight descending, flat index): a run's first two entries. The
-        source lookup is the dense one: the sort replaces the pair only."""
-        on = broker_flag_at(_span(v, 0, brokers) > 0.0, bseg, "dense")
+        """The same pair from ONE sort of the axis by (broker, weight
+        descending, flat index): a run's first two entries. The source
+        lookup is the dense one: the sort replaces the pair only."""
+        score = _span(v, 0, brokers)
+        on = broker_flag_at(score > 0.0, bseg, "dense")
         fw = jnp.where(on, v, -jnp.inf)
         ss, sw, si = jax.lax.sort(
             (bseg, -fw, jnp.arange(n_flat, dtype=jnp.int32)), num_keys=3)
@@ -147,7 +158,7 @@ def _build_cases(brokers: int, partitions: int):
             w = jnp.where((pos < n_flat) & (ss[at] == b_ids), -sw[at],
                           -jnp.inf)
             out += [w, jnp.where(jnp.isfinite(w), si[at], n_flat)]
-        return tuple(out)
+        return _keep_brokers(score, *out, quarter, n_flat)
 
     # deltas_*: a cluster of (brokers, partitions) at RF 3 with three
     # distinct brokers a partition and leaders on every slot, and the grid
@@ -361,7 +372,7 @@ def _build_cases(brokers: int, partitions: int):
                     lambda f: grid.from_rows(rs[:, f]),
                     lambda f: grid.from_dst(ds[:, f]))
             return loop(bd, x, iters)
-        if which in ("bbest_segment", "bbest_dense"):
+        if which in ("bbest_segment", "bbest_dense", "bbest_rows"):
             form = which.split("_")[1]
             return loop(lambda v: v + fold(best_two(v, form)), x, iters)
         if which == "bbest_sort":
@@ -396,22 +407,25 @@ def _build_cases(brokers: int, partitions: int):
               "frac_round": w, "stride_sort": w, "stride_sort_fused": w,
               "accept_flat": tables, "accept_margin": tables,
               "accept_packed": tables, "bbest_segment": w, "bbest_dense": w,
-              "bbest_sort": w, "bcount_segment": w, "bcount_dense": w,
+              "bbest_sort": w, "bbest_rows": w, "bcount_segment": w,
+              "bcount_dense": w,
               "deltas_flat": shift0, "deltas_margin": shift0}
     return run, inputs
 
 
 def run_microbench(brokers: int = 1000, partitions: int = 100_000,
                    iters: int = 16,
-                   cases: tuple[str, ...] | None = None) -> dict:
+                   cases: tuple[str, ...] | None = None,
+                   quarter: int = 64) -> dict:
     """Measure each op class's marginal ms/iteration inside a fused
-    while_loop at (brokers, partitions) scale. Returns
+    while_loop at (brokers, partitions) scale (``quarter``: the brokers
+    the ``bbest_*`` classes keep, 64 on the narrow grid). Returns
     ``{platform, brokers, partitions, iters, results: {case: ms_per_iter
     | {"error": ...}}}`` — a failed class records its error and the rest
     keep running (the same per-case isolation as the CLI tool)."""
     import jax
 
-    run, inputs = _build_cases(brokers, partitions)
+    run, inputs = _build_cases(brokers, partitions, quarter)
     results: dict[str, float | dict] = {}
     for name in (cases or CASE_NAMES):
         if name not in inputs:
@@ -434,5 +448,5 @@ def run_microbench(brokers: int = 1000, partitions: int = 100_000,
             results[name] = {"error": f"{type(e).__name__}: {e}"}
     return {"platform": jax.devices()[0].platform,
             "brokers": int(brokers), "partitions": int(partitions),
-            "iters": int(iters), "unit": "ms_per_iter",
-            "results": results}
+            "iters": int(iters), "quarter": int(quarter),
+            "unit": "ms_per_iter", "results": results}

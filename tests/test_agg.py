@@ -71,7 +71,7 @@ def test_carry_tracks_moves_and_leadership(setup):
     for active, rounds in ((8, 6), (14, 4)):
         prior = jnp.asarray([j < active for j in range(len(goals))])
         for _ in range(rounds):
-            state, agg, applied, _stat = _chain_round_body(
+            state, agg, applied, _stat, _fb = _chain_round_body(
                 state, agg, jnp.int32(active), prior, goals, constraint,
                 cfg, meta.num_topics, masks)
             total += int(applied)

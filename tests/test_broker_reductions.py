@@ -10,6 +10,7 @@ The segment form is the oracle: it is what the CPU runs unforced.
 import dataclasses
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -208,7 +209,8 @@ def test_select_sources_same_cards_under_both_forms(monkeypatch, name,
         monkeypatch.undo()
     for a, b in zip(got["segment"], got["dense"]):
         np.testing.assert_array_equal(a, b)
-    _p, _s, valid, on_source = got["dense"]
+    _p, _s, valid, on_source, fallback = got["dense"]
+    assert not fallback
     if name == "no source at all":
         assert not valid.any() and not on_source.any()
     else:
@@ -216,7 +218,7 @@ def test_select_sources_same_cards_under_both_forms(monkeypatch, name,
     if name == "replicas on DEAD brokers":
         # the global block (half the cards or more) is offline replicas
         # first: the drain's priority rides the 1e30 weight through here
-        p, s, ok, _ = got["dense"]
+        p, s, ok, _on, _fb = got["dense"]
         off = np.asarray(offline_replicas(state))
         assert off[p[ok], s[ok]].sum() >= min(int(off.sum()), k_src // 2)
 
@@ -285,15 +287,182 @@ def test_fused_chain_at_16_512_same_trajectory_with_the_dense_form_forced(
     assert infos_seg == infos_dense
 
 
+def test_fused_chain_at_64_1024_same_trajectory_over_the_candidate_rows(
+        monkeypatch):
+    """One fused ``optimize_chain`` pass over the default chain at 64
+    brokers / 1,024 partitions with 16 sources (a quarter of 4 kept, M =
+    20 rows of 64), a broker dead, the dense form forced: the rows form
+    traces, and placement, rounds and proposals by goal are the segment
+    form's. The dispatch span and ``solver_source_fallback_rounds_total``
+    carry the pass's fallback rounds, the tally's sum."""
+    from cruise_control_tpu.utils.sensors import SENSORS
+    state, meta = random_cluster(num_brokers=64, num_topics=8,
+                                 num_partitions=1024, rf=3, num_racks=4,
+                                 seed=5, skew_to_first=2.0)
+    state = set_broker_state(state, jnp.asarray([3]), BrokerState.DEAD)
+    goals = tuple(goals_by_priority(CruiseControlConfig()))
+    cfg = SearchConfig(num_sources=16, num_dests=6, moves_per_round=16,
+                       max_rounds=60)
+    args = (state, goals, BalancingConstraint(), cfg, meta.num_topics,
+            ExclusionMasks())
+
+    def one_pass():
+        chain_optimize_full.clear_cache()
+        before = SENSORS.counter_total("solver_source_fallback_rounds")
+        with TRACER.span("test.pass") as root:
+            out = optimize_chain(*args)
+        (dispatch,) = [c for c in root.children
+                       if c.name == "solver.dispatch"]
+        counted = SENSORS.counter_total("solver_source_fallback_rounds") \
+            - before
+        return out, dispatch.attributes, counted
+
+    (st_seg, infos_seg), attrs, counted = one_pass()
+    assert attrs["source_select"] == "segment"
+    assert attrs["source_fallback_rounds"] == counted == 0
+    try:
+        _force(monkeypatch, "dense")
+        jax.clear_caches()
+        (st_rows, infos_rows), attrs, counted = one_pass()
+        assert attrs["source_select"] == "rows"
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+
+    np.testing.assert_array_equal(np.asarray(st_seg.assignment),
+                                  np.asarray(st_rows.assignment))
+    np.testing.assert_array_equal(np.asarray(st_seg.leader_slot),
+                                  np.asarray(st_rows.leader_slot))
+    fallbacks = sum(i.pop("source_fallback_rounds") for i in infos_rows)
+    assert attrs["source_fallback_rounds"] == fallbacks == counted
+    assert fallbacks <= sum(i["rounds"] for i in infos_rows)
+    for info in infos_seg:
+        info.pop("source_fallback_rounds")
+    assert sum(i["rounds"] for i in infos_seg) > len(goals)
+    assert infos_seg == infos_rows
+
+
 @pytest.mark.parametrize("case", ("bbest_dense", "bbest_sort",
-                                  "bcount_dense"))
+                                  "bbest_rows", "bcount_dense"))
 def test_microbench_broker_forms_compute_the_same(case):
     """The microbench's ``bbest_*`` / ``bcount_*`` classes price the SAME
-    work: every form leaves the carry its segment form leaves."""
+    work: every form leaves the carry its segment form leaves (at 64
+    brokers keeping 4, so ``bbest_rows`` reduces 20 rows of 64)."""
     from cruise_control_tpu.utils.microbench import _build_cases
-    run, inputs = _build_cases(64, 64)
+    assert cand_mod.source_rows(4, 64, "dense") == 20
+    run, inputs = _build_cases(64, 64, quarter=4)
     oracle = case.split("_")[0] + "_segment"
     want = np.asarray(run(inputs[oracle], 2, oracle))
     assert (want != np.asarray(inputs[oracle])).any()
     np.testing.assert_array_equal(
         np.asarray(run(inputs[case], 2, case)), want)
+
+
+# name -> whether the rows form falls back to every broker's row
+ROWS_SCENARIOS = {
+    "many sources, all finite": False,
+    "the top-M brokers' weights all -inf": True,
+    "fewer sources than the quarter, as in a drain": False,
+    "ties in the score across the M-th place": False,
+}
+ROWS_B, ROWS_K = 64, 16          # quarter 4, M = 20: 2 M <= B
+
+
+def _rows_cluster(name):
+    """(state, source_score, weight) of one rows-form scenario at 64
+    brokers."""
+    rng = np.random.default_rng(17)
+    state, _meta = random_cluster(num_brokers=ROWS_B, num_topics=8,
+                                  num_partitions=256, rf=3, num_racks=4,
+                                  seed=7, skew_to_first=2.0)
+    shape = state.assignment.shape
+    score = rng.uniform(0.1, 1.0, ROWS_B)
+    weight = rng.uniform(1.0, 2.0, shape)
+    a = np.asarray(state.assignment)
+    m = cand_mod.source_rows(ROWS_K // 4, ROWS_B, "dense")
+    if name == "the top-M brokers' weights all -inf":
+        top = np.argsort(-score, kind="stable")[:m]
+        weight[np.isin(a, top)] = -INF
+    elif name == "fewer sources than the quarter, as in a drain":
+        score = np.full(ROWS_B, -1.0)
+        score[[5, 40]] = [900.0, 2.0]
+    elif name == "ties in the score across the M-th place":
+        # four levels of 16 brokers: M = 20 cuts the second level; the 14
+        # lowest ids of the first level hold nothing finite, so the kept
+        # quarter reaches into the tie
+        score = np.repeat([1.0, 0.75, 0.5, 0.25], 16)[rng.permutation(ROWS_B)]
+        first = np.flatnonzero(score == 1.0)[:14]
+        weight[np.isin(a, first)] = -INF
+    return (state, jnp.asarray(score, jnp.float32),
+            jnp.asarray(weight, jnp.float32))
+
+
+@pytest.mark.parametrize("name", sorted(ROWS_SCENARIOS))
+def test_rows_form_picks_the_full_paths_cards(monkeypatch, name):
+    """The dense pair over the rows of the M brokers of highest score
+    (``candidates.broker_blocks``) returns the cards, validity and
+    on-source mask of the full path, bit for bit: the segment form and the
+    dense form over every broker's row. Its fallback flag is set exactly
+    where fewer than a quarter of the M rows hold a finite best while the
+    M-th score is above 0."""
+    state, score, weight = _rows_cluster(name)
+    got = {}
+    try:
+        for form in ("rows", "dense", "segment"):
+            _force(monkeypatch, "segment" if form == "segment" else "dense")
+            if form == "dense":
+                monkeypatch.setattr(cand_mod, "source_rows",
+                                    lambda q, b, f: None)
+            got[form] = [np.asarray(x) for x in
+                         select_sources(state, score, weight, ROWS_K)]
+            assert cand_mod.source_select() == form
+            monkeypatch.undo()
+    finally:
+        monkeypatch.undo()
+    for form in ("dense", "segment"):
+        for a, b in zip(got[form][:4], got["rows"][:4]):
+            np.testing.assert_array_equal(a, b)
+        assert not got[form][4]
+    assert bool(got["rows"][4]) == ROWS_SCENARIOS[name]
+    assert got["rows"][2].any()
+
+
+def _argmax_rows(jaxpr, in_cond=False):
+    """(rows, inside a cond?) of every argmax over a [rows, n] operand,
+    through every nested jaxpr."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "argmax":
+            shape = eqn.invars[0].aval.shape
+            if len(shape) == 2:
+                yield shape[0], in_cond
+        for v in eqn.params.values():
+            for x in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(x, "jaxpr", x)
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _argmax_rows(
+                        sub, in_cond or eqn.primitive.name == "cond")
+
+
+@pytest.mark.parametrize("brokers, outside, inside", [
+    (1024, [80, 80], [1024, 1024]),
+    (104, [104, 104], []),
+])
+def test_the_traced_selection_reduces_the_candidate_rows(
+        monkeypatch, brokers, outside, inside):
+    """CPU, jaxpr walk, no compile, the dense form forced: at 1,024
+    brokers with 256 sources (a quarter of 64 kept) the selection's two
+    argmax reductions run over M = 80 rows, and over all 1,024 only in the
+    fallback's ``cond`` branch; at 104 brokers (2 M > B) it traces no rows
+    form: both over every broker, outside any ``cond``."""
+    state, _meta = random_cluster(num_brokers=brokers, num_topics=8,
+                                  num_partitions=brokers * 2, rf=3,
+                                  num_racks=4, seed=1)
+    score = jnp.ones(brokers, jnp.float32)
+    weight = jnp.ones(state.assignment.shape, jnp.float32)
+    _force(monkeypatch, "dense")
+    jaxpr = jax.make_jaxpr(
+        lambda st, sc, w: select_sources(st, sc, w, 256))(
+            state, score, weight).jaxpr
+    found = list(_argmax_rows(jaxpr))
+    assert sorted(r for r, c in found if not c) == outside
+    assert sorted(r for r, c in found if c) == inside

@@ -79,7 +79,8 @@ def _candidates(state, derived, src_score, rng, k_src, first_dst=None):
         dst_score = dst_score.at[first_dst].set(1.0)
     weight = jnp.asarray(rng.uniform(1.0, 2.0, state.assignment.shape),
                          jnp.float32)
-    _p, _s, src_valid, _on = select_sources(state, src_score, weight, k_src)
+    _p, _s, src_valid, _on, _fb = select_sources(state, src_score, weight,
+                                                 k_src)
     targeted = (jnp.asarray(rng.integers(0, B, k_src), jnp.int32),
                 src_valid)
     cand, layout = generate_candidates(

@@ -286,7 +286,8 @@ def test_slot_major_flat_axis_matches_partition_major(monkeypatch):
     score = jnp.asarray(rng.random(state.num_brokers).astype(np.float32))
 
     def snapshot():
-        p, s, ok, _on_source = select_sources(state, score, weight, 32)
+        p, s, ok, _on_source, _fallback = select_sources(
+            state, score, weight, 32)
         picked = {(int(a), int(b)) for a, b, v in zip(p, s, ok) if v}
         return (np.asarray(tensors.broker_load(state)),
                 np.asarray(tensors.topic_broker_replica_counts(
