@@ -446,9 +446,9 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
     whether the round took the self-healing branch and whether its source
     selection reduced every broker's row,
     ``[valid, accepted, heals, source_fallback]``: two reductions over the
-    candidate axis and two scalars. The last output is that
-    ``source_fallback`` scalar (bool) under every ``stats``: the bounded
-    route sums it into its ``PassCarry``.
+    candidate axis and two scalars. The last output is the pair of those
+    two scalars ``(heals, source_fallback)`` (bools) under every
+    ``stats``: the bounded route sums both into its ``PassCarry``.
 
     The selection's phases carry the scopes ``round.select``,
     ``round.apply`` and ``round.flight_stats`` (``round.agg_refresh`` in
@@ -512,11 +512,12 @@ def _chain_round_body(state: ClusterTensors, agg: "AggCarry | None",
                               sc.accept.sum().astype(jnp.float32),
                               sc.heals.astype(jnp.float32),
                               sc.source_fallback.astype(jnp.float32)])
-    return new_state, agg, applied, stat, sc.source_fallback
+    return new_state, agg, applied, stat, (sc.heals, sc.source_fallback)
 
 
 @partial(jax.tree_util.register_dataclass,
-         data_fields=["agg", "rounds", "last", "source_fallbacks"],
+         data_fields=["agg", "rounds", "last", "source_fallbacks",
+                      "healing_rounds"],
          meta_fields=[])
 @dataclasses.dataclass(frozen=True)
 class PassCarry:
@@ -529,22 +530,24 @@ class PassCarry:
     wall-clock), the rounds the pass has run (the refresh cadence counts
     them) and the applied count of its last round (a pass at its fixed
     point runs no further round, so the pump's speculative successor
-    runs none), and the pass's move rounds whose source selection reduced
-    every broker's row (``select_sources``' fallback; the pump reads it
-    once the pass's last dispatch is read). Device scalars, chained like
-    the state: no readback."""
+    runs none), and two tallies of the pass's move rounds: those whose
+    source selection reduced every broker's row (``select_sources``'
+    fallback) and those that built the per-slot offline mask
+    (``_self_healing``); the pump reads both once the pass's last dispatch
+    is read. Device scalars, chained like the state: no readback."""
 
     agg: AggCarry
     rounds: jax.Array       # i32: rounds the pass ran before this dispatch
     last: jax.Array         # i32: the last round's applied count
     source_fallbacks: jax.Array  # i32: fallback rounds of the pass so far
+    healing_rounds: jax.Array    # i32: healing rounds of the pass so far
 
 
 def _pass_start(state: ClusterTensors, num_topics: int) -> PassCarry:
     """The carry a pass starts from: aggregates computed afresh, no
     round run yet."""
     return PassCarry(compute_agg(state, num_topics), jnp.int32(0),
-                     jnp.int32(1), jnp.int32(0))
+                     jnp.int32(1), jnp.int32(0), jnp.int32(0))
 
 
 @partial(jax.jit, static_argnames=("num_topics",))
@@ -588,29 +591,31 @@ def _chain_rounds_driver(state: ClusterTensors, active_idx: jax.Array,
 
     def body(carry, rounds_done):
         if collect:
-            s, a, fb, ring = carry
+            s, a, fb, hl, ring = carry
         else:
-            s, a, fb = carry
+            s, a, fb, hl = carry
         a = maybe_refresh(a, s, num_topics, resume.rounds + rounds_done)
-        ns, na, applied, stat, fallback = _chain_round_body(
+        ns, na, applied, stat, (heals, fallback) = _chain_round_body(
             s, a, active_idx, prior_mask, goals, constraint, cfg,
             num_topics, masks, stats="row" if collect else None)
         fb = fb + fallback.astype(jnp.int32)
+        hl = hl + heals.astype(jnp.int32)
         if collect:
             ring = ring.at[rounds_done % ring_rounds].set(stat)
-            return (ns, na, fb, ring), applied
-        return (ns, na, fb), applied
+            return (ns, na, fb, hl, ring), applied
+        return (ns, na, fb, hl), applied
 
-    carry0 = (state, resume.agg, resume.source_fallbacks)
+    carry0 = (state, resume.agg, resume.source_fallbacks,
+              resume.healing_rounds)
     if collect:
         carry0 = carry0 + (jnp.zeros((ring_rounds, _FLIGHT_STATS),
                                      jnp.float32),)
     final_carry, total, rounds, last = run_carry_loop(
         body, carry0, cfg.max_rounds, budget=budget, last0=resume.last)
-    ring = final_carry[3] if collect else None
+    ring = final_carry[4] if collect else None
     return (final_carry[0], total, rounds, ring,
             PassCarry(final_carry[1], resume.rounds + rounds, last,
-                      final_carry[2]))
+                      final_carry[2], final_carry[3]))
 
 
 @partial(jax.jit, static_argnames=("goals", "constraint", "cfg", "num_topics",
@@ -767,8 +772,8 @@ def _chain_swap_driver(state: ClusterTensors, active_idx: jax.Array,
     (final, agg), total, rounds, last = run_carry_loop(
         body, (state, resume.agg), max_rounds, budget=budget,
         last0=resume.last)
-    return final, total, rounds, PassCarry(agg, resume.rounds + rounds,
-                                           last, resume.source_fallbacks)
+    return final, total, rounds, dataclasses.replace(
+        resume, agg=agg, rounds=resume.rounds + rounds, last=last)
 
 
 @partial(jax.jit, static_argnames=("goals", "constraint", "num_topics",
@@ -999,7 +1004,7 @@ def chain_optimize_full(state: ClusterTensors, goals: tuple[Goal, ...],
                     st, ag, tl = carry
                     ag = maybe_refresh(ag, st, num_topics,
                                        rounds + rounds_done)
-                    ns, nag, applied, stat, _fb = _chain_round_body(
+                    ns, nag, applied, stat, _flags = _chain_round_body(
                         st, ag, g, prior, goals, constraint, cfg, num_topics,
                         masks, stats="tally")
                     return (ns, nag, tl + stat), applied
@@ -1088,6 +1093,7 @@ def optimize_chain(state: ClusterTensors, chain: Sequence[Goal],
         count_source_fallbacks(
             dispatch, sum(i["source_fallback_rounds"] for i in infos),
             "fused")
+        count_healing_rounds(sum(i["healing_rounds"] for i in infos), "fused")
     return state, infos
 
 
@@ -1100,6 +1106,17 @@ def count_source_fallbacks(dispatch, rounds: int, grid: str | None) -> None:
     SENSORS.count("solver_source_fallback_rounds", rounds,
                   labels=None if grid is None else {"grid": grid})
     dispatch.set(source_fallback_rounds=rounds)
+
+
+def count_healing_rounds(rounds: int, grid: str) -> None:
+    """Move rounds that built the per-slot offline mask
+    (``_self_healing``), a whole chain's (``grid="fused"``) or one goal's
+    on the bounded route (``narrow`` / ``wide``):
+    ``solver_healing_rounds_total{grid=}``. The goals' infos carry them,
+    and ``optimizer.ensure_evacuated`` puts the pass's total on its
+    ``solver.dispatch`` spans."""
+    from ..utils.sensors import SENSORS
+    SENSORS.count("solver_healing_rounds", rounds, labels={"grid": grid})
 
 
 def set_dispatch_rounds(dispatch, infos: list[dict]) -> None:
@@ -1628,7 +1645,7 @@ def _megabatch_rounds_driver(states: ClusterTensors, active0: jax.Array,
     def per_cluster(s, a, ring, tm, rm, lm, gr):
         m = ExclusionMasks(tm, rm, lm)
         a = maybe_refresh(a, s, num_topics, gr)
-        ns, na, applied, stat, _fb = _chain_round_body(
+        ns, na, applied, stat, _flags = _chain_round_body(
             s, a, active_idx, prior_mask, goals, constraint, cfg,
             num_topics, m, stats="row" if collect else None, batched=True)
         if collect:
@@ -2427,6 +2444,8 @@ def optimize_goal_in_chain(state: ClusterTensors, chain: Sequence[Goal],
     # fallback would compile the full-chain program TWICE (plain +
     # donated — minutes each at scale).
     can_donate = [bool(donate_input)]
+    # The bounded move passes' healing rounds, read off each pass's carry.
+    healed = [0]
 
     def run_pass(phase: str, st, pass_cap: int):
         """One logical pass (a single fixed-point loop of up to
@@ -2498,10 +2517,12 @@ def optimize_goal_in_chain(state: ClusterTensors, chain: Sequence[Goal],
             can_donate[0] = True
             return (st, out[-1]), applied, r, donate, ring
 
-        def source_fallbacks(sc, span):
+        def move_counts(sc, span):
             # ccsa: ok[CCSA001] after the pass's last readback: the
             # carry is that dispatch's output, so this waits on nothing
             count_source_fallbacks(span, int(sc[1].source_fallbacks), grid)
+            # ccsa: ok[CCSA001] the same finished carry
+            healed[0] += int(sc[1].healing_rounds)
 
         # One carry a pass: the pass's dispatches chain it on device, so
         # any split of the pass walks the same rounds (PassCarry).
@@ -2510,7 +2531,7 @@ def optimize_goal_in_chain(state: ClusterTensors, chain: Sequence[Goal],
             out_of_time=out_of_time if wall_budget_s > 0 else None,
             async_readback=async_rb, stats=stats, kind=phase,
             flight=flight, grid=grid,
-            pass_counts=source_fallbacks if phase == "move" else None)
+            pass_counts=move_counts if phase == "move" else None)
         return st, applied, rounds_run
 
     # Fast path (parity with chain_optimize_full's per-goal lax.cond skip
@@ -2611,6 +2632,9 @@ def optimize_goal_in_chain(state: ClusterTensors, chain: Sequence[Goal],
         "offline_before": int(offline0),
         "offline_remaining": int(offline),
     }
+    if bounded:
+        info["healing_rounds"] = healed[0]
+        count_healing_rounds(healed[0], grid)
     if use_direct:
         # Direct-pass attribution (keys present only when the direct mode
         # was in force, so the disabled path's info dict stays identical

@@ -172,9 +172,10 @@ def ensure_evacuated(goal_chain: Sequence[Goal], infos: Sequence[dict],
     goals entered with replicas offline), and ``offline_before``,
     ``offline_remaining``, ``excluded_brokers`` on the pass's
     ``solver.dispatch`` spans under ``span``. Where the route tallies them
-    (the whole-chain dispatch), the move rounds that built the per-slot
-    offline mask (``chain._self_healing``): ``solver_healing_rounds_total``
-    and ``healing_rounds`` on the same spans."""
+    (the whole-chain dispatch and the bounded per-goal route, each of which
+    counts them in ``solver_healing_rounds_total{grid=}``), the pass's
+    move rounds that built the per-slot offline mask
+    (``chain._self_healing``): ``healing_rounds`` on the same spans."""
     if not infos:
         return
     from ..utils.sensors import SENSORS
@@ -189,7 +190,6 @@ def ensure_evacuated(goal_chain: Sequence[Goal], infos: Sequence[dict],
     healing = None
     if "healing_rounds" in infos[0]:
         healing = sum(info["healing_rounds"] for info in infos)
-        SENSORS.count("solver_healing_rounds", healing)
     for dispatch in _dispatch_spans(span):
         dispatch.set(
             offline_before=before, offline_remaining=remaining,

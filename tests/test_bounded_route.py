@@ -5,11 +5,14 @@ lowered through the optimizer's config, as a 1,000-broker cluster takes it
 with the defaults.
 
 Every pass over the same model gives the same plan, whatever budgets the
-dispatch controller chose: a dispatch resumes its pass from the previous
-dispatch's carry (``chain.PassCarry``: the aggregates, the pass's round
-count, the last round's applied count), so a split pass walks the unsplit
-loop's rounds, and a dispatch enqueued after the pass's fixed point runs
-none. The dispatch series carry a ``grid`` label and the bounded
+dispatch controller chose, for a rebalance and for a drain (three brokers
+removed: DEAD, excluded from replica moves and leadership, as
+``facade.remove_brokers`` marks them): a dispatch resumes its pass from
+the previous dispatch's carry (``chain.PassCarry``: the aggregates, the
+pass's round count, the last round's applied count, its tallies), so a
+split pass walks the unsplit loop's rounds, and a dispatch enqueued after
+the pass's fixed point runs none. The dispatch series carry a ``grid``
+label, the bounded route counts its healing rounds, and the bounded
 ``solver.dispatch`` spans say what the pump did."""
 
 import re
@@ -17,13 +20,16 @@ import re
 import numpy as np
 import pytest
 
+from cruise_control_tpu.analyzer.constraint import OptimizationOptions
 from cruise_control_tpu.analyzer.optimizer import (
     GoalOptimizer, goals_by_priority,
 )
+from cruise_control_tpu.common.broker_state import BrokerState
 from cruise_control_tpu.config.cruise_control_config import (
     CruiseControlConfig,
 )
 from cruise_control_tpu.model.fixtures import random_cluster
+from cruise_control_tpu.model.tensors import set_broker_state
 from cruise_control_tpu.utils.sensors import SENSORS
 from cruise_control_tpu.utils.tracing import TRACER
 
@@ -35,22 +41,42 @@ LOWERED = {"solver.fused.chain.max.brokers": "16",
 _SERIES = re.compile(r"^kafka_cruisecontrol_(\w+?)\{([^}]*)\} (\S+)$")
 
 
+# A heavy, a middle and a light broker of the placement skew, on three racks.
+REMOVED = (2, 13, 27)
+
+
 @pytest.fixture(scope="module")
 def cluster():
     return random_cluster(num_brokers=32, num_topics=4, num_partitions=960,
                           rf=3, num_racks=8, seed=5, skew_to_first=2.0)
 
 
-def solve(cluster, settings, passes=1):
+@pytest.fixture(scope="module", params=["rebalance", "drain"])
+def load(request, cluster):
+    """(state, meta, options) of a pass: the cluster as it is, or with
+    ``REMOVED`` marked as ``facade.remove_brokers`` marks them."""
+    state, meta = cluster
+    if request.param == "rebalance":
+        return state, meta, None
+    for b in REMOVED:
+        state = set_broker_state(state, np.int32(b), int(BrokerState.DEAD))
+    ids = tuple(meta.broker_ids[b] for b in REMOVED)
+    return state, meta, OptimizationOptions(
+        excluded_brokers_for_replica_move=ids,
+        excluded_brokers_for_leadership=ids)
+
+
+def solve(load, settings, passes=1):
     """Plans of ``passes`` passes on ONE optimizer (its dispatch
     controllers persist from pass to pass, as the served path's do)."""
-    state, meta = cluster
+    state, meta, options = load
     cfg = CruiseControlConfig(settings)
     opt = GoalOptimizer(cfg)
     out = []
     for _ in range(passes):
         _final, res = opt.optimizations(state, meta,
-                                        goals=goals_by_priority(cfg))
+                                        goals=goals_by_priority(cfg),
+                                        options=options)
         out.append(res)
     return out
 
@@ -80,33 +106,58 @@ def moved(before, after):
             if v != before.get(k, 0.0)}
 
 
+def counted(settings, load, passes):
+    """Passes of ``solve`` with the healing and evacuation rounds they
+    added to the counters: (results, {grid: healing rounds}, evacuation
+    rounds)."""
+    healing = series("solver_healing_rounds_total")
+    evacuation = SENSORS.counter_total("solver_evacuation_rounds")
+    results = solve(load, settings, passes)
+    by_grid = moved(healing, series("solver_healing_rounds_total"))
+    return (results, by_grid,
+            SENSORS.counter_total("solver_evacuation_rounds") - evacuation)
+
+
+def check_healing(load, by_grid, evacuation):
+    """A drain's bounded passes heal in some of their evacuation rounds and
+    in no other; a rebalance heals in none."""
+    assert set(by_grid) <= {'grid="narrow"', 'grid="wide"'}, by_grid
+    healed = sum(by_grid.values())
+    if load[2] is None:
+        assert healed == 0 == evacuation
+    else:
+        assert 0 < healed <= evacuation
+
+
 @pytest.fixture(scope="module")
-def unsplit(cluster):
+def unsplit(load):
     """The plan of passes that one dispatch runs whole (budget 1,024, the
     controller's ceiling, held fixed)."""
-    res, = solve(cluster, {**LOWERED, "solver.dispatch.max.rounds": "1024",
-                           "solver.dispatch.target.seconds": "0"})
+    res, = solve(load, {**LOWERED, "solver.dispatch.max.rounds": "1024",
+                        "solver.dispatch.target.seconds": "0"})
     return plan(res)
 
 
 @pytest.mark.parametrize("budget", [1, 4, 16])
-def test_the_plan_repeats_at_any_dispatch_budget(cluster, unsplit, budget):
+def test_the_plan_repeats_at_any_dispatch_budget(load, unsplit, budget):
     """Two passes at a fixed budget of 1, 4 or 16 rounds a dispatch give
     the plan of the unsplit passes, move for move and round for round."""
-    first, second = solve(cluster, {
+    (first, second), by_grid, evacuation = counted({
         **LOWERED, "solver.dispatch.max.rounds": str(budget),
-        "solver.dispatch.target.seconds": "0"}, passes=2)
+        "solver.dispatch.target.seconds": "0"}, load, passes=2)
     assert plan(first) == plan(second) == unsplit
     assert first.proposals and sum(r for _g, r, _m, _s in unsplit[1]) > 16
+    check_healing(load, by_grid, evacuation)
 
 
-def test_the_plan_repeats_under_the_adaptive_controller(cluster, unsplit):
+def test_the_plan_repeats_under_the_adaptive_controller(load, unsplit):
     """A controller that doubles its budget after every full dispatch
     splits each pass, and the second pass, elsewhere: the same plan."""
-    first, second = solve(cluster, {
+    (first, second), by_grid, evacuation = counted({
         **LOWERED, "solver.dispatch.max.rounds": "1",
-        "solver.dispatch.target.seconds": "1000000"}, passes=2)
+        "solver.dispatch.target.seconds": "1000000"}, load, passes=2)
     assert plan(first) == plan(second) == unsplit
+    check_healing(load, by_grid, evacuation)
 
 
 @pytest.mark.parametrize("wide_from", ["16", "0"])
@@ -120,8 +171,8 @@ def test_wide_rounds_are_counted_where_the_wide_grid_ran(cluster, wide_from):
                                "solver.wide.batch.min.brokers": wide_from})
     prefers = {g.name: g.prefers_wide_batches for g in goals_by_priority(cfg)}
     before = series("solver_dispatch_rounds_sum")
-    res, = solve(cluster, {**LOWERED,
-                           "solver.wide.batch.min.brokers": wide_from})
+    res, = solve((*cluster, None),
+                 {**LOWERED, "solver.wide.batch.min.brokers": wide_from})
     rounds = moved(before, series("solver_dispatch_rounds_sum"))
     by_grid = {grid: sum(v for k, v in rounds.items()
                          if f'grid="{grid}"' in k) for grid in
@@ -138,12 +189,14 @@ def test_wide_rounds_are_counted_where_the_wide_grid_ran(cluster, wide_from):
 def test_the_fused_route_labels_its_dispatch_fused(cluster):
     before = series("solver_dispatches_total")
     rounds_before = series("solver_dispatch_rounds_sum")
-    res, = solve(cluster, {})
+    res, = solve((*cluster, None), {})
     assert moved(before, series("solver_dispatches_total")) \
         == {'grid="fused",kind="chain"': 1.0}
     assert moved(rounds_before, series("solver_dispatch_rounds_sum")) \
         == {'grid="fused",kind="chain"':
             float(sum(g.rounds for g in res.goal_results))}
+    # the whole chain's healing rounds are counted under the same label
+    assert 'grid="fused"' in series("solver_healing_rounds_total")
 
 
 def spans(node):
@@ -166,7 +219,8 @@ def test_the_bounded_spans_say_what_the_pump_did(cluster):
     """Each bounded ``solver.dispatch`` span (one a pass) carries its
     ``grid`` (the goal's), ``pass_rounds`` (the rounds the pass searched),
     ``speculative`` (the pump's dispatches after the fixed point: at most
-    one) and ``budget_max`` (the largest budget a dispatch had)."""
+    one), ``budget_max`` (the largest budget a dispatch had) and the
+    whole pass's ``healing_rounds``."""
     was = TRACER.enabled
     TRACER.configure(enabled=True)
     try:
@@ -176,8 +230,9 @@ def test_the_bounded_spans_say_what_the_pump_did(cluster):
                                    "solver.dispatch.target.seconds": "0"})
         prefers = {g.name: g.prefers_wide_batches
                    for g in goals_by_priority(cfg)}
-        res, = solve(cluster, {**LOWERED, "solver.dispatch.max.rounds": "4",
-                               "solver.dispatch.target.seconds": "0"})
+        res, = solve((*cluster, None),
+                     {**LOWERED, "solver.dispatch.max.rounds": "4",
+                      "solver.dispatch.target.seconds": "0"})
         trace, = TRACER.traces(limit=1)
     finally:
         TRACER.configure(enabled=was)
@@ -196,6 +251,7 @@ def test_the_bounded_spans_say_what_the_pump_did(cluster):
             assert a["pass_rounds"] == a["rounds"]
             assert a["speculative"] in (0, 1)
             assert a["budget_max"] == 4
+            assert a["healing_rounds"] == 0     # no broker is DEAD
             rounds[attrs["goal"]] = rounds.get(attrs["goal"], 0) \
                 + a["pass_rounds"]
     assert rounds == {g.name: g.rounds for g in res.goal_results

@@ -6,6 +6,7 @@ against the plain sequential drain of ``benchlib/drain_reference.py``
 nothing on the removed brokers; one that cannot raises
 ``OptimizationFailureError`` and returns no partial plan."""
 
+import json
 import os
 import sys
 import time
@@ -43,13 +44,19 @@ from cruise_control_tpu.utils.tracing import TRACER  # noqa: E402
 
 # How GoalOptimizer picks each single-cluster route (optimizer.py): the
 # whole chain in one dispatch; per goal in bounded dispatches (the chain is
-# fused but the cluster is over the fused route's broker limit); per goal,
-# unbounded.
+# fused but the cluster is over the fused route's broker limit), on the
+# narrow grids and with the wide ones (the cluster is also at the wide
+# grids' broker threshold), as every cluster above 512 brokers takes it;
+# per goal, unbounded.
 ROUTES = {
     "fused": {},
     "bounded": {"solver.fused.chain.max.brokers": 8},
+    "bounded-wide": {"solver.fused.chain.max.brokers": 8,
+                     "solver.wide.batch.min.brokers": 8},
     "pergoal": {"solver.chain.fused": False},
 }
+# The routes that tally the rounds that built the offline mask.
+HEALING_COUNTED = ("fused", "bounded", "bounded-wide")
 # The other two places the per-goal infos converge: a cluster of a
 # megabatch, and the whole chain SPMD over the (virtual) devices.
 ROUTES_BEYOND_ONE_CLUSTER = {"megabatch": {}, "mesh": {}}
@@ -116,6 +123,12 @@ def counter(name, **labels):
                         **labels)
 
 
+def wide_rounds():
+    """Rounds the bounded route searched on the wide grids so far."""
+    return series_total(parse_exposition(SENSORS.render()),
+                        "solver_dispatch_rounds_sum", grid="wide")
+
+
 @pytest.mark.parametrize("removed", REMOVED, ids=lambda r: "-".join(map(str, r)))
 @pytest.mark.parametrize("route", ROUTES)
 def test_drain_agrees_with_the_plain_reference(route, removed):
@@ -130,7 +143,7 @@ def test_drain_agrees_with_the_plain_reference(route, removed):
     before = (counter("solver_offline_replicas", when="before"),
               counter("solver_offline_replicas", when="remaining"),
               counter("solver_evacuation_rounds"),
-              counter("solver_healing_rounds"))
+              counter("solver_healing_rounds"), wide_rounds())
     cc = facade(dep, route)
     try:
         result = cc.remove_brokers(removed, dryrun=True)
@@ -164,13 +177,15 @@ def test_drain_agrees_with_the_plain_reference(route, removed):
         == len(forced)
     assert counter("solver_offline_replicas", when="remaining") == before[1]
     assert counter("solver_evacuation_rounds") > before[2]
-    # the whole-chain dispatch tallies the rounds that built the offline
-    # mask: some, and no more than the evacuation rounds
+    # the whole-chain dispatch and the bounded passes tally the rounds that
+    # built the offline mask: some, and no more than the evacuation rounds
     healed = counter("solver_healing_rounds") - before[3]
-    if route == "fused":
+    if route in HEALING_COUNTED:
         assert 0 < healed <= counter("solver_evacuation_rounds") - before[2]
     else:
         assert healed == 0
+    # the wide grids ran on the route that forces them, and only there
+    assert (wide_rounds() > before[4]) == (route == "bounded-wide")
     dispatches = [s for s in spans(traces[0]["root"])
                   if s["name"] == "solver.dispatch"]
     # (the unbounded per-goal route opens goal.solve spans and no dispatch)
@@ -180,7 +195,7 @@ def test_drain_agrees_with_the_plain_reference(route, removed):
         assert attrs["offline_before"] == {"intValue": str(len(forced))}
         assert attrs["offline_remaining"] == {"intValue": "0"}
         assert attrs["excluded_brokers"] == {"intValue": str(len(removed))}
-        if route == "fused":
+        if route in HEALING_COUNTED:
             assert attrs["healing_rounds"] == {"intValue": str(int(healed))}
 
 
@@ -245,3 +260,41 @@ def test_the_api_answers_both_failures_alike(case):
     assert status == 500
     assert "OptimizationFailureError" in answer["errorMessage"]
     assert "proposals" not in answer
+
+
+def test_the_decommission_at_its_published_size_keeps_to_its_recipe():
+    """``configs/kafka-1000b-100kp-drain.json``: BASELINE config 4 uncut,
+    50 distinct brokers of 1,000 on all 8 racks, the small drain's five
+    first, nothing reduced; its cluster, scaled to 40 brokers with the
+    named brokers that lie below 40, is one the plain drain empties."""
+    with open(os.path.join(BENCH, "configs",
+                           "kafka-1000b-100kp-drain.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(BENCH, "configs",
+                           "kafka-100b-10kp-drain.json")) as f:
+        small = json.load(f)
+    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
+        entry = {c["name"]: c for c in json.load(f)["configs"]}[cfg["name"]]
+    removed = cfg["operation_brokers"]
+    assert entry["reduced"] == [] and entry["source"] == cfg["source"]
+    assert (cfg["brokers"], cfg["partitions"], cfg["drained_brokers"]) \
+        == (1000, 100000, 50) == tuple(cfg["source_scale"][k] for k in
+                                       ("brokers", "partitions",
+                                        "drained_brokers"))
+    assert len(set(removed)) == 50 == len(removed)
+    assert removed == [20 * i + (i + 2) % 8 for i in range(50)]
+    assert removed[:5] == small["operation_brokers"]
+    dep = build(cfg)
+    assert set(dep.broker_rack[removed].tolist()) == set(range(8))
+    assert cfg["guarantees"] == small["guarantees"]
+
+    scaled = build({**cfg, "brokers": 40, "partitions": 4000, "topics": 4,
+                    "operation_brokers": [b for b in removed if b < 40]})
+    assert scaled.operation_brokers == (2, 23)
+    greedy = drain_reference.drain(scaled, scaled.operation_brokers,
+                                   cfg["guarantees"])
+    assert greedy is not None
+    numbers = reference.evaluate(
+        scaled, cfg["guarantees"],
+        drain_reference.as_proposals(scaled, greedy))["numbers"]
+    assert numbers["on_removed_broker"] == 0 == numbers["rack_violations"]
