@@ -24,8 +24,8 @@ import jax.numpy as jnp
 
 from ..model.tensors import (
     ClusterTensors, broker_best, broker_best_rows, broker_flag_at,
-    broker_reduce_form, broker_segments, flatten_slots, is_leader_slot,
-    replica_exists, slot_coords,
+    broker_reduce_form, broker_segments, flat_top_k, flat_topk_form,
+    flatten_slots, is_leader_slot, replica_exists, slot_coords,
 )
 from .derived import DerivedState, dest_columns_ok, broker_masks_at
 
@@ -448,6 +448,21 @@ def source_select() -> str | None:
     return _source_select_traced
 
 
+# The form the source selection's top-k of the whole flat replica axis last
+# took in this process ("sort" or "two_level": model.tensors.flat_topk_form;
+# the leadership block's top-k, whose k is the grid's k_src, takes the same
+# form at every served grid), written when a program is traced, as
+# ``_source_select_traced`` is.
+_flat_topk_traced: str | None = None
+
+
+def flat_topk() -> str | None:
+    """How the last traced move round took its top-k's of the flat
+    replica axis (None before any trace): the ``flat_topk`` attribute of
+    the ``solver.dispatch`` spans."""
+    return _flat_topk_traced
+
+
 def source_rows(quarter: int, b: int, form: str) -> int | None:
     """How many candidate brokers the per-broker reductions of
     ``select_sources`` run over, from static shapes: ``quarter`` (the
@@ -539,9 +554,10 @@ def select_sources(state: ClusterTensors, source_score: jax.Array,
     The per-broker reductions take the form ``broker_reduce_form`` gives
     for these shapes (dense compare-and-reduce, or ``segment_*``), over
     the rows ``source_rows`` gives (every row where ``batched``: under
-    ``vmap``, ``broker_blocks``); cards and validity are the same under
-    every form."""
-    global _source_select_traced
+    ``vmap``, ``broker_blocks``); the global block's top-k the form
+    ``flat_topk_form`` gives (``lax.top_k``, or the two-level form); cards
+    and validity are the same under every form."""
+    global _source_select_traced, _flat_topk_traced
     b = state.num_brokers
     s_dim = state.max_replication_factor
     seg_flat = broker_segments(state)
@@ -568,7 +584,8 @@ def select_sources(state: ClusterTensors, source_score: jax.Array,
     _source_select_traced = form \
         if batched or source_rows(quarter, b, form) is None else "rows"
 
-    g_w, g_idx = jax.lax.top_k(flat_weight, half)
+    _flat_topk_traced = flat_topk_form(n_flat, half)
+    g_w, g_idx = flat_top_k(flat_weight, half)
     # Mask the global block's rows out of the per-broker selection so the
     # broker blocks only ADD diversity (on skewed clusters the globally
     # heaviest replicas are exactly the top brokers' best replicas, and a
@@ -666,7 +683,7 @@ def generate_candidates(state: ClusterTensors, derived: DerivedState,
         flat_lw = jnp.where(on_source & flatten_slots(is_leader_slot(state)),
                             flatten_slots(replica_weight), -jnp.inf)
         k_l = min(num_sources, flat_lw.shape[0])
-        top_lw, top_lidx = jax.lax.top_k(flat_lw, k_l)
+        top_lw, top_lidx = flat_top_k(flat_lw, k_l)
         lp = slot_coords(top_lidx, state.num_partitions,
                          s_dim)[0].astype(jnp.int32)
         l_valid = jnp.isfinite(top_lw)

@@ -40,7 +40,7 @@ from .agg import (
 )
 from .candidates import (
     CandidateDeltas, Candidates, compute_deltas, generate_candidates,
-    select_sources, source_select,
+    flat_topk, select_sources, source_select,
 )
 from .fill import targets_enabled
 from .constraint import BalancingConstraint
@@ -241,14 +241,18 @@ def accept_lookup() -> str | None:
 
 def _set_traced_forms(dispatch, kind: str = "move") -> None:
     """The traced forms of the move-round body onto a dispatch span:
-    ``accept_lookup``, and ``source_select`` (candidates.source_select:
-    how the source selection reduces the flat replica axis per broker)."""
+    ``accept_lookup``, ``source_select`` (candidates.source_select: how
+    the source selection reduces the flat replica axis per broker) and
+    ``flat_topk`` (candidates.flat_topk: how the round's top-k's of the
+    whole flat replica axis were taken)."""
     if kind != "move":
         return
     if _accept_lookup_traced is not None:
         dispatch.set(accept_lookup=_accept_lookup_traced)
     if source_select() is not None:
         dispatch.set(source_select=source_select())
+    if flat_topk() is not None:
+        dispatch.set(flat_topk=flat_topk())
 
 
 class ScoredCandidates(NamedTuple):

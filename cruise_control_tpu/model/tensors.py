@@ -309,6 +309,95 @@ def offline_per_broker(state: ClusterTensors, off: jax.Array) -> jax.Array:
                         broker_reduce_form(b, seg_flat.shape[0]))
 
 
+# ---- top-k of the flat replica axis ----------------------------------------
+#
+# A move round ranks the whole flat replica axis twice: the source
+# selection's global block and the leadership block take the k heaviest
+# replicas. ``lax.top_k`` lowers to ONE stable sort of the whole axis (a
+# comparator on the float's total order, then the index). Two forms give
+# its result, values, indices and order (docs/DESIGN.md "Top-k of the flat
+# replica axis"):
+#
+# - "sort": ``lax.top_k`` itself.
+# - "two_level": view the axis as rows of L contiguous elements, rank the
+#   rows by their best element, keep the k best rows in ascending order and
+#   run ``lax.top_k`` over their k * L elements alone.
+#
+# Measured on one v5e (ms an iteration of a fused loop, the sort against the
+# two-level form at the row length below; docs/DESIGN.md has the table, and
+# ``utils/microbench.py`` keeps the two as the cases ``flat_sort<k>`` /
+# ``flat_two<k>``): 0.38 against 0.022-0.046 ms at 307,200 flat replicas
+# (k 128 to 1,024), 0.086-0.090 against 0.018-0.021 at 76,800, and level at
+# 30,720 (0.020-0.021 against 0.017-0.021, k 128 / 256); no shape the grids
+# use reads the sort faster. Its result is ``lax.top_k``'s, so it is taken
+# wherever it keeps a small share of the axis; ``lax.top_k`` is left where
+# it would not.
+
+
+def flat_topk_form(n_flat: int, k: int) -> str:
+    """"sort" or "two_level": the form ``flat_top_k`` takes for these
+    (static) shapes. The ONE place that chooses: the two-level form where
+    the elements it keeps (k * L) are a quarter of the axis or fewer."""
+    return "two_level" if 4 * k * flat_topk_row_len(n_flat, k) <= n_flat \
+        else "sort"
+
+
+def flat_topk_row_len(n_flat: int, k: int) -> int:
+    """L, the row length of the two-level form: the power of two nearest
+    sqrt(2 n_flat / k), at least 8 (fit to one chip sweep over L: the
+    fastest row length it read at every k from 128 to 1,024 at 76,800 and
+    307,200, or within 2 % of it)."""
+    target = (2.0 * n_flat / max(k, 1)) ** 0.5
+    row = 8
+    while row * 2 <= target * 2 ** 0.5:
+        row *= 2
+    return row
+
+
+def _total_order_key(x: jax.Array) -> jax.Array:
+    """int32 keys whose order is the float32 total order ``lax.top_k``'s
+    comparator uses (-NaN < -inf < ... < -0.0 < +0.0 < ... < +inf < +NaN)."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+
+# The lowest float32 in the total order (all bits set: a negative NaN):
+# the two-level form pads the axis with it, behind every real element.
+_TOTAL_ORDER_MIN = np.array(-1, np.int32).view(np.float32)
+
+
+def two_level_top_k(x: jax.Array, k: int,
+                    row_len: int) -> tuple[jax.Array, jax.Array]:
+    """``lax.top_k(x, k)`` of a 1-D ``x`` without sorting all of it: the
+    ``k`` rows of ``row_len`` elements whose best element ranks highest
+    (best first, then the lower row: the order of the elements themselves)
+    hold every element of the result. Fewer than k rows rank above the
+    row of the k-th element, and each row that holds a result element
+    ranks no lower than that row, so the kept rows, taken in ascending
+    order, rank among themselves as the whole axis does."""
+    n = x.shape[0]
+    rows_n = -(-n // row_len)
+    kept = min(k, rows_n)
+    xp = jnp.concatenate(
+        [x, jnp.full(rows_n * row_len - n, _TOTAL_ORDER_MIN, x.dtype)])
+    grid = xp.reshape(rows_n, row_len)
+    _, rows = jax.lax.top_k(_total_order_key(grid).max(axis=1), kept)
+    rows = jnp.sort(rows)
+    vals, at = jax.lax.top_k(grid[rows].reshape(-1), k)
+    return vals, (rows[at // row_len] * row_len + at % row_len).astype(
+        jnp.int32)
+
+
+def flat_top_k(x: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """``lax.top_k(x, k)`` over the flat replica axis ``x [n_flat]``, in
+    the form ``flat_topk_form`` gives: the same values, indices and order
+    under both."""
+    n_flat = x.shape[-1]
+    if flat_topk_form(n_flat, k) == "two_level":
+        return two_level_top_k(x, k, flat_topk_row_len(n_flat, k))
+    return jax.lax.top_k(x, k)
+
+
 def _scatter_to_brokers(state: ClusterTensors, per_slot: jax.Array) -> jax.Array:
     """Sum a [P, S] or [P, S, R] per-replica quantity into per-broker rows
     ([B] or [B, R]). Padded slots route to a dead bucket at index B."""

@@ -60,6 +60,12 @@ from functools import partial
 # grid over a random cluster of (brokers, partitions): every field gathered
 # once per candidate (``deltas_flat``, no layout passed) and built on the
 # grid's margins (``deltas_margin``, the layout passed: the round's form).
+# The ``flat_sort<k>`` / ``flat_two<k>`` classes price the move
+# round's two top-k's of the whole flat replica axis
+# (``model.tensors.flat_top_k``) at the k's the grids use: ``lax.top_k``
+# itself, one sort of the axis, and the two-level form at the row length
+# ``flat_topk_row_len`` gives.
+FLAT_TOPK_KS = (128, 256, 512, 1024)
 CASE_NAMES = ("topk128", "topk1024", "approx1024", "segsum", "segmax",
               "gather_grid", "scatter_m", "elemwise", "pairwise_m",
               "segsort", "rankfill", "scatter_apply",
@@ -67,7 +73,9 @@ CASE_NAMES = ("topk128", "topk1024", "approx1024", "segsum", "segmax",
               "stride_sort_fused", "accept_flat", "accept_margin",
               "accept_packed", "bbest_segment", "bbest_dense", "bbest_sort",
               "bbest_rows", "bcount_segment", "bcount_dense", "deltas_flat",
-              "deltas_margin")
+              "deltas_margin") \
+    + tuple(f"flat_{form}{k}" for k in FLAT_TOPK_KS
+            for form in ("sort", "two"))
 
 _ACCEPT_TERMS = 100
 
@@ -123,7 +131,10 @@ def _build_cases(brokers: int, partitions: int, quarter: int = 64):
     # or in the dead bucket, as ``broker_segments`` lays them out; the
     # carry's first ``brokers`` entries are the brokers' source scores.
     from ..analyzer.candidates import _keep_brokers, broker_blocks
-    from ..model.tensors import broker_best, broker_count, broker_flag_at
+    from ..model.tensors import (
+        broker_best, broker_count, broker_flag_at, flat_topk_row_len,
+        two_level_top_k,
+    )
     bseg = jax.random.randint(akeys[0], (n_flat,), 0, brokers + 1)
     b_ids = jnp.arange(brokers, dtype=jnp.int32)
     quarter = min(quarter, brokers)
@@ -385,6 +396,12 @@ def _build_cases(brokers: int, partitions: int, quarter: int = 64):
             return loop(lambda v: deltas_step(v, None), x, iters)
         if which == "deltas_margin":
             return loop(lambda v: deltas_step(v, grid.layout), x, iters)
+        if which.startswith(("flat_sort", "flat_two")):
+            k = int(which.removeprefix("flat_sort").removeprefix("flat_two"))
+            row_len = flat_topk_row_len(n_flat, k)
+            top = (lambda v: jax.lax.top_k(v, k)) if "sort" in which else \
+                (lambda v: two_level_top_k(v, k, row_len))
+            return loop(lambda v: v + fold(top(v)), x, iters)
         if which == "scatter_apply":
             # one-shot scatter apply of a full mover batch onto [P, S].
             plane = jnp.zeros((partitions, s), jnp.int32)
@@ -410,6 +427,8 @@ def _build_cases(brokers: int, partitions: int, quarter: int = 64):
               "bbest_sort": w, "bbest_rows": w, "bcount_segment": w,
               "bcount_dense": w,
               "deltas_flat": shift0, "deltas_margin": shift0}
+    inputs.update({name: w for name in CASE_NAMES
+                   if name.startswith("flat_")})
     return run, inputs
 
 

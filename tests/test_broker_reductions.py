@@ -4,7 +4,11 @@
 compare-and-reduce form and the ``segment_*`` form give the same answers,
 bit for bit, so the source selection picks the same cards, the chain walks
 the same trajectory, and ``solver.dispatch`` says which form was traced.
-The segment form is the oracle: it is what the CPU runs unforced.
+The segment form is the oracle: it is what the CPU runs unforced. Likewise
+the top-k's of the whole flat replica axis (``flat_top_k``, docs/DESIGN.md
+"Top-k of the flat replica axis"): the two-level form returns
+``lax.top_k``'s values, indices and order, and ``lax.top_k`` is the
+oracle.
 """
 
 import dataclasses
@@ -29,9 +33,10 @@ from cruise_control_tpu.config.cruise_control_config import (
 from cruise_control_tpu.model import tensors
 from cruise_control_tpu.model.fixtures import random_cluster
 from cruise_control_tpu.model.tensors import (
-    BrokerState, broker_best, broker_count, broker_flag_at,
-    broker_reduce_form, broker_segments, flatten_slots, offline_per_broker,
-    offline_replicas, set_broker_state,
+    BrokerState, ClusterTensors, broker_best, broker_count, broker_flag_at,
+    broker_reduce_form, broker_segments, flat_top_k, flat_topk_form,
+    flat_topk_row_len, flatten_slots, offline_per_broker, offline_replicas,
+    set_broker_state, two_level_top_k,
 )
 from cruise_control_tpu.utils.tracing import TRACER
 
@@ -343,15 +348,23 @@ def test_fused_chain_at_64_1024_same_trajectory_over_the_candidate_rows(
 
 
 @pytest.mark.parametrize("case", ("bbest_dense", "bbest_sort",
-                                  "bbest_rows", "bcount_dense"))
+                                  "bbest_rows", "bcount_dense", "flat_two128",
+                                  "flat_two256", "flat_two512",
+                                  "flat_two1024"))
 def test_microbench_broker_forms_compute_the_same(case):
     """The microbench's ``bbest_*`` / ``bcount_*`` classes price the SAME
     work: every form leaves the carry its segment form leaves (at 64
-    brokers keeping 4, so ``bbest_rows`` reduces 20 rows of 64)."""
+    brokers keeping 4, so ``bbest_rows`` reduces 20 rows of 64); each
+    ``flat_two<k>`` the carry ``flat_sort<k>`` leaves (over 6,144 flat
+    replicas, so every k keeps fewer rows than there are)."""
     from cruise_control_tpu.utils.microbench import _build_cases
     assert cand_mod.source_rows(4, 64, "dense") == 20
-    run, inputs = _build_cases(64, 64, quarter=4)
-    oracle = case.split("_")[0] + "_segment"
+    if case.startswith("flat_"):
+        run, inputs = _build_cases(64, 2048, quarter=4)
+        oracle = case.replace("two", "sort")
+    else:
+        run, inputs = _build_cases(64, 64, quarter=4)
+        oracle = case.split("_")[0] + "_segment"
     want = np.asarray(run(inputs[oracle], 2, oracle))
     assert (want != np.asarray(inputs[oracle])).any()
     np.testing.assert_array_equal(
@@ -466,3 +479,224 @@ def test_the_traced_selection_reduces_the_candidate_rows(
     found = list(_argmax_rows(jaxpr))
     assert sorted(r for r, c in found if not c) == outside
     assert sorted(r for r, c in found if c) == inside
+
+
+# ---- top-k of the flat replica axis ----------------------------------------
+
+def _draw(name):
+    """(x, k, row_len) of one two-level scenario: seeded draws."""
+    rng = np.random.default_rng(23)
+    if name == "plain":
+        return rng.normal(size=1000), 37, 16
+    if name == "heavy ties":
+        return rng.integers(0, 4, 1000).astype(float), 100, 8
+    if name == "whole rows of one value":
+        return np.repeat(rng.integers(0, 3, 125), 8).astype(float), 50, 8
+    if name == "rows of -inf":
+        x = rng.normal(size=1024)
+        x.reshape(64, 16)[rng.random(64) < 0.7] = -INF
+        return x, 200, 16
+    if name == "all -inf":
+        return np.full(777, -INF), 64, 8
+    if name == "+inf and -inf among ties":
+        return rng.choice([-INF, INF, 1.0, 2.0], 999), 300, 4
+    if name == "signed zeros":
+        return rng.choice([-0.0, 0.0, -1.0], 512), 100, 8
+    if name == "k = 1":
+        return rng.integers(0, 2, 500).astype(float), 1, 8
+    if name == "k = n_flat":
+        return rng.integers(0, 5, 96).astype(float), 96, 8
+    if name == "n_flat not a multiple of L":
+        return rng.integers(0, 9, 1001).astype(float), 33, 64
+    if name == "a drain's 1e30 beside -inf":
+        x = np.where(rng.random(3000) < 0.8, -INF, rng.uniform(1, 2, 3000))
+        x[rng.integers(0, 3000, 40)] = 1e30
+        return x, 128, 16
+    raise KeyError(name)
+
+
+TWO_LEVEL_DRAWS = ("plain", "heavy ties", "whole rows of one value",
+                   "rows of -inf", "all -inf", "+inf and -inf among ties",
+                   "signed zeros", "k = 1", "k = n_flat",
+                   "n_flat not a multiple of L",
+                   "a drain's 1e30 beside -inf")
+
+
+def _same_top_k(got, want):
+    """Values (bit for bit: -0.0 is not +0.0), indices and order."""
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    np.testing.assert_array_equal(np.asarray(got[0]).view(np.int32),
+                                  np.asarray(want[0]).view(np.int32))
+
+
+@pytest.mark.parametrize("name", TWO_LEVEL_DRAWS)
+def test_two_level_top_k_is_lax_top_k(name):
+    x, k, row_len = _draw(name)
+    x = jnp.asarray(x, jnp.float32)
+    _same_top_k(jax.jit(two_level_top_k, static_argnums=(1, 2))(
+        x, k, row_len), jax.lax.top_k(x, k))
+
+
+def test_two_level_top_k_under_vmap():
+    """The megabatch body runs the round under ``vmap``: batched, the form
+    is still ``lax.top_k`` cluster by cluster."""
+    rng = np.random.default_rng(29)
+    x = jnp.asarray(np.where(rng.random((3, 1000)) < 0.3, -INF,
+                             rng.integers(0, 5, (3, 1000))), jnp.float32)
+    _same_top_k(jax.vmap(lambda v: two_level_top_k(v, 50, 16))(x),
+                jax.vmap(lambda v: jax.lax.top_k(v, 50))(x))
+
+
+def _force_topk(monkeypatch, form):
+    """Steer the top-k chooser, for every module that imported it."""
+    for mod in (tensors, cand_mod):
+        monkeypatch.setattr(mod, "flat_topk_form", lambda n, k: form)
+
+
+@pytest.mark.parametrize("k", (128, 256, 512, 1024))
+@pytest.mark.parametrize("n_flat", (30_720, 76_800, 307_200))
+def test_flat_top_k_forced_two_level_at_the_microbench_sizes(
+        monkeypatch, n_flat, k):
+    """``flat_top_k`` with the two-level form forced, at every (n_flat, k)
+    the microbench prices, on a draw with ties and -inf as the source
+    selection's weights have them."""
+    rng = np.random.default_rng(n_flat + k)
+    x = np.where(rng.random(n_flat) < 0.5, -INF,
+                 rng.integers(0, 50, n_flat).astype(float))
+    x = jnp.asarray(x, jnp.float32)
+    _force_topk(monkeypatch, "two_level")
+    _same_top_k(jax.jit(flat_top_k, static_argnums=1)(x, k),
+                jax.lax.top_k(x, k))
+
+
+def test_flat_topk_form_follows_shape(monkeypatch):
+    """One place chooses, from the static shapes alone: the two-level form
+    at every k the served grids use at 104, 256 and 1,024 padded brokers
+    (30,720 / 76,800 / 307,200 flat replicas), ``lax.top_k`` where the
+    rows kept would be more than a quarter of the axis; the same on every
+    backend."""
+    def forms():
+        return {(n, k): flat_topk_form(n, k)
+                for n in (1_536, 30_720, 76_800, 307_200)
+                for k in (128, 256, 512, 1024, n)}
+
+    on_cpu = forms()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert forms() == on_cpu
+    for n in (30_720, 76_800, 307_200):
+        for k in (128, 256, 512):
+            assert on_cpu[n, k] == "two_level"
+            assert 4 * k * flat_topk_row_len(n, k) <= n
+        assert on_cpu[n, n] == "sort"
+    assert on_cpu[76_800, 1024] == on_cpu[307_200, 1024] == "two_level"
+    assert on_cpu[30_720, 1024] == "sort"
+    assert {on_cpu[1_536, k] for k in (128, 256, 512, 1024)} == {"sort"}
+
+
+def test_fused_chain_at_64_1024_same_trajectory_with_the_two_level_top_k(
+        monkeypatch):
+    """One fused ``optimize_chain`` pass over the default chain at 64
+    brokers / 1,024 partitions, a broker dead, once with each top-k form
+    forced: placement, leader slots, rounds and proposals by goal are the
+    same, and the dispatch span says which form was traced."""
+    state, meta = random_cluster(num_brokers=64, num_topics=8,
+                                 num_partitions=1024, rf=3, num_racks=4,
+                                 seed=5, skew_to_first=2.0)
+    state = set_broker_state(state, jnp.asarray([3]), BrokerState.DEAD)
+    goals = tuple(goals_by_priority(CruiseControlConfig()))
+    cfg = SearchConfig(num_sources=64, num_dests=6, moves_per_round=32,
+                       max_rounds=60)
+    args = (state, goals, BalancingConstraint(), cfg, meta.num_topics,
+            ExclusionMasks())
+
+    def one_pass():
+        chain_optimize_full.clear_cache()
+        with TRACER.span("test.pass") as root:
+            out = optimize_chain(*args)
+        (dispatch,) = [c for c in root.children
+                       if c.name == "solver.dispatch"]
+        return out, dispatch.attributes
+
+    try:
+        _force_topk(monkeypatch, "sort")
+        jax.clear_caches()
+        (st_sort, infos_sort), attrs = one_pass()
+        assert attrs["flat_topk"] == "sort"
+        _force_topk(monkeypatch, "two_level")
+        jax.clear_caches()
+        (st_two, infos_two), attrs = one_pass()
+        assert attrs["flat_topk"] == "two_level"
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+
+    np.testing.assert_array_equal(np.asarray(st_sort.assignment),
+                                  np.asarray(st_two.assignment))
+    np.testing.assert_array_equal(np.asarray(st_sort.leader_slot),
+                                  np.asarray(st_two.leader_slot))
+    assert sum(i["rounds"] for i in infos_sort) > len(goals)
+    assert infos_sort == infos_two
+
+
+def _top_k_operands(jaxpr):
+    """The operand length of every top_k, through every nested jaxpr."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "top_k":
+            yield eqn.invars[0].aval.shape[-1]
+        for v in eqn.params.values():
+            for x in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(x, "jaxpr", x)
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _top_k_operands(sub)
+
+
+def _zero_cluster(brokers, partitions):
+    """A cluster of the served padded shapes; only shapes matter."""
+    return ClusterTensors(
+        assignment=jnp.zeros((partitions, 3), jnp.int32),
+        leader_slot=jnp.zeros(partitions, jnp.int32),
+        leader_load=jnp.zeros((partitions, 4)),
+        follower_load=jnp.zeros((partitions, 4)),
+        capacity=jnp.ones((brokers, 4)),
+        rack=jnp.zeros(brokers, jnp.int32),
+        broker_state=jnp.zeros(brokers, jnp.int8),
+        topic=jnp.zeros(partitions, jnp.int32),
+        partition_mask=jnp.ones(partitions, bool),
+        broker_mask=jnp.ones(brokers, bool))
+
+
+@pytest.mark.parametrize("brokers, partitions, form, flat_sorts", [
+    (1024, 102_400, "two_level", 0),
+    (104, 10_240, "two_level", 0),
+    (16, 512, "sort", 2),
+])
+def test_the_traced_round_takes_the_chosen_flat_top_k(
+        monkeypatch, brokers, partitions, form, flat_sorts):
+    """CPU, jaxpr walk, no compile, the chip's choices: at 1,024 and 104
+    brokers (307,200 / 30,720 flat replicas) the global block (k 128) and
+    the leadership block (k 256) of a grid of 256 sources never rank the
+    whole axis, only the rows' best elements and the rows kept; at 16
+    brokers (1,536), where the rows kept would be most of the axis, both
+    are ``lax.top_k`` of the whole axis. ``flat_topk`` says so."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    state = _zero_cluster(brokers, partitions)
+    n_flat = partitions * 3
+    score = jnp.ones(brokers, jnp.float32)
+    weight = jnp.ones(state.assignment.shape, jnp.float32)
+
+    def round_top_ks(st, sc, w):
+        cand, _layout = cand_mod.generate_candidates(
+            st, None, sc, sc, w, 256, 16, include_leadership=True,
+            leadership_only=True)
+        return cand
+
+    jaxpr = jax.make_jaxpr(round_top_ks)(state, score, weight).jaxpr
+    sizes = list(_top_k_operands(jaxpr))
+    assert sizes.count(n_flat) == flat_sorts
+    assert cand_mod.flat_topk() == form
+    if form == "two_level":
+        row_lens = [flat_topk_row_len(n_flat, k) for k in (128, 256)]
+        rows = [-(-n_flat // row) for row in row_lens]
+        kept = [k * row for k, row in zip((128, 256), row_lens)]
+        assert sorted(rows + kept) == sorted(
+            s for s in sizes if s > brokers)

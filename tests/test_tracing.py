@@ -256,6 +256,9 @@ def test_rebalance_dryrun_yields_full_trace_tree(traced_api):
     assert dattrs["accept_lookup"] == {"stringValue": "grid"}
     # and reduces the flat replica axis per broker as the CPU does
     assert dattrs["source_select"] == {"stringValue": "segment"}
+    # and ranks the whole flat replica axis with lax.top_k: on a cluster
+    # this small the two-level form's rows would be most of the axis
+    assert dattrs["flat_topk"] == {"stringValue": "sort"}
     per_goal = [int(r) for r in
                 dattrs["goal_rounds"]["stringValue"].split(",")]
     assert sum(per_goal) == int(dattrs["rounds"]["intValue"])
