@@ -8,13 +8,16 @@ by comparing assignment/leader arrays, and the comparison is one of arrays
 end to end: ``compare_diff`` orders, maps and filters the changed rows in
 numpy and returns them as ``ProposalColumns``, a
 ``Sequence[ExecutionProposal]`` that builds an object only for a reader
-that asks for one (the executor; not the served dry-run body).
+that asks for one (the executor; not the served dry-run body), and writes
+itself as the served body's JSON text (``ProposalColumns.to_json``).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import json
 from collections.abc import Iterator, Sequence
+from operator import itemgetter
 
 import numpy as np
 
@@ -70,8 +73,8 @@ class ProposalColumns(Sequence):
     ``Sequence[ExecutionProposal]``: indexing and iteration build the
     objects on demand (counter ``proposal_objects_materialized_total``), a
     slice is columns again. The readers of the served dry-run path
-    (``count_leadership_only``, ``proposal_rows``) read the columns and
-    build none."""
+    (``count_leadership_only``, ``to_json``) read the columns and build
+    none."""
 
     partition_index: Sequence[tuple[str, int]]  # ClusterMeta's, not copied
     rows: np.ndarray             # [n] partition indices
@@ -111,6 +114,75 @@ class ProposalColumns(Sequence):
         return zip([index[p] for p in self.rows.tolist()],
                    self.old_leader.tolist(), _unpadded(self.old_replicas),
                    _unpadded(self.new_replicas), self.new_leader.tolist())
+
+    def to_json(self) -> bytes:
+        """The plan as the served body's ``"proposals"`` array: exactly
+        ``json.dumps`` of a dict a row, ``{"topicPartition": {"topic",
+        "partition"}, "oldLeader", "oldReplicas", "newReplicas",
+        "newLeader"}``, pads dropped, with ``separators=(",", ":")``, built
+        from the columns with no Python object a row. Each row is laid out
+        in one ``uint8`` matrix row, every field at its widest with 0
+        bytes where a value is shorter; JSON text never holds a 0 byte
+        (``ensure_ascii`` escapes control characters), so deleting them
+        leaves the text."""
+        n = len(self)
+        if n == 0:
+            return b"[]"
+        names = [self.partition_index[p] for p in self.rows.tolist()]
+        code = {t: i for i, t in enumerate(
+            dict.fromkeys(map(itemgetter(0), names)))}
+        topics = _ascii_rows([json.dumps(t).encode() for t in code])
+        topic = np.fromiter(map(code.__getitem__, map(itemgetter(0), names)),
+                            np.intp, n)
+        partition = np.fromiter(map(itemgetter(1), names), np.int64, n)
+        s = self.old_replicas.shape[1]
+        brokers = np.concatenate(
+            [self.old_leader[:, None], self.old_replicas, self.new_replicas,
+             self.new_leader[:, None]], axis=1)
+        digits = _decimal(brokers)
+        replicas = digits[:, 1:-1]
+        replicas[..., 0] = ord(",")          # the sign's byte: no -1 listed
+        replicas[:, [0, s], 0] = 0           # nothing before a list's first
+        replicas[brokers[:, 1:-1] < 0] = 0   # a pad writes nothing
+
+        def text(b: bytes) -> np.ndarray:
+            return np.broadcast_to(np.frombuffer(b, np.uint8), (n, len(b)))
+
+        rows = np.concatenate([
+            text(b'{"topicPartition":{"topic":'), topics[topic],
+            text(b',"partition":'), _decimal(partition),
+            text(b'},"oldLeader":'), digits[:, 0],
+            text(b',"oldReplicas":['), replicas[:, :s].reshape(n, -1),
+            text(b'],"newReplicas":['), replicas[:, s:].reshape(n, -1),
+            text(b'],"newLeader":'), digits[:, -1], text(b'},')], axis=1)
+        body = rows.tobytes().translate(None, b"\0")
+        return b"".join((b"[", memoryview(body)[:-1], b"]"))
+
+
+def _decimal(values: np.ndarray) -> np.ndarray:
+    """``values`` (integers, any shape) as ASCII decimal, one row of bytes
+    a value: ``-`` or 0 first, then the digits right-aligned, 0 bytes
+    before the first."""
+    magnitude = np.abs(values)
+    top = int(magnitude.max(initial=0))
+    width = len(str(top))
+    magnitude = magnitude.astype(np.min_scalar_type(top))   # faster divides
+    out = np.zeros(values.shape + (width + 1,), np.uint8)
+    out[..., 0] = np.where(values < 0, ord("-"), 0)
+    out[..., width] = magnitude % 10 + ord("0")
+    for k in range(1, width):
+        above = magnitude // 10 ** k
+        out[..., width - k] = np.where(above > 0, above % 10 + ord("0"), 0)
+    return out
+
+
+def _ascii_rows(texts: list[bytes]) -> np.ndarray:
+    """``[len(texts), widest]`` ``uint8``, each text left-aligned, 0 bytes
+    after it."""
+    out = np.zeros((len(texts), max(map(len, texts))), np.uint8)
+    for i, t in enumerate(texts):
+        out[i, :len(t)] = np.frombuffer(t, np.uint8)
+    return out
 
 
 def _unpadded(replicas: np.ndarray) -> list[list[int]]:

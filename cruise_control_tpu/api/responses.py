@@ -9,10 +9,13 @@ are served by the same dicts pretty-printed.
 
 from __future__ import annotations
 
+import json
+import threading
+
 import numpy as np
 
 from ..analyzer.optimizer import OptimizerResult
-from ..analyzer.proposals import proposal_rows
+from ..analyzer.proposals import ProposalColumns, proposal_rows
 from ..common.resources import Resource
 from ..executor.admin import AdminBackend
 from ..facade import OperationResult
@@ -20,6 +23,7 @@ from ..model.tensors import (
     ClusterMeta, ClusterTensors, broker_leader_counts, broker_load,
     broker_replica_counts, leader_bytes_in, potential_nw_out, replica_load,
 )
+from ..utils.sensors import SENSORS
 
 
 def _num_cores(cpu_capacity_pct: float) -> int:
@@ -35,6 +39,9 @@ def _num_cores(cpu_capacity_pct: float) -> int:
     return max(1, int(round(cpu_capacity_pct / 100.0)))
 
 JSON_VERSION = 1
+# Bodies are written compact, as upstream's Gson writes them; any
+# ``indent`` takes CPython off its C encoder (docs/DESIGN.md).
+_COMPACT = (",", ":")
 
 
 def envelope(payload: dict) -> dict:
@@ -359,14 +366,96 @@ def optimization_result(op: OperationResult, verbose: bool = False) -> dict:
         if not verbose and len(proposals) > _NON_VERBOSE_PROPOSAL_CAP:
             body["proposalsTruncated"] = True
             proposals = proposals[:_NON_VERBOSE_PROPOSAL_CAP]
-        body["proposals"] = [
-            {"topicPartition": {"topic": topic, "partition": partition},
-             "oldLeader": old_leader,
-             "oldReplicas": old_replicas,
-             "newReplicas": new_replicas,
-             "newLeader": new_leader}
-            for (topic, partition), old_leader, old_replicas, new_replicas,
-            new_leader in proposal_rows(proposals)]
-        seg.set(numProposals=len(proposals))
+        if isinstance(proposals, ProposalColumns):
+            body["proposals"] = ProposalsJson(proposals.to_json(),
+                                              len(proposals))
+            form = "text"
+        else:
+            body["proposals"] = proposal_dicts(proposals)
+            form = "objects"
+        SENSORS.count("proposal_rows_encoded", len(proposals), {"form": form})
+        seg.set(numProposals=len(proposals), encoded=form)
     body.update(op.extra)
     return envelope(body)
+
+
+def proposal_dicts(proposals) -> list[dict]:
+    """The body's ``"proposals"``, a dict a move: what a plain list of
+    ``ExecutionProposal``s is written from (and, read from columns, what
+    ``ProposalColumns.to_json`` writes the text of)."""
+    return [
+        {"topicPartition": {"topic": topic, "partition": partition},
+         "oldLeader": old_leader,
+         "oldReplicas": old_replicas,
+         "newReplicas": new_replicas,
+         "newLeader": new_leader}
+        for (topic, partition), old_leader, old_replicas, new_replicas,
+        new_leader in proposal_rows(proposals)]
+
+
+class ProposalsJson(list):
+    """``body["proposals"]`` of a plan in columns: its JSON text
+    (``ProposalColumns.to_json``), which ``body_bytes`` writes as it is,
+    and for every reader in the process the list of dicts that the text
+    parses to. ``len`` answers from the row count; any other read parses
+    the text once, fills the list and counts the rows in
+    ``proposal_rows_encoded_total{form="objects"}``. It is a ``list``, so
+    ``json.dumps`` (which iterates a list's subclass), ``_as_text`` and
+    ``==`` against a plain list read it as one. Read it; do not edit it:
+    the text is what is served."""
+
+    __slots__ = ("text", "_unread")
+
+    def __init__(self, text: bytes, rows: int):
+        super().__init__()
+        self.text = text
+        self._unread = rows       # None once the list holds the dicts
+
+    def _read(self) -> "ProposalsJson":
+        if self._unread is not None:
+            with _READING:        # a stored body has readers on many threads
+                if self._unread is not None:
+                    rows = json.loads(self.text)
+                    SENSORS.count("proposal_rows_encoded", len(rows),
+                                  {"form": "objects"})
+                    list.__setitem__(self, slice(None), rows)
+                    self._unread = None
+        return self
+
+    def __len__(self) -> int:
+        return list.__len__(self) if self._unread is None else self._unread
+
+
+_READING = threading.Lock()
+
+
+def _reading(method):
+    def read(self, *args):
+        return method(self._read(), *args)
+    read.__name__ = method.__name__
+    return read
+
+
+for _method in ("__iter__", "__reversed__", "__getitem__", "__contains__",
+                "__eq__", "__ne__", "__repr__", "__add__", "__mul__",
+                "copy", "index", "count"):
+    setattr(ProposalsJson, _method, _reading(getattr(list, _method)))
+
+
+def body_bytes(body) -> bytes:
+    """``json.dumps(body, separators=(",", ":")).encode()``: a
+    ``ProposalsJson`` under ``body["proposals"]`` is written as its text,
+    in its place among the keys, and the rest of the body is dumped as
+    ever (a dict's text is its items' joined, so it splits at a key)."""
+    if not (isinstance(body, dict)
+            and isinstance(body.get("proposals"), ProposalsJson)):
+        return json.dumps(body, separators=_COMPACT).encode()
+    keys = list(body)
+    at = keys.index("proposals")
+    head = json.dumps({k: body[k] for k in keys[:at]},
+                      separators=_COMPACT)[:-1]
+    tail = json.dumps({k: body[k] for k in keys[at + 1:]},
+                      separators=_COMPACT)[1:]
+    return b"".join((head.encode(), b',"proposals":' if at else
+                     b'"proposals":', body["proposals"].text,
+                     b"," if tail != "}" else b"", tail.encode()))

@@ -1807,9 +1807,7 @@ class _Handler(BaseHTTPRequestHandler):
                 content_type = extra.pop("Content-Type",
                                          "text/plain; charset=utf-8")
             else:
-                # compact, as upstream's Gson writes it; any ``indent``
-                # takes CPython off its C encoder (docs/DESIGN.md)
-                data = json.dumps(body, separators=(",", ":")).encode()
+                data = responses.body_bytes(body)
                 content_type = extra.pop("Content-Type", "application/json")
         self._send(method, t0, status, data, content_type, extra)
 

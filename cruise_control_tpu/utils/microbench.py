@@ -469,3 +469,79 @@ def run_microbench(brokers: int = 1000, partitions: int = 100_000,
             "brokers": int(brokers), "partitions": int(partitions),
             "iters": int(iters), "quarter": int(quarter),
             "unit": "ms_per_iter", "results": results}
+
+
+# The plan sizes the benchmark's cells serve in a body (100-broker
+# rebalance, 250-broker rebalance, 1,000-broker rebalance).
+RENDER_ROWS = (6369, 16275, 72470)
+
+
+def run_render_bench(seed: int = 0, rows: tuple[int, ...] = RENDER_ROWS,
+                     repeats: int = 5) -> dict:
+    """Host-side, no device: a served body's ``"proposals"`` written from
+    a plan in columns both ways, a dict a move then ``json.dumps``
+    (``responses.proposal_dicts``, the render before the text) and the
+    columns' own text (``ProposalColumns.to_json``), on synthetic columns
+    at RF 3 over 1,000 brokers drawn from ``seed``. Median ms of
+    ``repeats`` a form and size, the collector off (what its pauses cost
+    depends on the heap around the call); ``equal`` says the bytes are."""
+    import gc
+    import json
+    import statistics
+
+    import numpy as np
+
+    from ..analyzer.proposals import ProposalColumns
+    from ..api.responses import proposal_dicts
+
+    def dicts(plan):
+        return json.dumps(proposal_dicts(plan), separators=(",", ":")).encode()
+
+    rng = np.random.default_rng(seed)
+    results = {}
+    for n in rows:
+        b0 = rng.integers(0, 1000, n)
+        d1 = rng.integers(1, 1000, n)
+        d2 = rng.integers(1, 999, n)
+        d2 += d2 >= d1
+        old = np.stack([b0, (b0 + d1) % 1000, (b0 + d2) % 1000], axis=1)
+        new = np.roll(old, 1, axis=1)
+        index = [(f"topic-{p // 1000}", p % 1000) for p in range(2 * n)]
+        plan = ProposalColumns(
+            partition_index=index,
+            rows=np.sort(rng.choice(2 * n, n, replace=False)),
+            old_replicas=old, new_replicas=new, old_leader=old[:, 0],
+            new_leader=new[:, 0], data_to_move_mb=np.zeros(n))
+        timed, texts = {}, {}
+        was_enabled = gc.isenabled()
+        gc.disable()
+        try:
+            for name, write in (("dicts", dicts),
+                                ("text", ProposalColumns.to_json)):
+                seconds = []
+                for _ in range(repeats):
+                    t0 = time.perf_counter()
+                    texts[name] = write(plan)
+                    seconds.append(time.perf_counter() - t0)
+                timed[name] = round(statistics.median(seconds) * 1e3, 3)
+        finally:
+            if was_enabled:
+                gc.enable()
+        results[str(n)] = {**timed,
+                           "equal": texts["dicts"] == texts["text"]}
+    return {"seed": int(seed), "repeats": int(repeats), "unit": "ms",
+            "results": results}
+
+
+if __name__ == "__main__":
+    import argparse
+    import json
+
+    parser = argparse.ArgumentParser(
+        description="host-side cases of the microbench (the device classes "
+                    "run through tools/microbench_device.py)")
+    parser.add_argument("case", choices=["render"])
+    parser.add_argument("--seed", type=int, default=0)
+    parser.add_argument("--repeats", type=int, default=5)
+    args = parser.parse_args()
+    print(json.dumps(run_render_bench(args.seed, repeats=args.repeats)))
